@@ -13,6 +13,7 @@ from typing import Any, Optional, Sequence
 
 import numpy as np
 
+from antidote_tpu import native_build
 from antidote_tpu.config import AntidoteConfig
 from antidote_tpu.crdt import is_type
 from antidote_tpu.store.kv import KVStore
@@ -24,6 +25,24 @@ from antidote_tpu.txn.manager import (
 )
 
 BoundObject = Any
+
+
+def device_report() -> dict:
+    """The JAX devices this process runs on, as JAX reports them, with
+    each device's allocator figures where the backend keeps them (the
+    CPU backend does not)."""
+    import jax
+
+    devs = jax.devices()
+    mem = [d.memory_stats() or {} for d in devs]
+    return {
+        "platform": devs[0].platform,
+        "kind": devs[0].device_kind,
+        "count": len(devs),
+        "bytes_in_use": [m.get("bytes_in_use") for m in mem],
+        "peak_bytes_in_use": [m.get("peak_bytes_in_use") for m in mem],
+        "bytes_limit": [m.get("bytes_limit") for m in mem],
+    }
 
 
 class AntidoteNode:
@@ -361,10 +380,15 @@ class AntidoteNode:
             "keys": len(self.store.directory),
             "tables": {
                 t: {"rows_used": int(tab.used_rows.sum()),
-                    "n_rows": tab.n_rows}
+                    "n_rows": tab.n_rows,
+                    "device_bytes": tab.device_bytes()}
                 for t, tab in self.store.tables.items()
             },
             "durable": self.store.log is not None,
+            # the device this node really runs on, and which native
+            # planes loaded (None) or why one fell back to Python
+            "device": device_report(),
+            "native": dict(native_build.LOAD_STATE),
         }
         if self.store.mesh is not None:
             # mesh serving plane (ISSUE 10): device count, per-shard

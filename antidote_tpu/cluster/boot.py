@@ -43,10 +43,8 @@ def main(argv=None) -> int:
                          "shard share over while the cluster serves")
     args = ap.parse_args(argv)
 
-    from antidote_tpu.config import (apply_jax_platform_env,
-                                 enable_compilation_cache)
+    from antidote_tpu.config import enable_compilation_cache
 
-    apply_jax_platform_env()
     enable_compilation_cache()
 
     from antidote_tpu.cluster import (ClusterMember, ClusterNode,

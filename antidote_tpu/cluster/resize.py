@@ -144,9 +144,6 @@ def main(argv=None) -> int:
     ap.add_argument("--dc-id", type=int, default=0)
     args = ap.parse_args(argv)
 
-    from antidote_tpu.config import apply_jax_platform_env
-
-    apply_jax_platform_env()
     resize_dc(args.old_dirs.split(","), args.new_dirs.split(","),
               args.dc_id)
     print("resized; boot the new members with "

@@ -70,10 +70,8 @@ def resolve_serve_shape(log_dir, shards, max_dcs):
 def cmd_serve(args) -> int:
     import os
 
-    from antidote_tpu.config import (apply_jax_platform_env,
-                                 enable_compilation_cache)
+    from antidote_tpu.config import enable_compilation_cache
 
-    apply_jax_platform_env()
     enable_compilation_cache()
 
     from antidote_tpu import faults as _faults
@@ -286,7 +284,12 @@ def cmd_serve(args) -> int:
                 stop=stop_metrics)
     sup.start()
     server = server_box["srv"]
-    ready: dict = {"host": server.host, "port": server.port, "ready": True}
+    from antidote_tpu.api.node import device_report
+
+    dev = device_report()
+    ready: dict = {"host": server.host, "port": server.port, "ready": True,
+                   "device": {k: dev[k] for k in ("platform", "kind",
+                                                  "count")}}
     if tenants.multi:
         ready["tenants"] = list(tenants.names)
     if follower is not None:
@@ -721,9 +724,10 @@ def main(argv=None) -> int:
     sv.add_argument("--pallas", action="store_true",
                     help="dispatch the materializer hot loops to the "
                          "fused Pallas kernels where one exists (counter "
-                         "fold, set_aw add-wins fold, OR-set presence); "
-                         "interpret mode off-TPU — the XLA scan stays "
-                         "the fallback and semantics oracle")
+                         "fold, set_aw add-wins fold, OR-set presence). "
+                         "On a TPU they are compiled and a kernel the "
+                         "compiler refuses is an error; off-TPU the "
+                         "flag changes nothing (the XLA folds serve)")
     sv.add_argument("--fold-chunk", type=int, default=4096,
                     help="over-ring fold routing threshold: a replayed "
                          "key whose op log exceeds this many ops folds "

@@ -234,11 +234,9 @@ class SetAW(TopCountResolved, CRDTType):
     def resolve(self, cfg, state):
         """Device OR-set presence + compaction.  With ``cfg.use_pallas`` the
         presence comparison runs as the fused Pallas kernel
-        (materializer/pallas_kernels.py::orset_presence) — the in-path
-        dispatch VERDICT asked for; the plain-XLA comparison is the
-        fallback.  Platform-gated (pallas_kernels.in_path_ok): on CPU the
-        interpreter-mode kernel halved every serving read and the device
-        kernel loop (measured on the 1M bench child)."""
+        (materializer/pallas_kernels.py::orset_presence) wherever
+        ``pallas_kernels.in_path_ok`` routes the serving path to the
+        kernels (a TPU); otherwise it is the plain-XLA comparison."""
         elems = state["elems"]
         use_kernel = False
         if getattr(cfg, "use_pallas", False):

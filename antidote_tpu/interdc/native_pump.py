@@ -48,13 +48,10 @@ def _load_lib():
     if _lib_tried:
         return _lib
     _lib_tried = True
-    try:
-        # pinned-flag build through the shared helper (make native /
-        # make native-check provenance: the .so embeds its source sha)
-        from antidote_tpu import native_build
+    from antidote_tpu import native_build
 
-        native_build.ensure(_SRC, _SO)
-        lib = ctypes.CDLL(str(_SO))
+    lib = native_build.load("pump", _SRC, _SO)
+    if lib is not None:
         lib.pump_new.restype = ctypes.c_void_p
         lib.pump_new.argtypes = []
         lib.pump_add.restype = ctypes.c_int
@@ -75,9 +72,7 @@ def _load_lib():
         lib.pump_queued.argtypes = [ctypes.c_void_p]
         lib.pump_free.restype = None
         lib.pump_free.argtypes = [ctypes.c_void_p]
-        _lib = lib
-    except Exception:
-        _lib = None
+    _lib = lib
     return _lib
 
 

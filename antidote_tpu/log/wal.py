@@ -18,7 +18,6 @@ import errno
 import heapq
 import os
 import struct
-import subprocess
 import threading
 import time
 import zlib
@@ -26,7 +25,7 @@ from pathlib import Path
 from typing import Iterator, List, Optional, Sequence, Tuple
 import msgpack
 
-from antidote_tpu import faults
+from antidote_tpu import faults, native_build
 
 _MAGIC = 0xA17D07E1
 _HDR = struct.Struct("<III")
@@ -43,16 +42,10 @@ def _load_lib():
     if _lib_tried:
         return _lib
     _lib_tried = True
-    try:
-        if not _SO.exists() or _SO.stat().st_mtime < _SRC.stat().st_mtime:
-            subprocess.run(
-                ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", "-pthread",
-                 str(_SRC), "-o", str(_SO)],
-                check=True, capture_output=True,
-            )
-        # use_errno: a failed append/commit must surface WHICH OS error
-        # (ENOSPC vs EIO vs ...) — the read-only degraded mode keys off it
-        lib = ctypes.CDLL(str(_SO), use_errno=True)
+    # use_errno: a failed append/commit must surface WHICH OS error
+    # (ENOSPC vs EIO vs ...) — the read-only degraded mode keys off it
+    lib = native_build.load("wal", _SRC, _SO, use_errno=True)
+    if lib is not None:
         lib.wal_open.restype = ctypes.c_void_p
         lib.wal_open.argtypes = [ctypes.c_char_p, ctypes.c_int, ctypes.c_int]
         lib.wal_append.restype = ctypes.c_int64
@@ -72,9 +65,7 @@ def _load_lib():
         lib.wal_truncate.restype = ctypes.c_int
         lib.wal_truncate.argtypes = [ctypes.c_void_p, ctypes.c_int64]
         lib.wal_close.argtypes = [ctypes.c_void_p]
-        _lib = lib
-    except Exception:
-        _lib = None
+    _lib = lib
     return _lib
 
 

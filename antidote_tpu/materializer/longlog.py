@@ -30,7 +30,6 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from antidote_tpu.clock import vector as vc
-from antidote_tpu.compat import shard_map
 
 
 def include_mask(ops_vc, n_ops, base_vc, read_vc):
@@ -132,7 +131,7 @@ def sharded_assoc_fold_fn(ty, cfg, mesh, axis: str = "shard"):
         per = l // n_dev
         offsets = jnp.arange(n_dev, dtype=jnp.int32) * per
 
-        mapped = shard_map(
+        mapped = jax.shard_map(
             per_device,
             mesh=mesh,
             in_specs=(op_spec, op_spec, op_spec, op_spec, rep, rep, rep,

@@ -1,9 +1,11 @@
 """Shared build + provenance helper for the native ``.so`` planes.
 
 Every C++ module in the tree (interdc/cpp/pump.cc, proto/cpp/frontend.cc,
-log/cpp/wal.cc) compiles through ONE pinned flag set, and every build
-embeds the sha256 of its source as ``ANTIDOTE_SRC_SHA`` (each module
-exports a ``<name>_src_sha()`` getter).  ``make native`` rebuilds all of
+log/cpp/wal.cc, store/cpp/router.cc) compiles through ONE pinned flag
+set and loads through :func:`load`; the builds embed the sha256 of their
+source as ``ANTIDOTE_SRC_SHA`` (pump and frontend export a
+``<name>_src_sha()`` getter).  The ``.so`` files are git-ignored: a
+clean checkout builds them from the committed sources on first use.  ``make native`` rebuilds all of
 them; ``make native-check`` compares each checked-in binary's embedded
 sha against the current source — the drift a hand-run g++ line can't
 detect (the satellite of ISSUE 16: pump.cc's .so could silently diverge
@@ -14,9 +16,12 @@ from __future__ import annotations
 
 import ctypes
 import hashlib
+import logging
 import pathlib
 import subprocess
-from typing import List, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
+
+log = logging.getLogger(__name__)
 
 #: the ONE compile line — loaders and `make native` must agree, or the
 #: native-check comparison would chase flag drift instead of source drift
@@ -56,6 +61,32 @@ def ensure(src: pathlib.Path, so: pathlib.Path) -> pathlib.Path:
     if not so.exists() or so.stat().st_mtime < src.stat().st_mtime:
         build(src, so)
     return so
+
+
+#: plane name -> None once its .so loaded, else why it did not (compile
+#: error, missing toolchain, dlopen failure).  Every loader falls back to
+#: its Python plane on failure; this is what keeps the fallback visible —
+#: the node status ``native`` block reports it.
+LOAD_STATE: Dict[str, Optional[str]] = {}
+
+
+def load(plane: str, src: pathlib.Path, so: pathlib.Path,
+         **cdll_kw) -> Optional[ctypes.CDLL]:
+    """Build (when stale) and dlopen one native plane, recording the
+    outcome in :data:`LOAD_STATE`; None when it cannot be had."""
+    try:
+        ensure(src, so)
+        lib = ctypes.CDLL(str(so), **cdll_kw)
+    except (OSError, subprocess.CalledProcessError) as e:
+        stderr = getattr(e, "stderr", None) or b""
+        LOAD_STATE[plane] = (
+            f"{e!r} {stderr.decode(errors='replace')[-400:]}".strip()
+        )
+        log.warning("native %s plane unavailable (%s); the Python plane "
+                    "serves instead", plane, LOAD_STATE[plane])
+        return None
+    LOAD_STATE[plane] = None
+    return lib
 
 
 def embedded_sha(so: pathlib.Path, getter: str) -> Optional[str]:

@@ -34,7 +34,7 @@ GC folds and head folds were already per-shard vmapped bodies
 them across devices with no cross-device traffic on the data plane, and
 the Pallas fold kernels dispatch with SHARD-LOCAL extents inside the
 sharded step (``spmd.sharded_step_fn`` + ``pallas_kernels.
-counter_fold_local``).
+counter_fold_deltas``).
 
 On this CPU container the mesh is the 8 virtual devices the test
 harness forces (tests/conftest.py); on real TPU hardware the same code
@@ -53,7 +53,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from antidote_tpu.compat import shard_map
 from antidote_tpu.materializer import longlog
 from antidote_tpu.parallel.spmd import SHARD_AXIS
 from antidote_tpu.store.typed_table import _shard_read_latest_body
@@ -129,7 +128,7 @@ class MeshServingPlane:
         readers fall back to the locked path until the next publish."""
         if t.sharding is self.sharding:
             return
-        t.sharding = self.sharding
+        t.set_sharding(self.sharding)
         put = lambda x: jax.device_put(x, self.sharding)
         t.snap = {f: put(x) for f, x in t.snap.items()}
         t.head = {f: put(x) for f, x in t.head.items()}
@@ -176,7 +175,7 @@ class MeshServingPlane:
             )
             return resolved, fresh
 
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             body, mesh=self.mesh,
             in_specs=(spec, spec, spec, spec),
             out_specs=(spec, spec), check_vma=False,
@@ -247,7 +246,7 @@ class MeshServingPlane:
                 # rendering of stable_time_functions:get_min_time
                 return lax.pmin(jnp.min(clocks, axis=0), SHARD_AXIS)
 
-            self._pmin_fn = jax.jit(shard_map(
+            self._pmin_fn = jax.jit(jax.shard_map(
                 body, mesh=self.mesh, in_specs=(spec,), out_specs=P(),
                 check_vma=False,
             ))

@@ -26,7 +26,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from antidote_tpu.compat import shard_map
 from antidote_tpu.store.typed_table import _shard_base_select_body, _shard_read_body
 
 SHARD_AXIS = "shard"
@@ -57,7 +56,7 @@ def sharded_step_fn(ty, cfg, mesh: Mesh):
 
     With ``cfg.use_pallas`` and a counter table, the ring fold inside
     the step dispatches to the fused Pallas kernel with SHARD-LOCAL
-    extents (``pallas_kernels.counter_fold_local``): each shard's block
+    extents (``pallas_kernels.counter_fold_deltas``): each shard's block
     runs its own kernel grid inside the shard_map body, so the fold
     stays device-local on a mesh (interpret mode off-TPU).  CALLER
     CONTRACT: the kernel sums lane-0 deltas in i32, and a static step
@@ -122,7 +121,7 @@ def sharded_step_fn(ty, cfg, mesh: Mesh):
             base_state, base_vc, complete = select_body(
                 snap, snap_vc, snap_seq, rows_clip, read_vcs
             )
-            dcnt, applied = pk.counter_fold_local(
+            dcnt, applied = pk.counter_fold_deltas(
                 ops_a[rows_clip][..., 0].astype(jnp.int32),
                 ops_vc[rows_clip], read_n_ops, base_vc, read_vcs,
             )
@@ -136,7 +135,7 @@ def sharded_step_fn(ty, cfg, mesh: Mesh):
             base_state, base_vc, complete = select_body(
                 snap, snap_vc, snap_seq, rows_clip, read_vcs
             )
-            state, applied = pk.set_aw_fold_local(
+            state, applied = pk.set_aw_fold(
                 base_state, ops_a[rows_clip], ops_b[rows_clip],
                 ops_vc[rows_clip], ops_origin[rows_clip],
                 read_n_ops, base_vc, read_vcs,
@@ -156,7 +155,7 @@ def sharded_step_fn(ty, cfg, mesh: Mesh):
     spec = P(SHARD_AXIS)
     n_in = 17
     step = jax.jit(
-        shard_map(
+        jax.shard_map(
             per_shard,
             mesh=mesh,
             in_specs=(spec,) * n_in,

@@ -67,11 +67,10 @@ def _load_lib():
     if _lib_tried:
         return _lib
     _lib_tried = True
-    try:
-        from antidote_tpu import native_build
+    from antidote_tpu import native_build
 
-        native_build.ensure(_SRC, _SO)
-        lib = ctypes.CDLL(str(_SO))
+    lib = native_build.load("frontend", _SRC, _SO)
+    if lib is not None:
         lib.frontend_create.restype = ctypes.c_void_p
         lib.frontend_create.argtypes = [
             ctypes.c_char_p, ctypes.c_int, ctypes.c_int, ctypes.c_long,
@@ -122,9 +121,7 @@ def _load_lib():
         lib.frontend_stop.argtypes = [ctypes.c_void_p]
         lib.frontend_free.restype = None
         lib.frontend_free.argtypes = [ctypes.c_void_p]
-        _lib = lib
-    except Exception:
-        _lib = None
+    _lib = lib
     return _lib
 
 

@@ -12,12 +12,13 @@ with and without a compiler agree on every shard assignment.
 from __future__ import annotations
 
 import ctypes
-import subprocess
 from pathlib import Path
 from typing import Any, Sequence
 
 import msgpack
 import numpy as np
+
+from antidote_tpu import native_build
 
 _SRC = Path(__file__).parent / "cpp" / "router.cc"
 _SO = Path(__file__).parent / "cpp" / "_router.so"
@@ -38,14 +39,8 @@ def _load_lib():
     if _lib_tried:
         return _lib
     _lib_tried = True
-    try:
-        if not _SO.exists() or _SO.stat().st_mtime < _SRC.stat().st_mtime:
-            subprocess.run(
-                ["g++", "-O2", "-shared", "-fPIC", "-std=c++17",
-                 str(_SRC), "-o", str(_SO)],
-                check=True, capture_output=True,
-            )
-        lib = ctypes.CDLL(str(_SO))
+    lib = native_build.load("router", _SRC, _SO)
+    if lib is not None:
         lib.router_hash64.restype = ctypes.c_uint64
         lib.router_hash64.argtypes = [ctypes.c_char_p, ctypes.c_uint64,
                                       ctypes.c_uint64]
@@ -56,9 +51,7 @@ def _load_lib():
             ctypes.c_int64, ctypes.c_uint64, ctypes.c_int64,
             np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS"),
         ]
-        _lib = lib
-    except Exception:
-        _lib = None
+    _lib = lib
     return _lib
 
 

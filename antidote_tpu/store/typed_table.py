@@ -742,6 +742,31 @@ class TypedTable:
             victim = min(self.epochs, key=lambda e: e["used"])
             self.epochs = [e for e in self.epochs if e is not victim]
 
+    def device_bytes(self) -> Dict[int, int]:
+        """Bytes of this table's arrays (op rings, snapshot versions,
+        head, frozen epochs and serving buffers) resident on each device,
+        by device id — from the arrays' own addressable shards, so a
+        mesh-placed table shows what each device really holds."""
+        frozen = [(e["head"], e["head_vc"]) for e in self.epochs]
+        frozen += [s for s in self._serving if s is not None]
+        out: Dict[int, int] = {}
+        for x in jax.tree.leaves((
+            self.snap, self.snap_vc, self.snap_seq, self.ops_a, self.ops_b,
+            self.ops_vc, self.ops_origin, self.head, self.head_vc, frozen,
+        )):
+            if not isinstance(x, jax.Array):
+                continue
+            try:
+                for sh in x.addressable_shards:
+                    out[sh.device.id] = (
+                        out.get(sh.device.id, 0) + sh.data.nbytes
+                    )
+            except RuntimeError:
+                # donated to a commit that ran while status was read
+                # (status takes no lock); its successor is counted next
+                continue
+        return out
+
     def invalidate_epochs(self) -> None:
         """Drop every published epoch — required after any out-of-band
         table mutation (row growth, key promotion, handoff install)."""
@@ -845,6 +870,28 @@ class TypedTable:
 
         return read
 
+    def _jit_routed(self, fn):
+        """jit a routed serving read: every operand and result carries
+        the leading shard axis ([P, M', ...]).  On a mesh-placed table
+        the body runs under an explicit ``shard_map`` over that axis —
+        each device works on its own shards' block, which the vmapped
+        per-shard gathers are by construction — because Mosaic kernels
+        (the fold strategies, set_aw's presence resolve) cannot be
+        partitioned automatically."""
+        sh = self.sharding
+        if sh is not None:
+            fn = jax.shard_map(fn, mesh=sh.mesh, in_specs=sh.spec,
+                               out_specs=sh.spec, check_vma=False)
+        return jax.jit(fn)
+
+    def set_sharding(self, sharding) -> None:
+        """Adopt a new placement (the mesh plane's ``place_table``): the
+        routed read programs are built for the placement they were
+        traced under, so they are dropped with the old one."""
+        self.sharding = sharding
+        self._resolved_fns.clear()
+        self.__dict__.pop("_latest_resolved_fn", None)
+
     @functools.cached_property
     def _latest_resolved_fn(self):
         """Fold-free serving read for read VCs that dominate every commit
@@ -853,7 +900,6 @@ class TypedTable:
         ty, cfg = self.ty, self.cfg
         latest = _shard_read_latest_body(ty, cfg)
 
-        @jax.jit
         def fn(head, head_vc, rows, read_vcs):
             state, fresh = jax.vmap(latest)(head, head_vc, rows, read_vcs)
             resolved = (
@@ -863,7 +909,7 @@ class TypedTable:
             )
             return resolved, fresh
 
-        return fn
+        return self._jit_routed(fn)
 
     def _read_resolved_fn(self, strategy: str, kmax: int = 0):
         """The fused serving read: head gather + snapshot-version select +
@@ -887,7 +933,6 @@ class TypedTable:
         latest = _shard_read_latest_body(ty, cfg)
         select = _shard_base_select_body(ty, cfg)
 
-        @jax.jit
         def fn(head, head_vc, snap, snap_vc, snap_seq,
                ops_a, ops_b, ops_vc, ops_origin, rows, n_ops_rows, read_vcs):
             state_h, fresh = jax.vmap(latest)(head, head_vc, rows, read_vcs)
@@ -906,13 +951,12 @@ class TypedTable:
 
                 p, m = rows.shape
                 k, d = opv.shape[2], opv.shape[3]
-                dcnt, applied = pk._counter_fold_call(
-                    opa[..., 0].reshape(p * m, k).astype(jnp.int32),
+                dcnt, applied = pk.counter_fold_deltas(
+                    opa[..., 0].reshape(p * m, k),
                     opv.reshape(p * m, k, d),
                     n_ops_rows.reshape(p * m),
                     base_vc.reshape(p * m, d),
                     read_vcs.reshape(p * m, d),
-                    256, not pk._on_tpu(),
                 )
                 state_f = {
                     "cnt": base_state["cnt"]
@@ -925,12 +969,11 @@ class TypedTable:
                 p, m = rows.shape
                 opb, opo = gat(ops_b, rows), gat(ops_origin, rows)
                 flat = lambda x: x.reshape((p * m,) + x.shape[2:])
-                state_pm, applied = pk.set_aw_fold_local(
+                state_pm, applied = pk.set_aw_fold(
                     {f: flat(x) for f, x in base_state.items()},
                     flat(opa), flat(opb), flat(opv), flat(opo),
                     n_ops_rows.reshape(p * m),
                     base_vc.reshape(p * m, -1), read_vcs.reshape(p * m, -1),
-                    256, not pk._on_tpu(),
                 )
                 state_f = {
                     f: x.reshape((p, m) + x.shape[1:])
@@ -967,6 +1010,7 @@ class TypedTable:
             )
             return resolved, fresh, complete
 
+        fn = self._jit_routed(fn)
         self._resolved_fns[(strategy, kmax)] = fn
         return fn
 
@@ -1026,11 +1070,8 @@ class TypedTable:
             if strategy == "pallas_counter":
                 from antidote_tpu.materializer import pallas_kernels as pk
 
-                k, d = opv.shape[1], opv.shape[2]
-                dcnt, applied = pk._counter_fold_call(
-                    opa[..., 0].astype(jnp.int32),
-                    opv, n_ops_flat, base_vc, read_vcs,
-                    256, not pk._on_tpu(),
+                dcnt, applied = pk.counter_fold_deltas(
+                    opa[..., 0], opv, n_ops_flat, base_vc, read_vcs,
                 )
                 state_f = {"cnt": base_state["cnt"] + dcnt.astype(jnp.int64)}
             elif strategy == "pallas_set_aw":
@@ -1039,10 +1080,9 @@ class TypedTable:
                 opb, opo = ops_b[ss, rr], ops_origin[ss, rr]
                 if kmax:
                     opb, opo = opb[:, :kmax], opo[:, :kmax]
-                state_f, applied = pk.set_aw_fold_local(
+                state_f, applied = pk.set_aw_fold(
                     base_state, opa, opb, opv, opo,
                     n_ops_flat, base_vc, read_vcs,
-                    256, not pk._on_tpu(),
                 )
             elif strategy == "assoc":
                 opb, opo = ops_b[ss, rr], ops_origin[ss, rr]

@@ -546,9 +546,6 @@ WORKLOADS = {
 
 
 def main():
-    from antidote_tpu.config import apply_jax_platform_env
-
-    apply_jax_platform_env()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true")
     ap.add_argument("--workload", default="all",

@@ -39,7 +39,8 @@ cannot run in this image, so `vs_baseline` in the companion suites
 compares against a host-Python per-key materializer fold — the same
 fold the BEAM performs per read, minus BEAM runtime overhead (a
 baseline that FAVORS the reference).  This driver's numbers are
-absolute server-side measurements for the table in BASELINE.md.
+absolute server-side measurements (CPU-sandbox records in
+BENCH_WIRE_*.json; PERF.md says what has been measured on a chip).
 """
 
 from __future__ import annotations

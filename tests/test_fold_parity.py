@@ -18,7 +18,9 @@ KVStore's over-ring replay ladder, and the fold metrics both feed.
 """
 
 import dataclasses
+import functools
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -75,8 +77,9 @@ def _assert_states_equal(ref, got, msg):
 # ---------------------------------------------------------------------------
 
 def test_pallas_set_aw_fold_matches_oracle():
-    """Both kernel entries (host + trace-safe local), random op rings with
-    removes and ARBITRARY non-bottom bases, n_ops edges 0 and full ring."""
+    """The kernel entry from the host and from inside a jit trace (as the
+    fused serving reads call it), random op rings with removes and
+    ARBITRARY non-bottom bases, n_ops edges 0 and full ring."""
     cfg = _mk_cfg(n_shards=1)
     ty = get_type("set_aw")
     b, k, e, d = 16, cfg.ops_per_key, cfg.set_slots, cfg.max_dcs
@@ -115,12 +118,14 @@ def test_pallas_set_aw_fold_matches_oracle():
         _assert_states_equal(ref_state, got_state, f"trial{trial}")
         np.testing.assert_array_equal(
             np.asarray(ref_applied), np.asarray(got_applied))
-        got2, app2 = pk.set_aw_fold_local(
+        got2, app2 = jax.jit(
+            functools.partial(pk.set_aw_fold, block=8)
+        )(
             state, jnp.asarray(ops_a), jnp.asarray(ops_b),
             jnp.asarray(ops_vc), jnp.asarray(ops_origin),
             jnp.asarray(n_ops), jnp.asarray(base_vc),
-            jnp.asarray(read_vc), block=8)
-        _assert_states_equal(ref_state, got2, f"trial{trial} local")
+            jnp.asarray(read_vc))
+        _assert_states_equal(ref_state, got2, f"trial{trial} in-trace")
         np.testing.assert_array_equal(
             np.asarray(ref_applied), np.asarray(app2))
 

@@ -1,0 +1,195 @@
+"""The chip's compiler, asked from the CPU sandbox: AOT-compile the Pallas
+kernels and the fused serving reads for a DESCRIBED TPU v5e (no chip is
+attached; nothing runs) at the widths `console serve` really uses.
+
+Interpret mode — the only way the other tests run these kernels — cannot
+see what Mosaic refuses: i64 index maps under the package's x64 setting,
+a block that overflows scoped VMEM, an unaligned slice.  These compiles
+can, at no chip time.  A pass here is a compile, never a chip run.
+
+The topology is described inside a fixture (only the xdist worker that is
+handed this file loads the TPU library), everything built from it is built
+in fixtures or tests, and the persistent compile cache is off around the
+compiles (an entry written for a described chip cannot be read back).
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec,
+                          SingleDeviceSharding)
+
+from antidote_tpu.config import AntidoteConfig
+from antidote_tpu.crdt import get_type
+from antidote_tpu.materializer import pallas_kernels as pk
+from antidote_tpu.store import TypedTable
+
+#: kernel batch and the `console serve` default widths (set_slots,
+#: ops_per_key); D = max_dcs is 4 in bench.py and 8 in `console serve`
+ROWS, E, K = 16_384, 16, 16
+DCS = (4, 8)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "not here"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture
+def shape(one_chip, no_persistent_cache):
+    assert jax.config.jax_enable_x64, "the in-trace callers trace with x64 on"
+
+    def mk(dims, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    return mk
+
+
+def _compile(fn, *args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+    return text
+
+
+@pytest.mark.parametrize("d", DCS)
+def test_counter_fold_compiles(shape, d):
+    _compile(
+        lambda *a: pk.counter_fold_deltas(*a, interpret=False),
+        shape((ROWS, K)), shape((ROWS, K, d)), shape((ROWS,)),
+        shape((ROWS, d)), shape((ROWS, d)),
+    )
+
+
+@pytest.mark.parametrize("d", DCS)
+def test_stable_min_compiles(shape, d):
+    _compile(lambda c: pk.stable_min(c, interpret=False), shape((ROWS, d)))
+
+
+@pytest.mark.parametrize("d", DCS)
+def test_orset_presence_compiles(shape, d):
+    _compile(
+        lambda *a: pk.orset_presence(*a, interpret=False),
+        shape((ROWS, E, d)), shape((ROWS, E, d)), shape((ROWS, E)),
+    )
+
+
+@pytest.mark.parametrize("d", DCS)
+def test_set_aw_fold_compiles(shape, d):
+    """At the block the server uses: the default, chosen from the shapes
+    (256, the old literal, needs 18.9 MB / 28.0 MB of the 16 MB scoped
+    VMEM at D=4 / D=8)."""
+    assert pk.set_aw_fold_block(E, K, d) == 64
+    state = {
+        "elems": shape((ROWS, E), jnp.int64), "addvc": shape((ROWS, E, d)),
+        "rmvc": shape((ROWS, E, d)), "ovf": shape((ROWS,)),
+    }
+    _compile(
+        lambda *a: pk.set_aw_fold(*a, interpret=False),
+        state, shape((ROWS, K, 1), jnp.int64), shape((ROWS, K, 1 + d)),
+        shape((ROWS, K, d)), shape((ROWS, K)), shape((ROWS,)),
+        shape((ROWS, d)), shape((ROWS, d)),
+    )
+
+
+def test_set_aw_fold_block_fails_loudly():
+    """A configuration no block fits is an error, not a reason to leave
+    the kernel for another fold."""
+    with pytest.raises(ValueError, match="scoped VMEM"):
+        pk.set_aw_fold_block(E, K, 512)
+
+
+@pytest.mark.parametrize("tyname,strategy", [
+    ("set_aw", "pallas_set_aw"), ("counter_pn", "pallas_counter"),
+])
+def test_fused_serving_read_compiles(shape, monkeypatch, tyname, strategy):
+    """The jitted one-launch serving read of a `use_pallas` table (head
+    gather + version select + ring fold kernel + resolve, with the
+    presence kernel for set_aw) at `console serve`'s default widths and
+    its largest batch bucket, full ring (kmax=0)."""
+    # the code under test asks jax for the backend and would take its
+    # off-TPU branches here: steer it to the branch a TPU takes
+    monkeypatch.setattr(pk, "_on_tpu", lambda: True)
+    cfg = AntidoteConfig(n_shards=16, max_dcs=8, keys_per_table=1024,
+                         use_pallas=True)
+    table = TypedTable(get_type(tyname), cfg)
+    assert table._fold_strategy() == strategy
+    m = cfg.batch_buckets[-1]
+    like = lambda x: shape(x.shape, x.dtype)
+    text = _compile(
+        table._read_resolved_flat_fn(strategy, 0),
+        *jax.tree.map(like, (
+            table.head, table.head_vc, table.snap, table.snap_vc,
+            table.snap_seq, table.ops_a, table.ops_b, table.ops_vc,
+            table.ops_origin,
+        )),
+        shape((m,), jnp.int64), shape((m,), jnp.int64), shape((m,)),
+        shape((m, cfg.max_dcs)),
+    )
+    # set_aw: the fold kernel and the presence kernel
+    assert text.count("tpu_custom_call") >= (2 if tyname == "set_aw" else 1)
+
+
+@pytest.mark.parametrize("tyname,strategy", [
+    ("set_aw", "pallas_set_aw"), ("counter_pn", "pallas_counter"),
+])
+def test_mesh_routed_read_compiles(topo, no_persistent_cache, monkeypatch,
+                                   tyname, strategy):
+    """The routed [P, M'] reads of a table placed over the four chips of
+    the described host (`console serve --mesh-devices 4 --pallas`): Mosaic
+    kernels cannot be partitioned automatically, so they must sit under
+    the table's explicit shard_map — and the program needs no collective
+    (each device reads its own shards)."""
+    monkeypatch.setattr(pk, "_on_tpu", lambda: True)
+    placed = NamedSharding(Mesh(np.array(topo.devices), ("shard",)),
+                           PartitionSpec("shard"))
+    cfg = AntidoteConfig(n_shards=16, max_dcs=8, keys_per_table=1024,
+                         use_pallas=True)
+    table = TypedTable(get_type(tyname), cfg)
+    table.set_sharding(placed)  # placement only: nothing is put anywhere
+    p, m = cfg.n_shards, cfg.batch_buckets[1]
+
+    def mk(dims, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=placed)
+
+    like = lambda x: mk(x.shape, x.dtype)
+    head = jax.tree.map(like, (table.head, table.head_vc))
+    fold = _compile(
+        table._read_resolved_fn(strategy, 0), *head,
+        *jax.tree.map(like, (
+            table.snap, table.snap_vc, table.snap_seq, table.ops_a,
+            table.ops_b, table.ops_vc, table.ops_origin,
+        )),
+        mk((p, m), jnp.int64), mk((p, m)), mk((p, m, cfg.max_dcs)),
+    )
+    assert "all-gather" not in fold and "all-reduce" not in fold
+    latest = table._latest_resolved_fn.lower(
+        *head, mk((p, m), jnp.int64), mk((p, m, cfg.max_dcs))
+    ).compile().as_text()
+    assert ("tpu_custom_call" in latest) == (tyname == "set_aw")

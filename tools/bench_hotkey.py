@@ -215,12 +215,10 @@ def run_child(l_ops: int, repeats: int) -> dict:
               for f, (s, dt) in ty.state_spec(cfg).items()}
     jra, jrb, jrv, jro, jrn, jrbase, jrread = map(
         jnp.asarray, (ra, rb, rv, ro, rn, rbase, rread))
-    interpret = not pk._on_tpu()
     (p_state, p_applied), s_pallas = timed(
         "pallas_ring",
-        lambda: pk.set_aw_fold_local(
-            rstate, jra, jrb, jrv, jro, jrn, jrbase, jrread,
-            block=256, interpret=interpret),
+        lambda: pk.set_aw_fold(
+            rstate, jra, jrb, jrv, jro, jrn, jrbase, jrread),
         repeats)
     results["pallas_ring"] = s_pallas
     # parity for the kernel: oracle fold_batch over a slice of rings
@@ -354,9 +352,6 @@ def main(argv=None) -> int:
     if args.smoke and args.l_ops == 1_048_576:
         args.l_ops = 65_536
     if args.one:
-        from antidote_tpu.config import apply_jax_platform_env
-
-        apply_jax_platform_env()
         print(json.dumps(run_child(args.l_ops, args.repeats)))
         return 0
     return run_parent(args)

@@ -284,9 +284,6 @@ def main(argv=None) -> int:
     if args.smoke and args.keys == 65536:
         args.keys, args.window = 8192, 1.0
     if args.one:
-        from antidote_tpu.config import apply_jax_platform_env
-
-        apply_jax_platform_env()
         print(json.dumps(run_child(args.one, args.keys, args.window,
                                    args.batch)))
         return 0

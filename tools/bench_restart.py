@@ -129,9 +129,6 @@ def child_main(argv) -> int:
     n_keys = int(argv[1])
     log_dir = argv[2]
     budget = int(argv[3]) if len(argv) > 3 else 0
-    from antidote_tpu.config import apply_jax_platform_env
-
-    apply_jax_platform_env()
     t0 = time.monotonic()
     if phase == "populate-cold":
         # beyond-RAM populate (ISSUE 13): resident rows bounded by the
