@@ -432,6 +432,12 @@ class AntidoteNode:
             return {"count": s["count"], "mean": round(s["mean"], 2),
                     "p50": s["p50"], "p99": s["p99"]}
 
+        def _hist_ms(h):
+            s = h.summary()
+            return {"count": s["count"],
+                    "sum_ms": s["count"] * s["mean"] * 1e3,
+                    "p50_ms": s["p50"] * 1e3, "p99_ms": s["p99"] * 1e3}
+
         wlog = self.store.log
         out["write_plane"] = {
             "merge_width": _hist(self.metrics.commit_merge_width),
@@ -442,6 +448,13 @@ class AntidoteNode:
             "wal_segments": wlog.n_segments if wlog is not None else 0,
             "segment_depth_bytes": (wlog.segment_depths()
                                     if wlog is not None else []),
+            # commit-path split (ISSUE 24): `group` is the lock-held
+            # time of a write-bearing commit round
+            # (antidote_commit_seconds); `phases` certify / wal_append /
+            # scatter / fsync_wait / listeners / publish sum to it
+            # (`freeze` lies inside publish, `ack` follows the lock)
+            "group": _hist_ms(self.metrics.commit_seconds),
+            "phases": self.txm.phases.status(),
         }
         # checkpoint / fast-restart view (ISSUE 8): last published image
         # stamp, size, age, and how much tail a crash-now restart would

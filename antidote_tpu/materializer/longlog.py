@@ -30,6 +30,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from antidote_tpu.clock import vector as vc
+from antidote_tpu.obs.trace import device_program
 
 
 def include_mask(ops_vc, n_ops, base_vc, read_vc):
@@ -145,4 +146,4 @@ def sharded_assoc_fold_fn(ty, cfg, mesh, axis: str = "shard"):
         )
         return ty.delta_apply(state0, delta), applied
 
-    return jax.jit(fn)
+    return device_program("giant_fold", fn)

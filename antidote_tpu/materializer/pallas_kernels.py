@@ -33,6 +33,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from antidote_tpu.obs.trace import device_program
+
 _I32_MAX = jnp.iinfo(jnp.int32).max
 #: block index 0 for BlockSpec index maps, pinned to i32 (see module doc)
 _Z = np.int32(0)
@@ -134,7 +136,7 @@ def _counter_fold_kernel(deltas_ref, ops_vc_ref, n_ops_ref, base_vc_ref,
     )
 
 
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+@device_program("counter_fold", static_argnames=("block", "interpret"))
 def _counter_fold_call(deltas, ops_vc, n_ops, base_vc, read_vc,
                        block: int, interpret: bool):
     b0 = deltas.shape[0]
@@ -159,6 +161,7 @@ def _counter_fold_call(deltas, ops_vc, n_ops, base_vc, read_vc,
             jax.ShapeDtypeStruct((b, 1), jnp.int32),
         ],
         interpret=interpret,
+        name="antidote_counter_fold",
     )(deltas, ops_vc, n_ops, base_vc, read_vc)
     return cnt[:b0, 0], applied[:b0, 0]
 
@@ -243,7 +246,7 @@ def _stable_min_kernel(clocks_ref, out_ref):
     )
 
 
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+@device_program("stable_min", static_argnames=("block", "interpret"))
 def _stable_min_call(clocks, block: int, interpret: bool):
     clocks = _pad_to(clocks, block, 0, fill=_I32_MAX)
     n, d = clocks.shape
@@ -254,6 +257,7 @@ def _stable_min_call(clocks, block: int, interpret: bool):
         out_specs=pl.BlockSpec((1, d), lambda i: (_Z, _Z)),
         out_shape=jax.ShapeDtypeStruct((1, d), jnp.int32),
         interpret=interpret,
+        name="antidote_stable_min",
     )(clocks)
     return out[0]
 
@@ -394,7 +398,7 @@ def _set_aw_fold_kernel(elems_lo_ref, elems_hi_ref, addvc_ref, rmvc_ref,
     out_applied_ref[:] = applied
 
 
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+@device_program("set_aw_fold", static_argnames=("block", "interpret"))
 def _set_aw_fold_call(elems_lo, elems_hi, addvc, rmvc, ovf,
                       h_lo, h_hi, is_rm, obs, ops_vc, ops_origin,
                       n_ops, base_vc, read_vc, block: int, interpret: bool):
@@ -447,6 +451,7 @@ def _set_aw_fold_call(elems_lo, elems_hi, addvc, rmvc, ovf,
             jax.ShapeDtypeStruct((b, 1), jnp.int32),
         ],
         interpret=interpret,
+        name="antidote_set_aw_fold",
     )(elems_lo, elems_hi, addvc_t, rmvc_t, ovf,
       h_lo, h_hi, is_rm, obs_t, ops_vc_t, ops_origin, own,
       n_ops, base_vc, read_vc)
@@ -532,7 +537,7 @@ def _presence_kernel(addvc_ref, rmvc_ref, elems_lo_ref, out_ref):
     out_ref[:] = present.astype(jnp.int32)
 
 
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+@device_program("orset_presence", static_argnames=("block", "interpret"))
 def _presence_call(addvc, rmvc, elems_lo, block: int, interpret: bool):
     b0 = addvc.shape[0]
     addvc = _pad_to(addvc, block, 0)
@@ -548,6 +553,7 @@ def _presence_call(addvc, rmvc, elems_lo, block: int, interpret: bool):
         out_specs=_row(block, e),
         out_shape=jax.ShapeDtypeStruct((b, e), jnp.int32),
         interpret=interpret,
+        name="antidote_orset_presence",
     )(addvc, rmvc, elems_lo)
     return out[:b0]
 

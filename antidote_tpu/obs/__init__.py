@@ -18,7 +18,12 @@ from antidote_tpu.obs.metrics import (
     net_metrics,
 )
 from antidote_tpu.obs.server import MetricsServer
-from antidote_tpu.obs.trace import Timer, trace_span
+from antidote_tpu.obs.trace import (
+    PhaseAccumulator,
+    StageAccumulator,
+    device_program,
+    span,
+)
 
 __all__ = [
     "Counter",
@@ -29,7 +34,9 @@ __all__ = [
     "NodeMetrics",
     "MetricsServer",
     "net_metrics",
-    "Timer",
     "install_error_monitor",
-    "trace_span",
+    "PhaseAccumulator",
+    "StageAccumulator",
+    "device_program",
+    "span",
 ]

@@ -9,7 +9,7 @@ The metric set mirrors ``antidote_stats_collector``
   antidote_aborted_transactions_total counter
   antidote_operations_total{type}     counter (read | read_async | update)
 
-plus framework-native extras (device launch timing, commit batch sizes).
+plus framework-native extras (serving-stage timing, commit batch sizes).
 Exposition follows the prometheus text format so the reference's Grafana
 dashboard queries (monitoring/Antidote-Dashboard.json) keep working.
 """
@@ -398,11 +398,6 @@ class NodeMetrics:
             "antidote_operations_total", "Operations by type", ("type",)
         )
         # framework-native extras
-        self.device_launch_seconds = r.histogram(
-            "antidote_device_launch_seconds",
-            "Wall time of device kernel launches (s)",
-            buckets=(0.0001, 0.0005, 0.001, 0.005, 0.01, 0.05, 0.1, 0.5, 1, 5),
-        )
         self.commit_batch_size = r.histogram(
             "antidote_commit_batch_size", "Effects per commit batch",
             buckets=(1, 2, 4, 8, 16, 64, 256, 1024, 4096, 16384),
@@ -460,7 +455,8 @@ class NodeMetrics:
         )
         self.server_request_seconds = r.histogram(
             "antidote_server_request_seconds",
-            "Wire-server request latency, admission to reply (s)",
+            "Wire-server request latency, frame arrival to reply handed "
+            "to the socket (s)",
             buckets=(0.0005, 0.001, 0.005, 0.01, 0.05, 0.1, 0.5, 1, 5, 30),
         )
         self.commit_seconds = r.histogram(

@@ -1,46 +1,223 @@
-"""Per-launch timing and JAX profiler hooks.
+"""Spans, stage records and stable device-program names: the one tracing
+facility of the serving and commit paths.
 
-The reference has no per-request tracing (SURVEY §5 notes the gap and asks
-the rebuild to add profiler hooks from day one).  ``Timer`` feeds the
-``antidote_device_launch_seconds`` histogram; ``trace_span`` wraps a block
-in a ``jax.profiler.TraceAnnotation`` when profiling is active, and is a
-plain timer otherwise.
+Three pieces, all always on (no switch, flag or environment variable):
+
+* :func:`span` — a host span on the profiler's own clock.  A
+  ``jax.profiler.TraceAnnotation`` (TraceMe level 1) and nothing else: it
+  lands in the xplane's host plane beside the device's "XLA Ops" line
+  while a profiler session runs and is inert otherwise.  Per batch or
+  commit group, never per request.
+* :class:`StageAccumulator` / :class:`PhaseAccumulator` — what
+  ``node_status()`` reports: a request's stage record (plain
+  ``time.monotonic()`` stamps carried by the request) is folded into its
+  path's sums by ONE call (:meth:`StageAccumulator.close`, one lock take)
+  when the reply has been handed to the socket; a commit group's phase
+  stamps by one call per group.
+* :func:`device_program` — ``jax.jit`` under a stable name, so the
+  trace's "XLA Modules" line reads ``jit_antidote_<what>`` and the ops
+  inside carry a ``jax.named_scope`` of the same name.
 """
 
 from __future__ import annotations
 
-import contextlib
-import time
+import functools
+import heapq
+import threading
+from typing import Dict, List, Sequence, Tuple
+
+import jax
+
+#: prefix of every device program's name (module ``jit_antidote_<what>``)
+PROGRAM_PREFIX = "antidote_"
 
 
-class Timer:
-    """Context manager: measure a block, optionally feed a histogram."""
-
-    def __init__(self, histogram=None):
-        self.histogram = histogram
-        self.elapsed = 0.0
-
-    def __enter__(self):
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        self.elapsed = time.perf_counter() - self._t0
-        if self.histogram is not None:
-            self.histogram.observe(self.elapsed)
-        return False
+def span(name: str, **ids):
+    """Host span ``name`` (ids ride as the annotation's metadata) on the
+    device trace's clock; use as a context manager."""
+    return jax.profiler.TraceAnnotation(name, **ids)
 
 
-@contextlib.contextmanager
-def trace_span(name: str, histogram=None):
-    """Named span: shows up in a JAX profiler trace (``jax.profiler
-    .start_trace``) and in the launch-seconds histogram."""
-    import jax
+def device_program(what: str, fn=None, **jit_kw):
+    """``jax.jit(fn, **jit_kw)`` named ``antidote_<what>``: the lowered
+    module is ``jit_antidote_<what>`` and every op inside sits under a
+    ``jax.named_scope`` of that name.  The body, its shapes and its
+    donation are the caller's, untouched.  Usable as a decorator."""
+    name = PROGRAM_PREFIX + what
 
-    t0 = time.perf_counter()
-    try:
-        with jax.profiler.TraceAnnotation(name):
-            yield
-    finally:
-        if histogram is not None:
-            histogram.observe(time.perf_counter() - t0)
+    def wrap(f):
+        # wraps: jit resolves static/donated argument NAMES through the
+        # signature, which has to stay the body's
+        @functools.wraps(f)
+        def program(*args, **kwargs):
+            with jax.named_scope(name):
+                return f(*args, **kwargs)
+
+        program.__name__ = program.__qualname__ = name
+        return jax.jit(program, **jit_kw)
+
+    return wrap if fn is None else wrap(fn)
+
+
+# ---------------------------------------------------------------------------
+# per-request stage records (the read path and every other wire request)
+# ---------------------------------------------------------------------------
+#: the stamps of a stage record, in path order; a stage is named after the
+#: stamp that closes it and runs from the previous stamp the request took
+STAMPS = ("arrive", "taken", "submit", "dequeued", "launched", "wb_start",
+          "synced", "ready", "sent")
+#: stage closed by each stamp after the first.  The stamp ``ready`` closes
+#: ``wb_host`` when the writeback stage synced the device for this request
+#: and ``exec`` otherwise (the locked worker's commit group or read, or a
+#: request served whole on its connection thread).
+STAGE_OF = {"taken": "cross", "submit": "decode", "dequeued": "parked",
+            "launched": "launch", "wb_start": "wb_wait",
+            "synced": "device_wait", "ready": "wb_host", "sent": "reply"}
+STAGES = ("cross", "decode", "parked", "launch", "wb_wait", "device_wait",
+          "wb_host", "exec", "reply")
+_STAGE_IDX = {s: i for i, s in enumerate(STAGES)}
+_SYNCED = STAMPS.index("synced")
+_EXEC, _WB_HOST = _STAGE_IDX["exec"], _STAGE_IDX["wb_host"]
+#: per stamp index > 0, the index of its stage in STAGES
+_CLOSES = [None] + [_STAGE_IDX[STAGE_OF[s]] for s in STAMPS[1:]]
+assert _CLOSES == [None, 0, 1, 2, 3, 4, 5, 6, 8]  # StageAccumulator.close
+SLOW_KEPT = 8
+
+
+def _new_path() -> list:
+    #: [per-stage sums (s), per-stage counts, total sum, count]
+    return [[0.0] * len(STAGES), [0] * len(STAGES), 0.0, 0]
+
+
+class StageAccumulator:
+    """Per-path sums of request stages, and the slowest few records since
+    the last status read.  ``close`` is the one call a request makes,
+    after its reply left: one lock take per request."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._paths: Dict[str, list] = {}
+        #: min-heap of (total, tiebreak, path, rid, batch, stamps)
+        self._slow: List[tuple] = []
+        self._n = 0
+
+    def close(self, path: str, rid: Tuple[int, int], batch_id: int,
+              stamps: Sequence[float]) -> float:
+        """Fold one finished request.  ``stamps`` follows :data:`STAMPS`
+        (0.0 = not taken; the first and last are always taken).  Returns
+        the request's total (arrive → sent) in seconds."""
+        (t_arrive, t_taken, t_submit, t_dequeued, t_launched, t_wb_start,
+         t_synced, t_ready, t_sent) = stamps
+        total = t_sent - t_arrive
+        with self._lock:
+            p = self._paths.get(path)
+            if p is None:
+                p = self._paths[path] = _new_path()
+            s, c = p[0], p[1]
+            # unrolled over STAMPS (this runs once per request): each
+            # stamp taken closes its stage, from the previous one taken
+            prev = t_arrive
+            if t_taken:
+                s[0] += t_taken - prev; c[0] += 1; prev = t_taken
+            if t_submit:
+                s[1] += t_submit - prev; c[1] += 1; prev = t_submit
+            if t_dequeued:
+                s[2] += t_dequeued - prev; c[2] += 1; prev = t_dequeued
+            if t_launched:
+                s[3] += t_launched - prev; c[3] += 1; prev = t_launched
+            if t_wb_start:
+                s[4] += t_wb_start - prev; c[4] += 1; prev = t_wb_start
+            if t_synced:
+                s[5] += t_synced - prev; c[5] += 1; prev = t_synced
+            if t_ready:
+                i = _WB_HOST if t_synced else _EXEC
+                s[i] += t_ready - prev; c[i] += 1; prev = t_ready
+            s[8] += t_sent - prev; c[8] += 1
+            p[2] += total
+            p[3] += 1
+            slow = self._slow
+            if len(slow) < SLOW_KEPT:
+                self._n += 1
+                heapq.heappush(slow, (total, self._n, path, rid, batch_id,
+                                      tuple(stamps)))
+            elif total > slow[0][0]:
+                self._n += 1
+                heapq.heapreplace(slow, (total, self._n, path, rid,
+                                         batch_id, tuple(stamps)))
+        return total
+
+    def status(self) -> dict:
+        """``{"paths": {path: {stage: {sum_ms, count}, "total": ...}},
+        "slow_requests": [...]}``; reading it starts a new slow window."""
+        with self._lock:
+            paths = {k: ([*p[0]], [*p[1]], p[2], p[3])
+                     for k, p in self._paths.items()}
+            slow, self._slow = self._slow, []
+        out: dict = {"paths": {}, "slow_requests": []}
+        for path, (sums, counts, total, n) in sorted(paths.items()):
+            blk = {s: {"sum_ms": sums[i] * 1e3, "count": counts[i]}
+                   for i, s in enumerate(STAGES) if counts[i]}
+            blk["total"] = {"sum_ms": total * 1e3, "count": n}
+            out["paths"][path] = blk
+        slow.sort(key=lambda r: r[0], reverse=True)
+        for total, _n, path, rid, batch_id, stamps in slow:
+            stages, prev = {}, stamps[0]
+            for idx, t in zip(_CLOSES, stamps):
+                if t and idx is not None:
+                    if idx == _WB_HOST and not stamps[_SYNCED]:
+                        idx = _EXEC
+                    stages[STAGES[idx]] = round((t - prev) * 1e3, 3)
+                    prev = t
+            out["slow_requests"].append({
+                "id": [int(rid[0]), int(rid[1])], "path": path,
+                "batch": int(batch_id), "total_ms": round(total * 1e3, 3),
+                "stages_ms": stages,
+            })
+        return out
+
+
+# ---------------------------------------------------------------------------
+# per-group commit phases
+# ---------------------------------------------------------------------------
+#: phases of one commit group inside the commit lock, in order: they sum
+#: to the group's lock-held time (``group``, = antidote_commit_seconds)
+COMMIT_PHASES = ("certify", "wal_append", "scatter", "fsync_wait",
+                 "listeners", "publish")
+#: reported beside them: ``freeze`` is the freeze_serving dispatch inside
+#: ``publish``; ``stage`` and ``ack`` are the wire server's merge point
+#: before the lock (a transaction started and its updates turned into
+#: effects, per member) and after it (results fanned back out)
+EXTRA_PHASES = ("freeze", "stage", "ack")
+
+
+class PhaseAccumulator:
+    """Sums of commit-group phases; one ``add_group`` call per group."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._sums = {p: 0.0 for p in COMMIT_PHASES + EXTRA_PHASES}
+        self._counts = {p: 0 for p in COMMIT_PHASES + EXTRA_PHASES}
+
+    def add_group(self, stamps: Sequence[float], freeze_s: float) -> None:
+        """``stamps``: lock taken, then the end of each of
+        :data:`COMMIT_PHASES` (a phase the group skipped repeats the stamp
+        before it, so the phases always sum to last − first)."""
+        with self._lock:
+            prev = stamps[0]
+            for p, t in zip(COMMIT_PHASES, stamps[1:]):
+                self._sums[p] += t - prev
+                self._counts[p] += 1
+                prev = t
+            self._sums["freeze"] += freeze_s
+            self._counts["freeze"] += 1
+
+    def add(self, phase: str, seconds: float) -> None:
+        with self._lock:
+            self._sums[phase] += seconds
+            self._counts[phase] += 1
+
+    def status(self) -> dict:
+        with self._lock:
+            return {p: {"sum_ms": self._sums[p] * 1e3,
+                        "count": self._counts[p]}
+                    for p in self._sums}

@@ -54,6 +54,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from antidote_tpu.materializer import longlog
+from antidote_tpu.obs.trace import device_program
 from antidote_tpu.parallel.spmd import SHARD_AXIS
 from antidote_tpu.store.typed_table import _shard_read_latest_body
 
@@ -175,7 +176,7 @@ class MeshServingPlane:
             )
             return resolved, fresh
 
-        return jax.jit(jax.shard_map(
+        return device_program("mesh_gather", jax.shard_map(
             body, mesh=self.mesh,
             in_specs=(spec, spec, spec, spec),
             out_specs=(spec, spec), check_vma=False,
@@ -246,7 +247,7 @@ class MeshServingPlane:
                 # rendering of stable_time_functions:get_min_time
                 return lax.pmin(jnp.min(clocks, axis=0), SHARD_AXIS)
 
-            self._pmin_fn = jax.jit(jax.shard_map(
+            self._pmin_fn = device_program("mesh_pmin", jax.shard_map(
                 body, mesh=self.mesh, in_specs=(spec,), out_specs=P(),
                 check_vma=False,
             ))

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from antidote_tpu.obs.trace import device_program
 from antidote_tpu.store.typed_table import _shard_base_select_body, _shard_read_body
 
 SHARD_AXIS = "shard"
@@ -154,7 +155,8 @@ def sharded_step_fn(ty, cfg, mesh: Mesh):
 
     spec = P(SHARD_AXIS)
     n_in = 17
-    step = jax.jit(
+    step = device_program(
+        "spmd_step",
         jax.shard_map(
             per_shard,
             mesh=mesh,

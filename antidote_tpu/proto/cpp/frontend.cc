@@ -259,11 +259,20 @@ void push_be32(std::vector<uint8_t>& o, uint32_t v) {
 // ---------------------------------------------------------------------
 // core structures
 // ---------------------------------------------------------------------
+// steady_clock is CLOCK_MONOTONIC here, the clock of Python's
+// time.monotonic(): stage stamps taken on either side compare directly
+long now_us() {
+  return long(std::chrono::duration_cast<std::chrono::microseconds>(
+                  std::chrono::steady_clock::now().time_since_epoch())
+                  .count());
+}
+
 struct Frame {
   long conn_id;
   int kind;  // 0 = conn closed, 1 = admitted frame, 2 = shed (aux = hint)
   long aux;
   std::vector<uint8_t> payload;
+  long t_arrive_us = 0;  // io thread, frame complete (stage stamp t_arrive)
 };
 
 struct Conn {
@@ -278,6 +287,11 @@ struct Conn {
   bool closed = false;
   bool want_out = false;
   bool rd_eof = false;  // peer half-closed; drain replies, then close
+  // reply-stage accounting: bytes of `out` already dropped from the
+  // buffer, and per frontend_send reply its (end offset in the conn's
+  // whole output stream, time handed over) until its last byte is written
+  uint64_t out_base = 0;
+  std::deque<std::pair<uint64_t, long>> sends;
 };
 
 struct ObjSpan {
@@ -326,6 +340,10 @@ struct Frontend {
   // stats (all under mu except where noted)
   long st_accept = 0, st_closed = 0, st_frames = 0, st_hits = 0,
        st_hit_objs = 0, st_shed = 0, st_fwd = 0, st_bad_frame = 0;
+  // stage accounting (ISSUE 24): arrival -> taken by Python, summed over
+  // admitted frames, and frontend_send -> last byte written
+  long st_cross_wait_us = 0, st_cross_frames = 0, st_send_wait_us = 0,
+       st_send_frames = 0;
   std::atomic<long> st_drains{0};
 
   std::vector<ObjSpan> scratch_objs;
@@ -359,19 +377,35 @@ void disarm_out(Frontend* f, Conn& c) {
 
 // flush as much buffered output as the socket accepts (mu held)
 void flush_out(Frontend* f, Conn& c) {
+  bool again = false;
   while (c.fd >= 0 && c.out_off < c.out.size()) {
     ssize_t w = send(c.fd, c.out.data() + c.out_off,
                      c.out.size() - c.out_off, MSG_DONTWAIT | MSG_NOSIGNAL);
     if (w > 0) {
       c.out_off += size_t(w);
     } else if (w < 0 && (errno == EAGAIN || errno == EWOULDBLOCK)) {
-      arm_out(f, c);
-      return;
+      again = true;
+      break;
     } else {
-      return;  // peer gone; the read side will close the conn
+      break;  // peer gone; the read side will close the conn
     }
   }
+  // replies Python handed over whose last byte is now written
+  uint64_t sent = c.out_base + c.out_off;
+  if (!c.sends.empty() && c.sends.front().first <= sent) {
+    long now = now_us();
+    while (!c.sends.empty() && c.sends.front().first <= sent) {
+      f->st_send_wait_us += now - c.sends.front().second;
+      ++f->st_send_frames;
+      c.sends.pop_front();
+    }
+  }
+  if (again) {
+    arm_out(f, c);
+    return;
+  }
   if (c.out_off >= c.out.size()) {
+    c.out_base += c.out.size();
     c.out.clear();
     c.out_off = 0;
     disarm_out(f, c);
@@ -407,9 +441,10 @@ void close_conn(Frontend* f, long cid) {
   c.in.clear();
   c.in.shrink_to_fit();
   c.out.clear();
+  c.sends.clear();
   // conn-drop sentinel: the bridge tears down the conn worker and
   // aborts orphaned interactive txns (Handler.handle's finally)
-  f->q.push_back(Frame{cid, 0, 0, {}});
+  f->q.push_back(Frame{cid, 0, 0, {}, 0});
   f->cv.notify_all();
   if (c.pending <= 0) f->conns.erase(it);
 }
@@ -604,7 +639,7 @@ void on_frame(Frontend* f, std::unique_lock<std::mutex>& lk, long cid,
       // in-flight Python replies must keep per-conn reply order — both
       // cross as a shed frame the bridge answers in the frame's dialect
       c->pending += 1;
-      Frame fr{cid, 2, hint, {}};
+      Frame fr{cid, 2, hint, {}, now_us()};
       fr.payload.assign(payload, payload + len);
       enqueue(f, lk, std::move(fr));
     } else {
@@ -621,7 +656,7 @@ void on_frame(Frontend* f, std::unique_lock<std::mutex>& lk, long cid,
   c->pending += 1;
   c->admitted += 1;
   ++f->st_fwd;
-  Frame fr{cid, 1, 0, {}};
+  Frame fr{cid, 1, 0, {}, now_us()};
   fr.payload.assign(payload, payload + len);
   enqueue(f, lk, std::move(fr));
 }
@@ -835,7 +870,8 @@ int frontend_port(void* h) {
 }
 
 // pack the drained crossing like pump_take_batch: payloads back-to-back
-// in `out`, 4 longs per frame in `descs` (conn_id, kind, len, aux).
+// in `out`, 5 longs per frame in `descs` (conn_id, kind, len, aux,
+// t_arrive_us: the io thread's monotonic stamp of the complete frame).
 // Returns n frames, 0 on timeout, -1 when stopped, -2 when the first
 // frame alone exceeds `cap` (descs[0..3] then carry its needs).
 long frontend_take_batch(void* h, uint8_t* out, long cap, long* descs,
@@ -848,6 +884,7 @@ long frontend_take_batch(void* h, uint8_t* out, long cap, long* descs,
   }
   if (f->q.empty()) return f->stop.load() ? -1 : 0;
   long n = 0, used = 0;
+  long now = now_us();
   while (n < max_n && !f->q.empty()) {
     Frame& fr = f->q.front();
     long need = long(fr.payload.size());
@@ -862,10 +899,15 @@ long frontend_take_batch(void* h, uint8_t* out, long cap, long* descs,
       break;
     }
     memcpy(out + used, fr.payload.data(), size_t(need));
-    descs[n * 4 + 0] = fr.conn_id;
-    descs[n * 4 + 1] = fr.kind;
-    descs[n * 4 + 2] = need;
-    descs[n * 4 + 3] = fr.aux;
+    descs[n * 5 + 0] = fr.conn_id;
+    descs[n * 5 + 1] = fr.kind;
+    descs[n * 5 + 2] = need;
+    descs[n * 5 + 3] = fr.aux;
+    descs[n * 5 + 4] = fr.t_arrive_us;
+    if (fr.kind == 1) {
+      f->st_cross_wait_us += now - fr.t_arrive_us;
+      ++f->st_cross_frames;
+    }
     used += need;
     ++n;
     f->q.pop_front();
@@ -899,6 +941,7 @@ void frontend_send(void* h, long conn_id, const uint8_t* buf, long len,
   if (!c.closed && len > 0) {
     bool was_empty = c.out.empty();
     c.out.insert(c.out.end(), buf, buf + len);
+    c.sends.emplace_back(c.out_base + c.out.size(), now_us());
     if (was_empty) f->out_dirty.push_back(conn_id);
     wake(f);
   } else if (!c.closed && c.rd_eof && c.pending <= 0) {
@@ -988,15 +1031,18 @@ void frontend_set_clockless_ok(void* h, int on) {
 
 // stats snapshot: [accepted, closed, frames, native_hits, hit_objects,
 //                  sheds, forwarded, drains, mirror_size, in_flight,
-//                  open_conns, bad_frames]
+//                  open_conns, bad_frames, cross_wait_us, cross_frames,
+//                  send_wait_us, send_frames]
 void frontend_stats(void* h, long* out, int n) {
   Frontend* f = static_cast<Frontend*>(h);
   std::lock_guard<std::mutex> lk(f->mu);
-  long vals[12] = {f->st_accept, f->st_closed, f->st_frames, f->st_hits,
+  long vals[16] = {f->st_accept, f->st_closed, f->st_frames, f->st_hits,
                    f->st_hit_objs, f->st_shed, f->st_fwd,
                    f->st_drains.load(), long(f->mirror.size()),
-                   f->g_inflight, f->n_open, f->st_bad_frame};
-  for (int i = 0; i < n && i < 12; ++i) out[i] = vals[i];
+                   f->g_inflight, f->n_open, f->st_bad_frame,
+                   f->st_cross_wait_us, f->st_cross_frames,
+                   f->st_send_wait_us, f->st_send_frames};
+  for (int i = 0; i < n && i < 16; ++i) out[i] = vals[i];
 }
 
 void frontend_stop(void* h) {

@@ -134,7 +134,8 @@ def _packb(v) -> bytes:
 class NativeFrontend:
     """Owns the client listen socket; yields (conn_id, kind, aux,
     payload) frames.  kind 0 = conn closed, 1 = admitted frame,
-    2 = admission-shed frame (aux carries the retry hint)."""
+    2 = admission-shed frame (aux carries the retry hint); every frame
+    carries the io thread's arrival stamp."""
 
     _BATCH = 512
 
@@ -142,15 +143,22 @@ class NativeFrontend:
     K_FRAME = 1
     K_SHED = 2
 
+    #: cross_wait_us/cross_frames: frame complete on the io thread ->
+    #: taken by Python, over admitted frames; send_wait_us/send_frames:
+    #: ``send`` -> the reply's last byte written to the socket
     STAT_FIELDS = ("accepted", "closed", "frames", "native_hits",
                    "hit_objects", "sheds", "forwarded", "drains",
-                   "mirror_size", "in_flight", "open_conns", "bad_frames")
+                   "mirror_size", "in_flight", "open_conns", "bad_frames",
+                   "cross_wait_us", "cross_frames", "send_wait_us",
+                   "send_frames")
+    #: longs per frame in the take_batch descriptor
+    _DESC = 5
 
     def __init__(self, lib, h):
         self._lib = lib
         self._h = h
         self._buf = ctypes.create_string_buffer(1 << 20)
-        self._descs = (ctypes.c_long * (4 * self._BATCH))()
+        self._descs = (ctypes.c_long * (self._DESC * self._BATCH))()
 
     @staticmethod
     def create(host: str, port: int, max_connections: int,
@@ -177,7 +185,9 @@ class NativeFrontend:
 
     def take_batch(self, timeout_ms: int) -> list:
         """Drain up to _BATCH crossings — [(conn_id, kind, aux,
-        payload)], [] after timeout or once stopped."""
+        payload, t_arrive)], [] after timeout or once stopped.
+        ``t_arrive`` is the io thread's ``time.monotonic()`` (seconds)
+        when the frame was complete."""
         h = self._h  # capture: close() may null the handle concurrently
         if h is None:
             return []
@@ -191,15 +201,15 @@ class NativeFrontend:
             return self.take_batch(timeout_ms)
         if n <= 0:
             return []
-        d = self._descs
-        total = sum(d[i * 4 + 2] for i in range(n))
+        d = self._descs[:self._DESC * n]
+        total = sum(d[2::self._DESC])
         raw = ctypes.string_at(self._buf, total)
         out = []
         off = 0
-        for i in range(n):
-            ln = d[i * 4 + 2]
-            out.append((int(d[i * 4]), int(d[i * 4 + 1]),
-                        int(d[i * 4 + 3]), raw[off:off + ln]))
+        for i in range(0, len(d), self._DESC):
+            ln = d[i + 2]
+            out.append((d[i], d[i + 1], d[i + 3], raw[off:off + ln],
+                        d[i + 4] * 1e-6))
             off += ln
         return out
 

@@ -42,6 +42,7 @@ from antidote_tpu.overload import (
     check_deadline,
     deadline_from_ms,
 )
+from antidote_tpu.obs.trace import StageAccumulator, span
 from antidote_tpu.tenancy import TenantLanes, TenantRegistry
 from antidote_tpu.proto import apb
 from antidote_tpu.proto.proxy import ProxyExhausted, ProxyPlane
@@ -69,7 +70,9 @@ class _StaticWork:
 
     __slots__ = ("kind", "objects", "updates", "clock", "event", "result",
                  "error", "deadline", "t_submit", "wants_bytes",
-                 "reply_bytes", "txid", "tenant")
+                 "reply_bytes", "txid", "tenant", "t_dequeued",
+                 "t_launched", "t_wb_start", "t_synced", "t_ready",
+                 "batch_id")
 
     def __init__(self, kind, objects=None, updates=None, clock=None,
                  deadline=None, wants_bytes=False, txid=None, tenant=None):
@@ -91,13 +94,57 @@ class _StaticWork:
         #: batch dispatcher DEQUEUES the work — a request that outlived
         #: its caller while parked is aborted, not executed
         self.deadline: Optional[float] = deadline
-        #: submit timestamp (stage_parked histogram)
+        #: submit timestamp (stage_parked histogram) — and, with the
+        #: stamps below, this request's STAGE RECORD (ISSUE 24): plain
+        #: ``time.monotonic()`` values written by whichever thread moves
+        #: the work on (0.0 = stage not taken), folded into the per-path
+        #: accumulator by ONE call on the connection thread once the
+        #: reply has been handed to the socket (``_close_request``)
         self.t_submit = 0.0
+        self.t_dequeued = 0.0   # _shed_expired: batch gate / locked plane
+        self.t_launched = 0.0   # its batch's epoch_read_launch returned
+        self.t_wb_start = 0.0   # the writeback stage took its batch
+        self.t_synced = 0.0     # last device->host transfer of its batch
+        self.t_ready = 0.0      # event.set(): the result is there
+        #: launch batch (reads) or commit group (writes) that served it
+        self.batch_id = 0
         #: native-dialect reads ask the writeback stage to serialize the
         #: reply frame for them (batched reply serialization: one tight
         #: encode loop instead of per-connection wakeup-then-frame)
         self.wants_bytes = wants_bytes
         self.reply_bytes: Optional[bytes] = None
+
+
+class _RequestTrace:
+    """One connection thread's request-in-progress: the stamps taken
+    before a :class:`_StaticWork` exists (arrival on the io thread, the
+    crossing into Python), the request id (connection id, sequence
+    number) and the work the request parked, if any.  Reused for every
+    request of the connection; lives in the thread's ``_tls``."""
+
+    __slots__ = ("conn", "seq", "t_arrive", "t_taken", "t_ready", "work",
+                 "path")
+
+    def __init__(self, conn: int):
+        self.conn = conn
+        self.seq = 0
+        self.begin(0.0, 0.0)
+
+    def begin(self, t_arrive: float, t_taken: float) -> None:
+        self.seq += 1
+        self.t_arrive = t_arrive
+        self.t_taken = t_taken
+        self.t_ready = 0.0
+        self.work: Optional[_StaticWork] = None
+        self.path = "other"
+
+    def reply_built(self) -> None:
+        """A request served whole on its connection thread (cache hit,
+        interactive op, status) has no work to carry ``t_ready``: stamp
+        the finished reply, so its ``reply`` stage is the send alone,
+        like a parked request's."""
+        if self.work is None:
+            self.t_ready = time.monotonic()
 
 
 class RawReply:
@@ -115,13 +162,18 @@ class _EpochReadBatch:
     between the dispatcher's launch stage and the writeback stage: device
     handles plus the per-work result spans."""
 
-    __slots__ = ("pending", "works", "spans", "vc_list")
+    __slots__ = ("pending", "works", "spans", "vc_list", "id",
+                 "t_launched")
 
-    def __init__(self, pending, works, spans, vc_list):
+    def __init__(self, pending, works, spans, vc_list, id_, t_launched):
         self.pending = pending
         self.works = works
         self.spans = spans
         self.vc_list = vc_list
+        #: launch batch id (the dispatcher's count of launched chunks)
+        self.id = id_
+        #: ``time.monotonic()`` when epoch_read_launch returned
+        self.t_launched = t_launched
 
 
 def _decode_objects(objs):
@@ -231,8 +283,17 @@ class ProtocolServer:
         self._static_q = TenantLanes(self.tenants, queue_max,
                                      name="static batch gate")
         self._batch_max = 1024
-        #: per-handler-thread scratch (stage_decode timing)
+        #: per-connection-thread scratch: the request in progress
+        #: (``_RequestTrace``)
         self._tls = threading.local()
+        #: per-path request stage sums + the slowest records (ISSUE 24;
+        #: node status ``pipeline.paths`` / ``pipeline.slow_requests``)
+        self._stages = StageAccumulator()
+        #: launched read chunks so far (written by the dispatcher only)
+        self._launch_seq = 0
+        #: (seconds, waits) the locked worker spent blocked on an empty
+        #: merge-point queue — replaced whole by that worker only
+        self._locked_idle = (0.0, 0)
         # --- staged serving pipeline (ISSUE 5) -------------------------
         #: serving-epoch publication cadence for the dedicated ticker
         self.epoch_tick_ms = epoch_tick_ms
@@ -420,6 +481,8 @@ class ProtocolServer:
                 except OSError:
                     client_id = f"conn{next(server_self._conn_ids)}"
                 metrics = server_self.metrics
+                rec = server_self._tls.rec = _RequestTrace(
+                    next(server_self._conn_ids))
                 # buffered framing: header + body in ~one syscall each
                 rfile = self.request.makefile("rb")
                 while True:
@@ -427,6 +490,7 @@ class ProtocolServer:
                         frame = read_frame_buffered(rfile)
                     except (ConnectionError, OSError, ValueError):
                         return
+                    t0 = time.monotonic()
                     # frontend.recv fault site — the Python-plane twin
                     # of the native drain worker's (chaos parity: the
                     # same plan wrecks frames on either accept path)
@@ -438,7 +502,6 @@ class ProtocolServer:
                     # per-client cap the request is answered with a
                     # typed busy error + retry-after hint — the client
                     # backs off, the server never queues unboundedly.
-                    t0 = time.monotonic()
                     try:
                         server_self.admission.enter(client_id)
                     except BusyError as e:
@@ -446,16 +509,17 @@ class ProtocolServer:
                         if not self._reply_error(frame, "busy", e):
                             return
                         continue
-                    # decode-stage clock: runs until the work parks at
-                    # the batch gate (observed in _submit)
-                    server_self._tls.t0 = t0
+                    # the stage record starts at the complete frame
+                    # (no crossing on this plane: t_taken stays 0); the
+                    # decode stage runs until the work parks (_submit)
+                    rec.begin(t0, 0.0)
                     try:
-                        if not self._handle_admitted(frame, conn_txns):
+                        if not self._handle_admitted(frame, conn_txns,
+                                                     rec):
                             return
                     finally:
                         server_self.admission.exit(client_id)
-                        metrics.server_request_seconds.observe(
-                            time.monotonic() - t0)
+                        server_self._close_request(rec)
 
             def _reply_error(self, frame, kind: str, e) -> bool:
                 """Typed error reply in the FRAME'S dialect; False when
@@ -475,9 +539,10 @@ class ProtocolServer:
                 except (ConnectionError, OSError):
                     return False
 
-            def _handle_admitted(self, frame, conn_txns) -> bool:
+            def _handle_admitted(self, frame, conn_txns, rec) -> bool:
                 """One admitted request end-to-end; False = drop conn."""
                 buf = server_self._frame_reply(frame, conn_txns)
+                rec.reply_built()
                 try:
                     # py-socket-ok: socketserver fallback plane — with
                     # the native front-end on, client replies leave
@@ -662,7 +727,7 @@ class ProtocolServer:
         while not self._closing:
             batch = nf.take_batch(200)
             now = time.monotonic()
-            for conn_id, kind, aux, payload in batch:
+            for conn_id, kind, aux, payload, t_arrive in batch:
                 if kind == nf.K_CONN_DROP:
                     q = workers.pop(conn_id, None)
                     if q is not None:
@@ -682,7 +747,7 @@ class ProtocolServer:
                         args=(conn_id, q),
                         name=f"antidote-native-conn-{conn_id}",
                     ).start()
-                q.put((kind, aux, payload, now))
+                q.put((kind, aux, payload, t_arrive, now))
         for q in workers.values():
             q.put(None)
 
@@ -692,12 +757,13 @@ class ProtocolServer:
         orphan-txn rollback when the conn drops."""
         nf = self.native
         conn_txns = set()
+        rec = self._tls.rec = _RequestTrace(conn_id)
         try:
             while True:
                 item = q.get()
                 if item is None or self._closing:
                     return
-                kind, aux, frame, t0 = item
+                kind, aux, frame, t_arrive, t_taken = item
                 admitted = 1 if kind == nf.K_FRAME else 0
                 frame = self._frame_fault(frame)
                 if frame is None:
@@ -713,19 +779,53 @@ class ProtocolServer:
                     self.metrics.shed.inc(plane="server")
                     nf.send(conn_id, self._busy_reply_bytes(frame, aux), 0)
                     continue
-                self._tls.t0 = t0
+                # t_arrive: the io thread's stamp of the complete frame;
+                # t_taken: the drain loop's, after take_batch returned
+                rec.begin(t_arrive, t_taken)
                 try:
                     buf = self._frame_reply(frame, conn_txns)
                 except Exception as e:  # never wedge the admission slot
                     log.exception("native drain request failed")
                     buf = encode(MessageCode.ERROR_RESP, {
                         "error": type(e).__name__, "detail": str(e)})
+                rec.reply_built()
                 nf.send(conn_id, buf, admitted)
-                self.metrics.server_request_seconds.observe(
-                    time.monotonic() - t0)
+                self._close_request(rec)
         finally:
             for txid in conn_txns:
                 self._abort_orphan(txid)
+
+    def _close_request(self, rec: _RequestTrace) -> None:
+        """The ONE closing call of a request's stage record, after its
+        reply has been handed to the socket (``sendall`` / ``nf.send``
+        returned): folded into its path's sums (one lock take), and the
+        request histogram (arrival → sent).  The path is what served THIS
+        request, whatever else rode in its batch."""
+        now = time.monotonic()
+        w = rec.work
+        if w is None:
+            path, batch = rec.path, 0
+            stamps = (rec.t_arrive, rec.t_taken, 0.0, 0.0, 0.0, 0.0, 0.0,
+                      rec.t_ready, now)
+        else:
+            if not w.t_ready:
+                # no stage answered it: refused at a full gate, expired
+                # while parked, or failed by a stage's error path
+                path = "shed"
+            elif w.kind != "read":
+                path = w.kind                   # "update" | "commit"
+            elif w.t_synced:
+                path = "gather"                 # a device gather served it
+            elif w.t_wb_start:
+                path = "cache"                  # all hits at launch time
+            else:
+                path = "locked"
+            batch = w.batch_id
+            stamps = (rec.t_arrive, rec.t_taken, w.t_submit, w.t_dequeued,
+                      w.t_launched, w.t_wb_start, w.t_synced, w.t_ready,
+                      now)
+        self.metrics.server_request_seconds.observe(
+            self._stages.close(path, (rec.conn, rec.seq), batch, stamps))
 
     def _busy_reply_bytes(self, frame: bytes, hint_ms: int) -> bytes:
         """Framed admission-shed reply in the frame's dialect (the
@@ -773,6 +873,9 @@ class ProtocolServer:
         clock_vc = _vc(clock)
         fast = self._try_cache_read(objects, clock_vc, wants_bytes)
         if fast is not None:
+            rec = getattr(self._tls, "rec", None)
+            if rec is not None:
+                rec.path = "cache"
             return fast
         w = _StaticWork("read", objects=objects, clock=clock_vc,
                         deadline=deadline, wants_bytes=wants_bytes,
@@ -851,10 +954,11 @@ class ProtocolServer:
             raise
         now = time.monotonic()
         work.t_submit = now
-        t0 = getattr(self._tls, "t0", None)
-        if t0 is not None:
-            m.stage_decode_seconds.observe(now - t0)
-            self._tls.t0 = None
+        rec = getattr(self._tls, "rec", None)
+        if rec is not None and rec.work is None:
+            rec.work = work
+            m.stage_decode_seconds.observe(
+                now - (rec.t_taken or rec.t_arrive))
         try:
             try:
                 # bounded gate: shed with a typed busy error instead of
@@ -891,13 +995,18 @@ class ProtocolServer:
             raise work.error
         return work.result
 
-    def _drain_batch(self, q, window_s: float = 0.0):
-        """Block for one work, drain whatever else queued (up to
-        ``_batch_max``); with ``window_s`` keep gathering late arrivals
-        up to that long (the --group-commit-window-us merge window).
-        Returns (works, stop_seen)."""
-        batch = [q.get()]
-        deadline = (time.monotonic() + window_s) if window_s > 0 else None
+    def _drain_batch(self, q, wait_span: str, window_s: float = 0.0):
+        """Block for one work (under the host span ``wait_span``), drain
+        whatever else queued (up to ``_batch_max``); with ``window_s``
+        keep gathering late arrivals up to that long (the
+        --group-commit-window-us merge window).
+        Returns (works, stop_seen, seconds blocked for the first)."""
+        t0 = time.monotonic()
+        with span(wait_span):
+            batch = [q.get()]
+        now = time.monotonic()
+        waited = now - t0
+        deadline = (now + window_s) if window_s > 0 else None
         while len(batch) < self._batch_max:
             try:
                 batch.append(q.get_nowait())
@@ -912,7 +1021,7 @@ class ProtocolServer:
                 except queue.Empty:
                     break
         stop = any(w is _STOP for w in batch)
-        return [w for w in batch if w is not _STOP], stop
+        return [w for w in batch if w is not _STOP], stop, waited
 
     def _shed_expired(self, works, where: str, observe_parked=False):
         """Deadline discipline shared by both planes: work that outlived
@@ -922,6 +1031,8 @@ class ProtocolServer:
         now = time.monotonic()
         m = self.metrics
         for w in works:
+            if not w.t_dequeued:
+                w.t_dequeued = now
             if observe_parked and w.t_submit:
                 m.stage_parked_seconds.observe(now - w.t_submit)
             if w.deadline is not None and now > w.deadline:
@@ -962,7 +1073,7 @@ class ProtocolServer:
         q = self._static_q
         m = self.metrics
         while True:
-            works, stop = self._drain_batch(q)
+            works, stop, _ = self._drain_batch(q, "serve.gate_wait")
             m.commit_gate_depth.set(q.qsize())
             works = self._shed_expired(works, "batch gate",
                                        observe_parked=True)
@@ -1026,7 +1137,10 @@ class ProtocolServer:
         waits for it."""
         q = self._locked_q
         while True:
-            works, stop = self._drain_batch(q, self._group_window_s)
+            works, stop, waited = self._drain_batch(
+                q, "serve.locked_wait", self._group_window_s)
+            idle_s, idle_n = self._locked_idle
+            self._locked_idle = (idle_s + waited, idle_n + 1)
             # re-checked at THIS dequeue too (the overload contract at
             # the merge point): a work can expire while parked behind a
             # slow commit group — this plane's whole job is absorbing
@@ -1107,12 +1221,15 @@ class ProtocolServer:
         for w in merged:
             spans.append((len(objs), len(objs) + len(w.objects)))
             objs.extend(w.objects)
+        self._launch_seq = batch_id = self._launch_seq + 1
         try:
-            pending, fallback = store.epoch_read_launch(objs, ep)
+            with span("serve.launch", batch=batch_id, objects=len(objs)):
+                pending, fallback = store.epoch_read_launch(objs, ep)
         except BaseException:
             store.unpin_serving_epoch(ep)
             log.exception("epoch read launch failed; locked fallback")
             return works
+        t_launched = time.monotonic()
         keep, kspans = merged, spans
         if fallback:
             fb = set(fallback)
@@ -1132,7 +1249,8 @@ class ProtocolServer:
         # bounded handoff: a lagging writeback stage backpressures this
         # dispatcher (and through the bounded gate, the clients)
         self._writeback_q.put(_EpochReadBatch(pending, keep, kspans,
-                                              vc_list))
+                                              vc_list, batch_id,
+                                              t_launched))
         return locked
 
     #: merged epoch-read launches are chunked at this many objects: one
@@ -1166,7 +1284,8 @@ class ProtocolServer:
         q = self._writeback_q
         m = self.metrics
         while True:
-            batch = q.get()
+            with span("serve.wb_wait"):
+                batch = q.get()
             if batch is _STOP:
                 return
             store = self.node.txm.store
@@ -1174,16 +1293,28 @@ class ProtocolServer:
             try:
                 # sync-ok: the writeback stage owns the device sync
                 vals = store.epoch_read_finish(batch.pending)
-                for w, (lo, hi) in zip(batch.works, batch.spans):
-                    w.result = (vals[lo:hi], batch.vc_list)
-                    if w.wants_bytes:
-                        w.reply_bytes = encode(
-                            MessageCode.READ_OBJECTS_RESP, {
-                                "values": [encode_value(v)
-                                           for v in vals[lo:hi]],
-                                "commit_clock": batch.vc_list,
-                            })
-                    w.event.set()
+                t_synced = batch.pending.t_synced
+                gathered = batch.pending.gathered
+                with span("serve.wb_host", batch=batch.id,
+                          works=len(batch.works)):
+                    for w, (lo, hi) in zip(batch.works, batch.spans):
+                        w.result = (vals[lo:hi], batch.vc_list)
+                        if w.wants_bytes:
+                            w.reply_bytes = encode(
+                                MessageCode.READ_OBJECTS_RESP, {
+                                    "values": [encode_value(v)
+                                               for v in vals[lo:hi]],
+                                    "commit_clock": batch.vc_list,
+                                })
+                        w.batch_id = batch.id
+                        w.t_launched = batch.t_launched
+                        w.t_wb_start = t0
+                        # its own objects' outcome: a read whose objects
+                        # all hit the cache at launch only shared the batch
+                        if not gathered.isdisjoint(range(lo, hi)):
+                            w.t_synced = t_synced
+                        w.t_ready = time.monotonic()
+                        w.event.set()
             except BaseException as e:
                 log.exception("epoch read writeback failed")
                 for w in batch.works:
@@ -1221,10 +1352,11 @@ class ProtocolServer:
                 if self._epoch_reads else 0.5)
         while not self._ticker_stop.wait(tick):
             try:
-                if self._epoch_reads:
-                    txm.publish_serving_epoch()
-                    self._native_advance()
-                self._publish_table_epochs_capped()
+                with span("epoch.tick"):
+                    if self._epoch_reads:
+                        txm.publish_serving_epoch()
+                        self._native_advance()
+                    self._publish_table_epochs_capped()
             except Exception:
                 log.exception("epoch ticker publish failed")
 
@@ -1307,6 +1439,7 @@ class ProtocolServer:
                     vals, vc = self.node.read_objects(objs, clock=clock)
                 for i, w in enumerate(merged):
                     w.result = (vals[offs[i]:offs[i + 1]], vc)
+                    w.t_ready = time.monotonic()
                     w.event.set()
             except Exception:
                 solo = merged + solo  # isolate the offender
@@ -1319,6 +1452,7 @@ class ProtocolServer:
                                                       clock=w.clock)
             except Exception as e:
                 w.error = e
+            w.t_ready = time.monotonic()
             w.event.set()
 
     def _covered_vc(self):
@@ -1383,6 +1517,7 @@ class ProtocolServer:
         # Interactive commits ride the FIRST round only: their abort is
         # the client's to observe, never auto-retried.
         while pending or (first and inter):
+            t_stage = time.monotonic()
             staged = []
             for w in pending:
                 # re-check per-work deadlines at every retry round: a
@@ -1411,6 +1546,8 @@ class ProtocolServer:
             first = False
             if not batch:
                 return
+            seq0 = txm.group_seq
+            t_staged = time.monotonic()
             try:
                 outs = txm.commit_transactions_group(
                     [t for _, t in batch])
@@ -1428,16 +1565,25 @@ class ProtocolServer:
                     w.error = e
                     w.event.set()
                 return
+            # the `ack` phase: per-source results fanned back out, after
+            # the commit lock was released
+            t_ack = time.monotonic()
+            group_id = txm.group_seq
             retry = []
             for (w, txn), r in zip(batch, outs):
+                w.batch_id = group_id
                 if isinstance(r, AbortError) and w.kind == "update":
                     retry.append(w)
-                elif isinstance(r, Exception):
+                    continue
+                if isinstance(r, Exception):
                     w.error = r
-                    w.event.set()
                 else:
                     w.result = r
-                    w.event.set()
+                w.t_ready = time.monotonic()
+                w.event.set()
+            if txm.group_seq != seq0:
+                txm.phases.add("stage", t_staged - t_stage)
+                txm.phases.add("ack", time.monotonic() - t_ack)
             pending = retry
 
     # ------------------------------------------------------------------
@@ -1769,6 +1915,11 @@ class ProtocolServer:
                 "batch_gate_max": self._static_q.maxsize,
             })
             status["pipeline"] = self._pipeline_status()
+            idle_s, idle_n = self._locked_idle
+            # time the locked worker sat blocked on an empty merge-point
+            # queue: a commit round is `group` + this
+            status.setdefault("write_plane", {})["locked_idle"] = {
+                "sum_ms": idle_s * 1e3, "count": idle_n}
             status["tenants"] = self._tenant_status()
             if self.interdc is not None and hasattr(self.interdc,
                                                     "replica_status"):
@@ -1858,6 +2009,9 @@ class ProtocolServer:
                 "parked": us(m.stage_parked_seconds),
                 "launch": us(m.stage_launch_seconds),
                 "writeback": us(m.stage_writeback_seconds),
+                # a whole request, frame arrival -> reply handed to the
+                # socket (antidote_server_request_seconds)
+                "request": us(m.server_request_seconds),
             },
             "reads": {
                 path[0]: int(v)
@@ -1876,6 +2030,9 @@ class ProtocolServer:
             "locked_depth": self._locked_q.qsize(),
             "group_commit_window_us": round(self._group_window_s * 1e6, 1),
         }
+        # per-path stage split of every request since boot, and the
+        # slowest stage records since the last status read (ISSUE 24)
+        out.update(self._stages.status())
         if self.native is not None:
             out["native"] = self.native.stats()
         if self.proxy is not None:

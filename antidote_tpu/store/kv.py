@@ -12,6 +12,7 @@ like Antidote's ``{Key, Type, Bucket}`` bound objects.
 
 from __future__ import annotations
 
+import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -19,6 +20,7 @@ import numpy as np
 from antidote_tpu.config import AntidoteConfig
 from antidote_tpu.crdt import get_type, is_type
 from antidote_tpu.crdt.blob import BlobStore
+from antidote_tpu.obs.trace import span
 from antidote_tpu.store.router import shard_batch, shard_of
 from antidote_tpu.store.typed_table import TypedTable, _bucket
 
@@ -290,12 +292,11 @@ def _make_promote_fn():
     newest-version order survives the move.  Jitted per (src, dst) tier
     pair — the previous eager form was ~25 separate device dispatches,
     a visible serving-latency spike per hot-key tier crossing."""
-    import functools
-
-    import jax
     import jax.numpy as jnp
 
-    @functools.partial(jax.jit, donate_argnums=(0, 1))
+    from antidote_tpu.obs.trace import device_program
+
+    @device_program("tier_promote", donate_argnums=(0, 1))
     def fn(src, dst, shard, row, new_row, seq_shift):
         def emb(v, dshape):
             out = jnp.zeros(dshape, v.dtype)
@@ -382,13 +383,20 @@ class _EpochReadPending:
     """Launched-but-unmaterialized epoch read batch: device handles only
     (the dispatcher stage must never sync)."""
 
-    __slots__ = ("ep", "objects", "vals", "launches")
+    __slots__ = ("ep", "objects", "vals", "launches", "gathered",
+                 "t_synced")
 
-    def __init__(self, ep, objects, vals, launches):
+    def __init__(self, ep, objects, vals, launches, gathered):
         self.ep = ep
         self.objects = objects
         self.vals = vals
         self.launches = launches
+        #: indices of the objects a device gather serves (the others hit
+        #: the snapshot cache, read bottom, or fell back)
+        self.gathered = gathered
+        #: ``time.monotonic()`` after the last device-to-host transfer of
+        #: :meth:`KVStore.epoch_read_finish` (0.0: nothing was launched)
+        self.t_synced = 0.0
 
 #: composite-key namespaces (crdt/maps.py field_key/member_key): an effect
 #: on a derived key must also invalidate the PARENT map's cached value
@@ -688,7 +696,7 @@ class KVStore:
         callers with retry loops (remote ingress, recovery) never
         double-apply.
         """
-        errors, _ = self.apply_effect_groups(
+        errors, _ticket, _wal = self.apply_effect_groups(
             [(list(effects), list(commit_vcs), list(origins))],
             defer_sync=False,
         )
@@ -703,10 +711,12 @@ class KVStore:
         alone; sibling sub-groups still log, scatter and ack.
 
         ``groups``: list of ``(effects, commit_vcs, origins)`` per
-        sub-group.  Returns ``(errors, ticket)``: one ``None`` or
-        ``Exception`` per sub-group, and — with ``defer_sync`` — the
-        group-fsync ticket acks must wait on (None when nothing was
-        logged; the fsync runs CONCURRENTLY with the device scatter)."""
+        sub-group.  Returns ``(errors, ticket, wal)``: one ``None`` or
+        ``Exception`` per sub-group; with ``defer_sync`` the group-fsync
+        ticket acks must wait on (None when nothing was logged; the fsync
+        runs CONCURRENTLY with the device scatter); and ``time.monotonic()``
+        at the start and the end of the WAL phase (append + fsync
+        submitted), for the commit path's phase split."""
         self._mutating = True
         self.mutation_epoch += 1
         try:
@@ -791,62 +801,66 @@ class KVStore:
         # NACKed group can never partially resurrect on recovery, and
         # its siblings still commit
         errors: List[Optional[Exception]] = [None] * len(groups)
-        if self.log is not None and any(to_log_groups):
-            errors = self.log.log_effect_groups(to_log_groups)
-        # survivors only: cache invalidation, device scatter, clocks
-        ticket = None
-        by_table: Dict[str, list] = {}
-        touched = []
-        inval: List[Tuple[Any, str]] = []
-        for (effs, vcs, orgs), locs, err in zip(groups, located, errors):
-            if err is not None:
-                continue
-            for i, eff in enumerate(effs):
-                tname_t, shard, row = locs[i]
-                inval.append((eff.key, eff.bucket))
-                self.note_ckpt_dirty((eff.key, eff.bucket))
-                if self.merkle is not None:
-                    self.merkle.mark(shard, (eff.key, eff.bucket))
-                # composite invalidation: a field/membership write kills
-                # the parent map's assembled value (recursively for
-                # nested maps)
-                k = eff.key
-                while (type(k) is tuple and len(k) >= 2
-                       and k[0] in _DERIVED_NS):
-                    k = k[1]
-                    inval.append((k, eff.bucket))
-                by_table.setdefault(tname_t, []).append(
-                    (shard, row, eff.eff_a, eff.eff_b, vcs[i], orgs[i])
-                )
-                touched.append((shard, np.asarray(vcs[i], np.int32)))
-        if self.log is not None and touched:
-            # group fsync: deferred acks wait on the ticket AFTER the
-            # commit lock releases, so the fsync overlaps the device
-            # scatter below and the NEXT merged batch's certification;
-            # the blocking form (remote ingress, recovery) keeps the
-            # barrier-before-apply ordering so its retry loops never
-            # double-apply a device mutation
-            ticket = self.log.barrier_async([s for s, _ in touched])
-            if not defer_sync:
-                ticket.wait()
-                ticket = None
+        t_wal = time.monotonic()
+        with span("commit.wal_append"):
+            if self.log is not None and any(to_log_groups):
+                errors = self.log.log_effect_groups(to_log_groups)
+            # survivors only: cache invalidation, device scatter, clocks
+            ticket = None
+            by_table: Dict[str, list] = {}
+            touched = []
+            inval: List[Tuple[Any, str]] = []
+            for (effs, vcs, orgs), locs, err in zip(groups, located, errors):
+                if err is not None:
+                    continue
+                for i, eff in enumerate(effs):
+                    tname_t, shard, row = locs[i]
+                    inval.append((eff.key, eff.bucket))
+                    self.note_ckpt_dirty((eff.key, eff.bucket))
+                    if self.merkle is not None:
+                        self.merkle.mark(shard, (eff.key, eff.bucket))
+                    # composite invalidation: a field/membership write
+                    # kills the parent map's assembled value (recursively
+                    # for nested maps)
+                    k = eff.key
+                    while (type(k) is tuple and len(k) >= 2
+                           and k[0] in _DERIVED_NS):
+                        k = k[1]
+                        inval.append((k, eff.bucket))
+                    by_table.setdefault(tname_t, []).append(
+                        (shard, row, eff.eff_a, eff.eff_b, vcs[i], orgs[i])
+                    )
+                    touched.append((shard, np.asarray(vcs[i], np.int32)))
+            if self.log is not None and touched:
+                # group fsync: deferred acks wait on the ticket AFTER the
+                # commit lock releases, so the fsync overlaps the device
+                # scatter below and the NEXT merged batch's certification;
+                # the blocking form (remote ingress, recovery) keeps the
+                # barrier-before-apply ordering so its retry loops never
+                # double-apply a device mutation
+                ticket = self.log.barrier_async([s for s, _ in touched])
+                if not defer_sync:
+                    ticket.wait()
+                    ticket = None
+        t_wal_end = time.monotonic()
         if inval:
             # one locked sweep per batch, not one acquisition per effect
             with self._value_cache_lock:
                 for dk in inval:
                     self._value_cache.pop(dk, None)
-        for tname_t, items in by_table.items():
-            t = self.table(tname_t)
-            aw = t.ty.eff_a_width(t.cfg)
-            bw = t.ty.eff_b_width(t.cfg)
-            t.append(
-                np.asarray([x[0] for x in items], np.int64),
-                np.asarray([x[1] for x in items], np.int64),
-                np.stack([_pad_lane(x[2], aw, np.int64) for x in items]),
-                np.stack([_pad_lane(x[3], bw, np.int32) for x in items]),
-                np.stack([np.asarray(x[4], np.int32) for x in items]),
-                np.asarray([x[5] for x in items], np.int32),
-            )
+        with span("commit.scatter", effects=len(inval)):
+            for tname_t, items in by_table.items():
+                t = self.table(tname_t)
+                aw = t.ty.eff_a_width(t.cfg)
+                bw = t.ty.eff_b_width(t.cfg)
+                t.append(
+                    np.asarray([x[0] for x in items], np.int64),
+                    np.asarray([x[1] for x in items], np.int64),
+                    np.stack([_pad_lane(x[2], aw, np.int64) for x in items]),
+                    np.stack([_pad_lane(x[3], bw, np.int32) for x in items]),
+                    np.stack([np.asarray(x[4], np.int32) for x in items]),
+                    np.asarray([x[5] for x in items], np.int32),
+                )
         # only after every append succeeded may the partition clocks claim
         # these commits (the stable snapshot must never dominate unapplied
         # ops — the causal gate trusts it)
@@ -858,7 +872,7 @@ class KVStore:
             # holds the commit lock; eviction mutates tables)
             self.cold.note_writes(inval)
             self.cold.maybe_evict()
-        return errors, ticket
+        return errors, ticket, (t_wal, t_wal_end)
 
     # ------------------------------------------------------------------
     # serving epochs (lock-split wire reads — ISSUE 5)
@@ -893,6 +907,11 @@ class KVStore:
             nm.reset()
 
     def publish_serving_epoch(self, vc: np.ndarray) -> str:
+        """:meth:`publish_serving_epoch_timed`'s status alone."""
+        return self.publish_serving_epoch_timed(vc)[0]
+
+    def publish_serving_epoch_timed(self, vc: np.ndarray
+                                    ) -> Tuple[str, float]:
         """Publish a new store-wide serving snapshot at clock ``vc``.
 
         Caller must hold the commit lock (``vc`` and the frozen heads
@@ -902,9 +921,12 @@ class KVStore:
         size), by full copy on the first freezes or after invalidation.
         Returns "published", "noop" (epoch already current) or
         "deferred" (a reader still pins a retired epoch whose buffers
-        the freeze would donate — retried on the next publish trigger).
+        the freeze would donate — retried on the next publish trigger),
+        and the seconds spent dispatching freeze_serving programs (the
+        commit path's ``freeze`` phase, inside ``publish``).
         """
         cur = self.serving_epoch
+        freeze_s = 0.0
         if cur is not None and cur.mut_epoch == self.mutation_epoch:
             # safe-time PINGS advance the applied clocks without any
             # data apply (mutation epoch unchanged ⇒ the frozen buffers
@@ -913,7 +935,7 @@ class KVStore:
             # trusts the cut, not the cross-shard-max vc — doesn't spin
             # on a stale capture after the last write of a burst
             cur.applied = self.applied_vc.copy()
-            return "noop"
+            return "noop", freeze_s
         m = self.metrics
         with self._epoch_lock:
             can_donate = all(e.pins == 0 for e in self._epoch_graveyard)
@@ -938,12 +960,14 @@ class KVStore:
                 # succeeds, which needs this freeze) — rebuild by copy.
                 spare_live = (cur is not None
                               and cur.tables.get(tname) is t.serving_spare())
+                t_frz = time.monotonic()
                 res = t.freeze_serving(can_donate and not spare_live,
                                        force_copy=spare_live)
+                freeze_s += time.monotonic() - t_frz
                 if res is None:
                     if m is not None:
                         m.epoch_publish.inc(mode="defer")
-                    return "deferred"
+                    return "deferred", freeze_s
                 slot, mode, tch, rows, shard_rows = res
                 tch = None if (tch is None or pend is None) else tch | pend
                 t._pending_touched = tch
@@ -987,7 +1011,7 @@ class KVStore:
             t._pending_touched = frozenset()  # this epoch carries them
         if m is not None:
             m.serving_epoch_id.set(ep.id)
-        return "published"
+        return "published", freeze_s
 
     # ------------------------------------------------------------------
     # hot-key snapshot cache
@@ -1231,7 +1255,9 @@ class KVStore:
                 launches.append((tname_t, items, resolved, fresh, None))
             if m is not None:
                 m.serving_reads.inc(mcount, path="gather")
-        return _EpochReadPending(ep, objects, vals, launches), fallback
+        gathered = {x[0] for items in need.values() for x in items}
+        return (_EpochReadPending(ep, objects, vals, launches, gathered),
+                fallback)
 
     def epoch_read_finish(self, pending: "_EpochReadPending") -> List[Any]:
         """Materialize + decode a launched epoch read batch (the ONLY
@@ -1248,32 +1274,35 @@ class KVStore:
             # routed (mesh) launches materialize the global [P, M']
             # array in ONE transfer here — the writeback stage owns the
             # sync; unrouting is host indexing, never a concat loop
-            host = {f: np.asarray(x) for f, x in resolved.items()}
+            with span("serve.device_wait", table=tname_t, rows=len(items)):
+                host = {f: np.asarray(x) for f, x in resolved.items()}
+            pending.t_synced = time.monotonic()
             del fresh  # provably all-fresh: frozen head_vc ≤ cap ≤ E
             has_resolve = ty.resolve_spec(t.cfg) is not None
             slot = ep.tables[tname_t]
-            for j, (i, shard, row) in enumerate(items):
-                if pos is not None:
-                    view = {f: x[pos[j, 0], pos[j, 1]]
-                            for f, x in host.items()}
-                else:
-                    view = {f: x[j] for f, x in host.items()}
-                if has_resolve:
-                    v = ty.value_from_resolved(view, self.blobs, t.cfg)
-                    if v is RESOLVE_OVERFLOW:
-                        # truncated top-count view: re-gather the full
-                        # frozen state for this one key (rare)
-                        full = {
-                            f: np.asarray(x[shard, row])
-                            for f, x in slot["head"].items()
-                        }
-                        v = ty.value(full, self.blobs, t.cfg)
-                else:
-                    v = ty.value(view, self.blobs, t.cfg)
-                vals[i] = v
-                key, _tn, bucket = pending.objects[i]
-                self.snapshot_cache_fill((key, bucket), ep,
-                                         (tname_t, shard, row), v)
+            with span("serve.wb_host", table=tname_t, rows=len(items)):
+                for j, (i, shard, row) in enumerate(items):
+                    if pos is not None:
+                        view = {f: x[pos[j, 0], pos[j, 1]]
+                                for f, x in host.items()}
+                    else:
+                        view = {f: x[j] for f, x in host.items()}
+                    if has_resolve:
+                        v = ty.value_from_resolved(view, self.blobs, t.cfg)
+                        if v is RESOLVE_OVERFLOW:
+                            # truncated top-count view: re-gather the full
+                            # frozen state for this one key (rare)
+                            full = {
+                                f: np.asarray(x[shard, row])
+                                for f, x in slot["head"].items()
+                            }
+                            v = ty.value(full, self.blobs, t.cfg)
+                    else:
+                        v = ty.value(view, self.blobs, t.cfg)
+                    vals[i] = v
+                    key, _tn, bucket = pending.objects[i]
+                    self.snapshot_cache_fill((key, bucket), ep,
+                                             (tname_t, shard, row), v)
         return vals
 
     # ------------------------------------------------------------------

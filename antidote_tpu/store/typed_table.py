@@ -50,6 +50,7 @@ from antidote_tpu.config import AntidoteConfig
 from antidote_tpu.crdt.base import CRDTType
 from antidote_tpu.materializer import fold as fold_mod
 from antidote_tpu.materializer import longlog
+from antidote_tpu.obs.trace import device_program
 
 
 def _bucket(n: int, buckets) -> int:
@@ -381,7 +382,7 @@ class TypedTable:
         padded-batch bucket."""
         fn = self._freeze_scatter_fns.get(bucket)
         if fn is None:
-            @functools.partial(jax.jit, donate_argnums=(0, 1))
+            @device_program("freeze_serving_scatter", donate_argnums=(0, 1))
             def fn(sp_head, sp_vc, head, head_vc, ss, rr):
                 out = {
                     f: x.at[ss, rr].set(head[f][ss, rr], mode="drop")
@@ -403,7 +404,8 @@ class TypedTable:
         its own slice.  One compile per padded-per-shard bucket."""
         fn = self._freeze_scatter_fns.get(("shard", bucket))
         if fn is None:
-            @functools.partial(jax.jit, donate_argnums=(0, 1))
+            @device_program("freeze_serving_scatter_routed",
+                            donate_argnums=(0, 1))
             def fn(sp_head, sp_vc, head, head_vc, row_mat):
                 sidx = jnp.arange(row_mat.shape[0])[:, None]
                 out = {
@@ -521,7 +523,7 @@ class TypedTable:
         """One-launch guarded row clear (cold-tier evict): zero every
         device array at the given (shard, row) pairs.  Donated in place;
         padding uses shard index P (scatter drops)."""
-        @functools.partial(jax.jit, donate_argnums=(0,))
+        @device_program("evict_clear", donate_argnums=(0,))
         def fn(tree, ss, rr):
             return jax.tree.map(
                 lambda x: x.at[ss, rr].set(
@@ -583,7 +585,7 @@ class TypedTable:
         checkpoint.install_image: versioned reads at clocks ≥ head_vc
         fold the empty ring on this base exactly; reads below surface the
         compaction horizon instead of a silently wrong value)."""
-        @functools.partial(jax.jit, donate_argnums=(0,))
+        @device_program("cold_install", donate_argnums=(0,))
         def fn(tree, ss, rr, head_rows, hvc_rows, seqs):
             out = dict(tree)
             out["head"] = {
@@ -652,7 +654,7 @@ class TypedTable:
         """Dispatch-only gather of (head, head_vc) rows — the delta
         checkpoint's capture primitive: launched under the commit-lock
         barrier, materialized outside it."""
-        @jax.jit
+        @device_program("ckpt_gather")
         def fn(head, head_vc, ss, rr):
             return ({f: x[ss, rr] for f, x in head.items()},
                     head_vc[ss, rr])
@@ -712,7 +714,9 @@ class TypedTable:
 
     @functools.cached_property
     def _copy_tree_fn(self):
-        return jax.jit(lambda tree: jax.tree.map(jnp.copy, tree))
+        return device_program(
+            "freeze_serving_copy",
+            lambda tree: jax.tree.map(jnp.copy, tree))
 
     def publish_epoch(self) -> None:
         """Freeze the current head as a serving epoch.
@@ -791,7 +795,7 @@ class TypedTable:
     # ------------------------------------------------------------------
     @functools.cached_property
     def _append_fn(self):
-        @functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3))
+        @device_program("commit_scatter_ring", donate_argnums=(0, 1, 2, 3))
         def append(ops_a, ops_b, ops_vc, ops_origin, shards, rows, slots, a, b, v, o):
             # out-of-range indices (padding) are dropped by the scatter
             return (
@@ -807,7 +811,7 @@ class TypedTable:
     def _read_fn(self):
         body = _shard_read_body(self.ty, self.cfg)
 
-        @jax.jit
+        @device_program("read_versioned")
         def read(snap, snap_vc, snap_seq, ops_a, ops_b, ops_vc, ops_origin,
                  rows, n_ops_rows, read_vcs):
             return jax.vmap(body)(
@@ -821,7 +825,7 @@ class TypedTable:
     def _gc_fn(self):
         # GC = copy the head (already the exact fold of the full ring +
         # prior history) into a fresh snapshot version; no fold needed.
-        @functools.partial(jax.jit, donate_argnums=(0, 1, 2))
+        @device_program("gc", donate_argnums=(0, 1, 2))
         def gc(snap, snap_vc, snap_seq, head, head_vc, rows, new_seqs):
             def per_shard(snap, snap_vc, snap_seq, head, head_vc, rows, seqs):
                 from antidote_tpu.clock import orddict
@@ -849,7 +853,8 @@ class TypedTable:
         if fn is None:
             body = _shard_head_update_body(self.ty, self.cfg, window)
 
-            @functools.partial(jax.jit, donate_argnums=(0, 1))
+            @device_program(f"commit_scatter_head_w{window}",
+                            donate_argnums=(0, 1))
             def fn(head, head_vc, ops_a, ops_b, ops_vc, ops_origin,
                    rows, starts, ends):
                 return jax.vmap(body)(
@@ -864,14 +869,15 @@ class TypedTable:
     def _read_latest_fn(self):
         body = _shard_read_latest_body(self.ty, self.cfg)
 
-        @jax.jit
+        @device_program("read_latest")
         def read(head, head_vc, rows, read_vcs):
             return jax.vmap(body)(head, head_vc, rows, read_vcs)
 
         return read
 
-    def _jit_routed(self, fn):
-        """jit a routed serving read: every operand and result carries
+    def _jit_routed(self, what: str, fn):
+        """jit a routed serving read as the device program
+        ``antidote_<what>``: every operand and result carries
         the leading shard axis ([P, M', ...]).  On a mesh-placed table
         the body runs under an explicit ``shard_map`` over that axis —
         each device works on its own shards' block, which the vmapped
@@ -882,7 +888,7 @@ class TypedTable:
         if sh is not None:
             fn = jax.shard_map(fn, mesh=sh.mesh, in_specs=sh.spec,
                                out_specs=sh.spec, check_vma=False)
-        return jax.jit(fn)
+        return device_program(what, fn)
 
     def set_sharding(self, sharding) -> None:
         """Adopt a new placement (the mesh plane's ``place_table``): the
@@ -909,7 +915,7 @@ class TypedTable:
             )
             return resolved, fresh
 
-        return self._jit_routed(fn)
+        return self._jit_routed("head_gather_routed", fn)
 
     def _read_resolved_fn(self, strategy: str, kmax: int = 0):
         """The fused serving read: head gather + snapshot-version select +
@@ -1010,7 +1016,7 @@ class TypedTable:
             )
             return resolved, fresh, complete
 
-        fn = self._jit_routed(fn)
+        fn = self._jit_routed(f"read_resolved_{strategy}_k{kmax}", fn)
         self._resolved_fns[(strategy, kmax)] = fn
         return fn
 
@@ -1023,7 +1029,7 @@ class TypedTable:
         the sharded axis would induce collectives)."""
         ty, cfg = self.ty, self.cfg
 
-        @jax.jit
+        @device_program("head_gather")
         def fn(head, head_vc, ss, rr, read_vcs):
             hvc = head_vc[ss, rr]
             state = {f: x[ss, rr] for f, x in head.items()}
@@ -1049,7 +1055,7 @@ class TypedTable:
         ty, cfg = self.ty, self.cfg
         select = _shard_base_select_body(ty, cfg)
 
-        @jax.jit
+        @device_program(f"read_resolved_{strategy}_k{kmax}_flat")
         def fn(head, head_vc, snap, snap_vc, snap_seq,
                ops_a, ops_b, ops_vc, ops_origin, ss, rr, n_ops_flat,
                read_vcs):
@@ -1122,7 +1128,7 @@ class TypedTable:
 
     @functools.cached_property
     def _merge_scatter_fn(self):
-        @jax.jit
+        @device_program("merge_scatter")
         def fn(dst_tree, idx, src_tree):
             return jax.tree.map(
                 lambda d, s: d.at[idx].set(s, mode="drop"), dst_tree, src_tree

@@ -34,11 +34,11 @@ import logging
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
-import jax
 import numpy as np
 
 from antidote_tpu.config import AntidoteConfig
 from antidote_tpu.crdt import TYPES, get_type, is_type
+from antidote_tpu.obs.trace import PhaseAccumulator, device_program, span
 from antidote_tpu.overload import (
     BusyError,
     DeadlineExceeded,
@@ -65,7 +65,8 @@ def _jitted_apply(ty_name: str, cfg: AntidoteConfig):
     overlaying N of its own effects would otherwise dispatch ~25 eager
     primitives per effect (the rga populate hot spot)."""
     ty = get_type(ty_name)
-    return jax.jit(functools.partial(ty.apply, cfg))
+    return device_program(f"overlay_apply_{ty_name}",
+                          functools.partial(ty.apply, cfg))
 
 
 Update = Tuple[Any, str, str, Tuple[str, Any]]  # (key, type_name, bucket, op)
@@ -215,6 +216,15 @@ class TransactionManager:
         #: EPOCH_INLINE_PUBLISH_S
         self._last_inline_publish = 0.0
         self._reads_at_last_publish = -1.0
+        #: commit-path phase split (ISSUE 24): one ``add_group`` per
+        #: write-bearing commit round — certify / wal_append / scatter /
+        #: fsync_wait / listeners / publish, summing to the round's
+        #: lock-held time (``antidote_commit_seconds``); node status
+        #: ``write_plane.phases``
+        self.phases = PhaseAccumulator()
+        #: write-bearing commit rounds so far: the id a round's host
+        #: spans and its members' stage records carry
+        self.group_seq = 0
 
     # ------------------------------------------------------------------
     # serving-epoch publication (lock-split wire reads)
@@ -769,70 +779,98 @@ class TransactionManager:
                     raise
                 if has_writes:
                     self.check_writable()
-            t0 = time.monotonic()
-            try:
-                out = self._commit_group_locked(txns)
-                if round_writes and self.serving_epochs:
-                    # publish BEFORE the ack leaves: a clockless
-                    # read admitted after this commit's reply must
-                    # find an epoch that covers it (read-your-
-                    # writes stays intact under the lock split).
-                    # A deferred/failed publish raises the lag
-                    # floor instead — epoch reads below it fall
-                    # back to the (always-fresh) locked path.
-                    # WRITE-STORM DEFERRAL (ISSUE 6): with the
-                    # epoch plane idle (no epoch-path read since
-                    # the last publish), the per-batch publish
-                    # scatter was >60% of batch cost serving
-                    # nobody — those batches defer (lag floor
-                    # up; any arriving read stays correct via
-                    # the locked path) up to the rate window.
-                    # The moment epoch reads flow, every batch
-                    # publishes before its ack again (deferring
-                    # mixed loads reroutes the read majority to
-                    # the locked plane and blows up its tail).
-                    now2 = time.monotonic()
-                    reads_now = -1.0
-                    if self.metrics is not None:
-                        sr = self.metrics.serving_reads
-                        reads_now = (sr.value(path="cache")
-                                     + sr.value(path="gather"))
-                    idle = (reads_now ==
-                            self._reads_at_last_publish)
-                    if (idle and now2 - self._last_inline_publish
-                            < self.EPOCH_INLINE_PUBLISH_S):
-                        self.epoch_lag_counter = self.commit_counter
+            if round_writes:
+                self.group_seq += 1
+            with span("commit.group", id=self.group_seq, txns=len(txns)):
+                return self._commit_round_locked(txns, round_writes)
+
+    def _commit_round_locked(self, txns: Sequence[Transaction],
+                             round_writes: bool) -> List[Any]:
+        """The round's critical section (commit lock held): the merged
+        commit group, then the serving-epoch publish before any ack
+        leaves; a write-bearing round's phase stamps go to
+        ``self.phases`` and its lock-held time to ``commit_seconds``."""
+        t0 = time.monotonic()
+        stamps, freeze_s = None, 0.0
+        try:
+            out, inner = self._commit_group_locked(txns)
+            stamps = (t0, *inner, time.monotonic())
+            if round_writes and self.serving_epochs:
+                # publish BEFORE the ack leaves: a clockless
+                # read admitted after this commit's reply must
+                # find an epoch that covers it (read-your-
+                # writes stays intact under the lock split).
+                # A deferred/failed publish raises the lag
+                # floor instead — epoch reads below it fall
+                # back to the (always-fresh) locked path.
+                # WRITE-STORM DEFERRAL (ISSUE 6): with the
+                # epoch plane idle (no epoch-path read since
+                # the last publish), the per-batch publish
+                # scatter was >60% of batch cost serving
+                # nobody — those batches defer (lag floor
+                # up; any arriving read stays correct via
+                # the locked path) up to the rate window.
+                # The moment epoch reads flow, every batch
+                # publishes before its ack again (deferring
+                # mixed loads reroutes the read majority to
+                # the locked plane and blows up its tail).
+                now2 = time.monotonic()
+                reads_now = -1.0
+                if self.metrics is not None:
+                    sr = self.metrics.serving_reads
+                    reads_now = (sr.value(path="cache")
+                                 + sr.value(path="gather"))
+                idle = (reads_now ==
+                        self._reads_at_last_publish)
+                if (idle and now2 - self._last_inline_publish
+                        < self.EPOCH_INLINE_PUBLISH_S):
+                    self.epoch_lag_counter = self.commit_counter
+                    self._native_lag_raised()
+                else:
+                    self._last_inline_publish = now2
+                    self._reads_at_last_publish = reads_now
+                    try:
+                        with span("commit.publish"):
+                            st, freeze_s = (
+                                self.store.publish_serving_epoch_timed(
+                                    self.serving_epoch_vc()))
+                    except Exception:
+                        st = "error"
+                        log.exception(
+                            "serving-epoch publish failed")
+                    if st not in ("published", "noop"):
+                        self.epoch_lag_counter = (
+                            self.commit_counter)
                         self._native_lag_raised()
-                    else:
-                        self._last_inline_publish = now2
-                        self._reads_at_last_publish = reads_now
-                        try:
-                            st = self._publish_serving_epoch_locked()
-                        except Exception:
-                            st = "error"
-                            log.exception(
-                                "serving-epoch publish failed")
-                        if st not in ("published", "noop"):
-                            self.epoch_lag_counter = (
-                                self.commit_counter)
-                            self._native_lag_raised()
-            except OSError as e:
-                if round_writes and e.errno in (errno.ENOSPC,
-                                                errno.EIO,
-                                                errno.EROFS,
-                                                errno.EDQUOT):
-                    # the WAL refused the append BEFORE any device
-                    # table mutated (durability-first ordering in
-                    # KVStore.apply_effects): fail the round and
-                    # flip into read-only degraded mode
-                    self._enter_read_only(e)
-                    raise ReadOnlyError(
-                        self.read_only_reason) from e
-                raise
-            finally:
-                if self.metrics is not None and round_writes:
-                    self.metrics.commit_seconds.observe(
-                        time.monotonic() - t0)
+        except OSError as e:
+            if round_writes and e.errno in (errno.ENOSPC,
+                                            errno.EIO,
+                                            errno.EROFS,
+                                            errno.EDQUOT):
+                # the WAL refused the append BEFORE any device
+                # table mutated (durability-first ordering in
+                # KVStore.apply_effects): fail the round and
+                # flip into read-only degraded mode
+                self._enter_read_only(e)
+                raise ReadOnlyError(
+                    self.read_only_reason) from e
+            raise
+        finally:
+            if round_writes:
+                t_end = time.monotonic()
+                if stamps is not None:
+                    # (a round that failed before its listeners ran has
+                    # no phase split to report.)  The one accumulator
+                    # call per group: lock taken, then the end of
+                    # certify (to the WAL append: certification, escrow,
+                    # the store's locate/promote/record build),
+                    # wal_append, scatter, fsync_wait, listeners and
+                    # publish.  A phase the round skipped is zero-long,
+                    # so the six always sum to ``t_end - t0``, the
+                    # round's ``antidote_commit_seconds`` observation.
+                    self.phases.add_group((*stamps, t_end), freeze_s)
+                if self.metrics is not None:
+                    self.metrics.commit_seconds.observe(t_end - t0)
                     self.metrics.commit_merge_width.observe(
                         sum(1 for t in txns if t.writeset))
         return out
@@ -857,9 +895,28 @@ class TransactionManager:
         covering group fsync (overlapped with the scatter; awaited
         BEFORE listeners run, so nothing non-durable ever reaches the
         serving epoch or the inter-DC stream), listeners per member.
-        Returns the per-txn results."""
+        Returns the per-txn results and four stamps: ``time.monotonic()``
+        at the start and the end of the WAL append, the end of the scatter
+        and the end of the fsync wait (all four the end of certification
+        when no member survived it)."""
+        with span("commit.certify"):
+            out, pend = self._certify_locked(txns)
+        if pend:
+            stamps = self._apply_certified_locked(out, pend)
+        else:
+            stamps = (time.monotonic(),) * 4
+        if self.commit_counter >= self._next_cert_gc:
+            self._gc_committed_keys()
+            self._next_cert_gc = self.commit_counter + self._cert_gc_every
+        return out, stamps
+
+    def _certify_locked(self, txns: Sequence[Transaction]):
+        """The ``certify`` phase of a commit group: vectorised
+        certification and escrow reservation, one counter mint per
+        surviving member.  Returns (per-txn results so far, the members to
+        apply as (out idx, txn, commit_vc, effects, stamped {ck: prev},
+        counter))."""
         out: List[Any] = []
-        # (out idx, txn, commit_vc, effects, stamped {ck: prev}, counter)
         pend: List[tuple] = []
         # vectorized certification (ISSUE 6): ONE pass over the stamp
         # table up front — each unique written key is looked up once for
@@ -1027,94 +1084,102 @@ class TransactionManager:
             pend.append((len(out), txn, commit_vc, effects, stamped,
                          self.commit_counter))
             out.append(commit_vc)
-        if pend:
-            groups = [
-                (effs, [vc] * len(effs), [self.my_dc] * len(effs))
-                for _i, _t, vc, effs, _s, _c in pend
-            ]
-            try:
-                errors, ticket = self.store.apply_effect_groups(groups)
-            except BaseException:
-                # a non-WAL failure (device error): nothing scattered —
-                # un-stamp every member's marks and counters, or later
-                # txns would first-committer-abort against writes that
-                # never existed
-                for _i, _t, _vc, _e, stamped, ctr in reversed(pend):
-                    for ck, old in stamped.items():
-                        if self.committed_keys.get(ck) == ctr:
-                            if old is None:
-                                self.committed_keys.pop(ck, None)
-                            else:
-                                self.committed_keys[ck] = old
-                self.commit_counter = pend[0][5] - 1
-                raise
-            ok: List[tuple] = []
-            # failure-atomic PER SUB-GROUP: a NACKed member rolls back
-            # only its own stamps (reverse order unwinds same-key
-            # overwrites; a sibling's newer stamp survives) and keeps
-            # its counter hole — holes are safe, certification compares
-            # magnitudes and safe-time pings may claim a ts that owns
-            # no txn (nothing will arrive for it)
-            for (i, txn, vc, effs, stamped, ctr), err in zip(
-                    reversed(pend), reversed(errors)):
-                if err is None:
-                    ok.append((i, txn, vc, effs))
-                    continue
+        return out, pend
+
+    def _apply_certified_locked(self, out: List[Any], pend: List[tuple]):
+        """The rest of a commit group: ONE grouped WAL append + device
+        scatter, the covering fsync's wait, listeners per member.  NACKed
+        members' entries of ``out`` become their errors.  Returns
+        ``time.monotonic()`` at the start and the end of the WAL append,
+        the end of the scatter and the end of the fsync wait."""
+        groups = [
+            (effs, [vc] * len(effs), [self.my_dc] * len(effs))
+            for _i, _t, vc, effs, _s, _c in pend
+        ]
+        try:
+            errors, ticket, (t_wal0, t_wal1) = (
+                self.store.apply_effect_groups(groups))
+        except BaseException:
+            # a non-WAL failure (device error): nothing scattered —
+            # un-stamp every member's marks and counters, or later
+            # txns would first-committer-abort against writes that
+            # never existed
+            for _i, _t, _vc, _e, stamped, ctr in reversed(pend):
                 for ck, old in stamped.items():
                     if self.committed_keys.get(ck) == ctr:
                         if old is None:
                             self.committed_keys.pop(ck, None)
                         else:
                             self.committed_keys[ck] = old
-                out[i] = self._wal_refusal(err)
-            ok.reverse()  # commit order for listeners
-            # ACK/VISIBILITY GATE: the group fsync was submitted before
-            # the device scatter and ran concurrently with it; it must
-            # COMPLETE before commit listeners publish to the inter-DC
-            # stream (or the serving epoch publishes) — effects a crash
-            # could un-happen must never be externally visible, or a
-            # recovered node re-mints the same (shard, origin, opid)
-            # and remote DCs drop the new ops as duplicates.  A failed
-            # or stalled fsync fails every ack in the batch typed and
-            # flips read-only: the durable state is ambiguous until the
-            # volume heals (see docs/operations.md).
-            if ticket is not None:
-                try:
-                    try:
-                        ticket.wait()
-                    except TimeoutError as e:
-                        raise OSError(
-                            errno.EIO, f"WAL group fsync stalled: {e}"
-                        ) from e
-                except OSError as e:
-                    err = self._wal_refusal(e)
-                    for i, _t, _vc, _e in ok:
-                        out[i] = err
-                    ok = []
-            # the group minted EVERY member's commit counter above, but
-            # members publish one at a time below — so a safe-time read
-            # from inside an early member's egress listener (the
-            # commit-path heartbeat threshold) would return a counter
-            # covering still-unpublished members.  A subscriber that
-            # trusts such a ping advances its chain clock past them and
-            # then drops their real messages as duplicates: permanently
-            # lost effects.  The flag makes listeners defer heartbeats
-            # until the whole group is on the stream.
-            self._publishing_group = len(ok) > 1
+            self.commit_counter = pend[0][5] - 1
+            raise
+        t_scat = t_fsync = time.monotonic()
+        ok: List[tuple] = []
+        # failure-atomic PER SUB-GROUP: a NACKed member rolls back
+        # only its own stamps (reverse order unwinds same-key
+        # overwrites; a sibling's newer stamp survives) and keeps
+        # its counter hole — holes are safe, certification compares
+        # magnitudes and safe-time pings may claim a ts that owns
+        # no txn (nothing will arrive for it)
+        for (i, txn, vc, effs, stamped, ctr), err in zip(
+                reversed(pend), reversed(errors)):
+            if err is None:
+                ok.append((i, txn, vc, effs))
+                continue
+            for ck, old in stamped.items():
+                if self.committed_keys.get(ck) == ctr:
+                    if old is None:
+                        self.committed_keys.pop(ck, None)
+                    else:
+                        self.committed_keys[ck] = old
+            out[i] = self._wal_refusal(err)
+        ok.reverse()  # commit order for listeners
+        # ACK/VISIBILITY GATE: the group fsync was submitted before
+        # the device scatter and ran concurrently with it; it must
+        # COMPLETE before commit listeners publish to the inter-DC
+        # stream (or the serving epoch publishes) — effects a crash
+        # could un-happen must never be externally visible, or a
+        # recovered node re-mints the same (shard, origin, opid)
+        # and remote DCs drop the new ops as duplicates.  A failed
+        # or stalled fsync fails every ack in the batch typed and
+        # flips read-only: the durable state is ambiguous until the
+        # volume heals (see docs/operations.md).
+        if ticket is not None:
             try:
-                for _i, txn, commit_vc, effects in ok:
-                    for listener in self.commit_listeners:
-                        listener(effects, commit_vc, self.my_dc)
-                    for eff, op in txn.writeset:
-                        self.hooks.execute_post_commit_hook(
-                            eff.key, eff.type_name, eff.bucket, op
-                        )
-            finally:
-                self._publishing_group = False
-        if self.commit_counter >= self._next_cert_gc:
-            self._gc_committed_keys()
-            self._next_cert_gc = self.commit_counter + self._cert_gc_every
-        return out
+                try:
+                    with span("commit.fsync_wait"):
+                        ticket.wait()
+                except TimeoutError as e:
+                    raise OSError(
+                        errno.EIO, f"WAL group fsync stalled: {e}"
+                    ) from e
+            except OSError as e:
+                err = self._wal_refusal(e)
+                for i, _t, _vc, _e in ok:
+                    out[i] = err
+                ok = []
+            t_fsync = time.monotonic()
+        # the group minted EVERY member's commit counter above, but
+        # members publish one at a time below — so a safe-time read
+        # from inside an early member's egress listener (the
+        # commit-path heartbeat threshold) would return a counter
+        # covering still-unpublished members.  A subscriber that
+        # trusts such a ping advances its chain clock past them and
+        # then drops their real messages as duplicates: permanently
+        # lost effects.  The flag makes listeners defer heartbeats
+        # until the whole group is on the stream.
+        self._publishing_group = len(ok) > 1
+        try:
+            for _i, txn, commit_vc, effects in ok:
+                for listener in self.commit_listeners:
+                    listener(effects, commit_vc, self.my_dc)
+                for eff, op in txn.writeset:
+                    self.hooks.execute_post_commit_hook(
+                        eff.key, eff.type_name, eff.bucket, op
+                    )
+        finally:
+            self._publishing_group = False
+        return t_wal0, t_wal1, t_scat, t_fsync
 
     def _gc_committed_keys(self) -> None:
         """Drop certification entries no open (or future) txn can conflict

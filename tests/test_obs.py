@@ -13,7 +13,6 @@ from antidote_tpu.config import AntidoteConfig
 from antidote_tpu.obs import (
     Histogram,
     NodeMetrics,
-    Timer,
     install_error_monitor,
 )
 from antidote_tpu.txn.manager import AbortError
@@ -128,13 +127,6 @@ def test_histogram_buckets_and_percentile():
     text = "\n".join(h.expose())
     assert 'h_bucket{le="10"} 3' in text
     assert "h_count 5" in text
-
-
-def test_timer_feeds_histogram():
-    h = Histogram("t", buckets=(10,))
-    with Timer(h):
-        pass
-    assert h.count == 1
 
 
 def test_metrics_http_exposition():

@@ -413,7 +413,7 @@ def test_pipeline_status_block_exposed():
         pl = st["pipeline"]
         assert pl["epoch_reads"] is True
         assert set(pl["stages"]) == {"decode", "parked", "launch",
-                                     "writeback"}
+                                     "writeback", "request"}
         for s in pl["stages"].values():
             assert {"count", "sum_ms", "mean_us", "p50_us",
                     "p99_us"} <= set(s)

@@ -1,0 +1,449 @@
+"""ISSUE 24 tracing tests: per-request stage records on both front-end
+planes, commit-group phases, the native plane's crossing counters, and the
+stable names of the device programs.
+
+CPU, counts and structure only: no time measured here is a device metric.
+"""
+
+from __future__ import annotations
+
+import logging
+import re
+import time
+
+import jax
+import pytest
+
+from antidote_tpu.api.node import AntidoteNode
+from antidote_tpu.config import AntidoteConfig
+from antidote_tpu.obs import trace
+from antidote_tpu.proto.client import AntidoteClient
+from antidote_tpu.proto.server import ProtocolServer
+
+pytestmark = pytest.mark.smoke
+
+N_KEYS = 12
+
+
+def mk_cfg():
+    # same shapes as test_proto/test_native_frontend: warm compile cache
+    return AntidoteConfig(
+        n_shards=2, max_dcs=2, ops_per_key=8, snap_versions=2,
+        set_slots=8, rga_slots=16, keys_per_table=64, batch_buckets=(8, 64),
+    )
+
+
+def _boot(native: bool):
+    node = AntidoteNode(mk_cfg())
+    srv = ProtocolServer(node, port=0, native_frontend=native,
+                         epoch_tick_ms=25)
+    if native and srv.native is None:
+        srv.close()
+        pytest.skip("native front-end unavailable (no g++?)")
+    return node, srv
+
+
+def _record_closes(monkeypatch):
+    """Wrap the one closing call: every request's record."""
+    seen = []
+    inner = trace.StageAccumulator.close
+
+    def close(self, path, rid, batch_id, stamps):
+        seen.append((path, rid, batch_id, tuple(stamps)))
+        return inner(self, path, rid, batch_id, stamps)
+
+    monkeypatch.setattr(trace.StageAccumulator, "close", close)
+    return seen
+
+
+def _wait_epoch_covers(node, timeout=5.0):
+    txm = node.txm
+    deadline = time.monotonic() + timeout
+    while (node.store.serving_epoch is None
+           or int(node.store.serving_epoch.vc[txm.my_dc])
+           < txm.commit_counter):
+        assert time.monotonic() < deadline, "epoch never covered commits"
+        time.sleep(0.005)
+
+
+# ---------------------------------------------------------------------------
+# (a) per-request stage records, both planes
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("native", [False, True], ids=["python", "native"])
+def test_every_request_has_a_monotone_stage_record(native, monkeypatch):
+    seen = _record_closes(monkeypatch)
+    node, srv = _boot(native)
+    c = AntidoteClient(srv.host, srv.port)
+    try:
+        for i in range(N_KEYS):
+            c.update_objects([(f"k{i}", "counter_pn", "b", ("increment", 1))])
+        _wait_epoch_covers(node)
+        before = c.node_status()["pipeline"]
+        for i in range(N_KEYS):          # one object each: gather path
+            vals, _ = c.read_objects([(f"k{i}", "counter_pn", "b")])
+            assert vals == [1]
+        for i in range(N_KEYS):          # again: cache / native mirror
+            c.read_objects([(f"k{i}", "counter_pn", "b")])
+        after = c.node_status()["pipeline"]
+    finally:
+        c.close()
+        srv.close()
+    gathered = after["reads"]["gather"] - before["reads"].get("gather", 0)
+    assert gathered == N_KEYS
+    paths = after["paths"]
+    assert paths["gather"]["total"]["count"] == N_KEYS
+    assert paths["update"]["total"]["count"] == N_KEYS
+    # every request that crossed into Python closed exactly one record
+    assert {p for p, *_ in seen} <= set(paths) | {"other"}
+    ids = [rid for _p, rid, _b, _s in seen]
+    assert len(set(ids)) == len(ids), "request ids repeat"
+    for path, rid, batch_id, stamps in seen:
+        taken = [t for t in stamps if t]
+        assert taken == sorted(taken), (path, rid, stamps)
+        assert stamps[0] and stamps[-1]
+        if path == "gather":
+            assert all(stamps[2:]), stamps     # every stage of the path
+            assert bool(stamps[1]) is native   # the crossing: native only
+            assert batch_id >= 1
+        if path == "update":
+            assert batch_id >= 1               # its commit group
+    # the stages of a path sum to its total (telescoping, float-exact
+    # to rounding), and each stage was counted once per request
+    for path, blk in paths.items():
+        total = blk["total"]
+        parts = [v for k, v in blk.items() if k != "total"]
+        assert sum(p["sum_ms"] for p in parts) == pytest.approx(
+            total["sum_ms"], rel=1e-9, abs=1e-9), path
+        assert all(p["count"] <= total["count"] for p in parts)
+    g = paths["gather"]
+    want = ["decode", "parked", "launch", "wb_wait", "device_wait",
+            "wb_host", "reply"]
+    assert [s for s in trace.STAGES if s in g] == (
+        ["cross"] if native else []) + want
+    assert all(g[s]["count"] == N_KEYS for s in want)
+    # the request histogram now spans arrival -> sent, once per record
+    assert after["stages"]["request"]["count"] >= len(seen) - 1
+
+
+def test_slow_requests_are_the_slowest_since_the_last_status_read():
+    node, srv = _boot(False)
+    c = AntidoteClient(srv.host, srv.port)
+    try:
+        for i in range(N_KEYS):
+            c.update_objects([(f"k{i}", "counter_pn", "b", ("increment", 1))])
+        slow = c.node_status()["pipeline"]["slow_requests"]
+        again = c.node_status()["pipeline"]["slow_requests"]
+    finally:
+        c.close()
+        srv.close()
+    assert 1 <= len(slow) <= trace.SLOW_KEPT
+    totals = [r["total_ms"] for r in slow]
+    assert totals == sorted(totals, reverse=True)
+    for r in slow:
+        assert set(r) == {"id", "path", "batch", "total_ms", "stages_ms"}
+        assert sum(r["stages_ms"].values()) == pytest.approx(
+            r["total_ms"], abs=0.01)      # each rounded to the microsecond
+    # reading the status opened a new window: only the status call itself
+    assert len(again) <= 1
+
+
+def test_a_request_path_is_its_own_outcome_not_its_batch(monkeypatch):
+    """One launch batch carrying a read whose object hits the snapshot
+    cache and a read that needs the gather: each is counted under its own
+    path; a work no stage answered is counted as shed."""
+    from antidote_tpu.proto.server import _RequestTrace, _StaticWork
+
+    seen = _record_closes(monkeypatch)
+    node, srv = _boot(False)
+    c = AntidoteClient(srv.host, srv.port)
+    try:
+        for i in range(2):
+            c.update_objects([(f"k{i}", "counter_pn", "b", ("increment", 1))])
+        _wait_epoch_covers(node)
+        c.read_objects([("k0", "counter_pn", "b")])    # back-fills k0
+        hit = _StaticWork("read", objects=[("k0", "counter_pn", "b")])
+        miss = _StaticWork("read", objects=[("k1", "counter_pn", "b")])
+        shed = _StaticWork("read", objects=[("k1", "counter_pn", "b")])
+        now = time.monotonic()
+        for w in (hit, miss, shed):
+            w.t_submit = w.t_dequeued = now
+        assert srv._launch_epoch_reads([hit, miss]) == []
+        assert hit.event.wait(5) and miss.event.wait(5)
+        del seen[:]
+        for w in (hit, miss, shed):
+            rec = _RequestTrace(99)
+            rec.begin(now, 0.0)
+            rec.work = w
+            srv._close_request(rec)
+    finally:
+        c.close()
+        srv.close()
+    assert hit.result[0] == [1] and miss.result[0] == [1]
+    assert hit.batch_id == miss.batch_id >= 1
+    assert hit.t_wb_start and not hit.t_synced and miss.t_synced
+    assert [p for p, *_ in seen] == ["cache", "gather", "shed"]
+
+
+# ---------------------------------------------------------------------------
+# (b) commit-group phases
+# ---------------------------------------------------------------------------
+def test_commit_phases_sum_to_the_group_and_count_once_per_group(tmp_path):
+    import dataclasses
+
+    node = AntidoteNode(dataclasses.replace(mk_cfg(), sync_log=True),
+                        log_dir=str(tmp_path))
+    txm = node.txm
+    txm.enable_serving_epochs()
+    try:
+        groups = 5
+        for g in range(groups):
+            txns = []
+            for j in range(3):
+                t = node.start_transaction()
+                node.update_objects(
+                    [(f"k{g}_{j}", "counter_pn", "b", ("increment", 1))], t)
+                txns.append(t)
+            outs = txm.commit_transactions_group(txns)
+            assert not any(isinstance(o, Exception) for o in outs)
+        t = node.start_transaction()      # a read-only commit: no group
+        txm.commit_transactions_group([t])
+        wp = node.status()["write_plane"]
+    finally:
+        node.store.log.close()
+    assert txm.group_seq == groups
+    ph = wp["phases"]
+    in_lock = [ph[p] for p in trace.COMMIT_PHASES]
+    assert all(p["count"] == groups for p in in_lock)
+    assert ph["freeze"]["count"] == groups
+    assert wp["group"]["count"] == groups
+    assert sum(p["sum_ms"] for p in in_lock) == pytest.approx(
+        wp["group"]["sum_ms"], rel=1e-9)
+    assert all(p["sum_ms"] >= 0 for p in ph.values())
+    # the WAL was on and every group published: none of these is skipped
+    for p in ("certify", "wal_append", "scatter", "publish"):
+        assert ph[p]["sum_ms"] > 0, p
+    assert ph["freeze"]["sum_ms"] <= ph["publish"]["sum_ms"]
+
+
+def test_server_reports_ack_phase_and_locked_idle():
+    node, srv = _boot(False)
+    c = AntidoteClient(srv.host, srv.port)
+    try:
+        for i in range(4):
+            c.update_objects([(f"k{i}", "counter_pn", "b", ("increment", 1))])
+        st = c.node_status()
+    finally:
+        c.close()
+        srv.close()
+    wp = st["write_plane"]
+    assert wp["phases"]["ack"]["count"] == wp["phases"]["certify"]["count"]
+    assert wp["phases"]["ack"]["count"] == node.txm.group_seq >= 1
+    assert wp["locked_idle"]["count"] >= wp["group"]["count"]
+    assert st["pipeline"]["paths"]["update"]["parked"]["count"] == 4
+
+
+# ---------------------------------------------------------------------------
+# (c) the native plane's crossing counters
+# ---------------------------------------------------------------------------
+def test_native_cross_frames_equal_forwarded():
+    node, srv = _boot(True)
+    c = AntidoteClient(srv.host, srv.port)
+    try:
+        for i in range(N_KEYS):
+            c.update_objects([(f"k{i}", "counter_pn", "b", ("increment", 1))])
+        for i in range(N_KEYS):
+            c.read_objects([(f"k{i}", "counter_pn", "b")])
+        time.sleep(0.2)        # the last reply's bytes reach the socket
+        nat = c.node_status()["pipeline"]["native"]
+        # the status request itself has crossed but not been answered yet
+        assert nat["cross_frames"] == nat["forwarded"] >= 2 * N_KEYS
+        assert nat["send_frames"] == nat["forwarded"] - 1
+        assert nat["cross_wait_us"] >= 0 and nat["send_wait_us"] >= 0
+    finally:
+        c.close()
+        srv.close()
+
+
+# ---------------------------------------------------------------------------
+# the accumulators and the span call themselves
+# ---------------------------------------------------------------------------
+def test_stage_accumulator_names_the_stage_closed_at_ready():
+    acc = total = trace.StageAccumulator()
+    #        arrive taken submit deq  launch wb   sync ready sent
+    acc.close("gather", (1, 1), 7, (1.0, 1.1, 1.2, 1.3, 1.4, 1.5, 1.6, 1.7,
+                                    1.8))
+    acc.close("update", (1, 2), 3, (2.0, 0.0, 2.1, 2.2, 0.0, 0.0, 0.0, 2.6,
+                                    2.7))
+    acc.close("cache", (1, 3), 0, (3.0, 3.1, 0.0, 0.0, 0.0, 0.0, 0.0, 3.2,
+                                   3.3))
+    st = total.status()
+    assert set(st["paths"]["gather"]) == set(trace.STAGES) - {"exec"} | {
+        "total"}
+    assert set(st["paths"]["update"]) == {"decode", "parked", "exec",
+                                          "reply", "total"}
+    assert st["paths"]["update"]["exec"]["sum_ms"] == pytest.approx(400.0)
+    assert set(st["paths"]["cache"]) == {"cross", "exec", "reply", "total"}
+    assert [r["id"] for r in st["slow_requests"]] == [[1, 1], [1, 2], [1, 3]]
+    assert st["slow_requests"][0]["batch"] == 7
+    assert total.status()["slow_requests"] == []        # a new window
+    # the sums run on since boot, the slow records since the last read
+    acc.close("cache", (2, 1), 0, (5.0, 0.0, 0.0, 0.0, 0.0, 0.0, 0.0, 5.1,
+                                   5.2))
+    st2 = total.status()
+    assert st2["paths"]["gather"] == st["paths"]["gather"]
+    assert st2["paths"]["cache"]["total"]["count"] == 2
+    assert [r["id"] for r in st2["slow_requests"]] == [[2, 1]]
+
+
+def test_stage_accumulator_keeps_only_the_slowest():
+    total = trace.StageAccumulator()
+    for i in range(3 * trace.SLOW_KEPT):
+        total.close("other", (1, i), 0,
+                    (0.0 + 1e-9, 0, 0, 0, 0, 0, 0, 0, float(i + 1)))
+    slow = total.status()["slow_requests"]
+    assert [r["id"][1] for r in slow] == list(
+        range(3 * trace.SLOW_KEPT - 1, 2 * trace.SLOW_KEPT - 1, -1))
+
+
+def test_phase_accumulator_sums_to_last_minus_first():
+    acc = trace.PhaseAccumulator()
+    acc.add_group((10.0, 10.5, 10.5, 11.0, 11.25, 11.25, 12.0), 0.125)
+    acc.add("ack", 0.5)
+    st = acc.status()
+    assert sum(st[p]["sum_ms"] for p in trace.COMMIT_PHASES) == 2000.0
+    assert st["wal_append"] == {"sum_ms": 0.0, "count": 1}
+    assert st["freeze"]["sum_ms"] == 125.0 and st["ack"]["count"] == 1
+
+
+def test_span_is_a_trace_annotation_and_inert_without_a_session():
+    s = trace.span("serve.launch", batch=3, objects=64)
+    assert isinstance(s, jax.profiler.TraceAnnotation)
+    with s:
+        pass
+
+
+# ---------------------------------------------------------------------------
+# (d) every jitted serving program lowers under a stable name
+# ---------------------------------------------------------------------------
+class _Names(logging.Handler):
+    def __init__(self):
+        super().__init__(logging.DEBUG)
+        self.names = []
+
+    def emit(self, record):
+        m = re.search(r"Compiling (?:jit\()?([^ ()]+)", record.getMessage())
+        if m:
+            self.names.append(m.group(1))
+
+
+@pytest.fixture(scope="module")
+def lowered_names(tmp_path_factory):
+    """Names of every module lowered while a node on a 2-device mesh
+    serves writes past a ring overflow, reads at the head, at an earlier
+    snapshot, through the frozen serving epoch and the mesh gather, a
+    tier promotion, a checkpoint gather and an eviction."""
+    from antidote_tpu.parallel.mesh import MeshServingPlane
+
+    cfg = AntidoteConfig(
+        n_shards=2, max_dcs=2, ops_per_key=4, snap_versions=2, set_slots=2,
+        keys_per_table=8, batch_buckets=(8,), use_pallas=False)
+    h = _Names()
+    lg = logging.getLogger("jax")
+    lg.addHandler(h)
+    try:
+        with jax.log_compiles():
+            for mesh in (False, True):
+                node = AntidoteNode(cfg)
+                store, txm = node.store, node.txm
+                if mesh:
+                    MeshServingPlane(cfg, n_devices=2).attach(store)
+                txm.enable_serving_epochs()
+                t_old = None
+                for i in range(6):       # ring of 4: GC; 2 slots: promotion
+                    t = node.start_transaction()
+                    node.update_objects(
+                        [("s", "set_aw", "b", ("add", f"e{i}")),
+                         ("c", "counter_pn", "b", ("increment", 1))], t)
+                    node.commit_transaction(t)
+                    if i == 3:
+                        t_old = node.start_transaction()
+                txm.publish_serving_epoch()
+                node.read_objects([("c", "counter_pn", "b")], t_old)
+                node.commit_transaction(t_old)
+                objs = [("s", "set_aw", "b"), ("c", "counter_pn", "b")]
+                node.read_objects(objs)
+                ep = store.pin_serving_epoch()
+                pending, fallback = store.epoch_read_launch(objs, ep)
+                store.epoch_read_finish(pending)
+                store.unpin_serving_epoch(ep)
+                t = store.table("counter_pn")
+                t.gather_rows_dispatch([0], [0])
+                t.publish_epoch()
+                t.evict_rows([0], [0])  # evict-ok: names only, node dropped
+                if mesh:
+                    store.mesh.stable_vc()
+    finally:
+        lg.removeHandler(h)
+    return h.names
+
+
+PROGRAMS = [
+    "antidote_commit_scatter_ring", "antidote_commit_scatter_head_w1",
+    "antidote_gc", "antidote_tier_promote", "antidote_head_gather",
+    "antidote_head_gather_routed", "antidote_read_latest",
+    "antidote_read_resolved_", "antidote_freeze_serving_copy",
+    "antidote_freeze_serving_scatter", "antidote_freeze_serving_scatter_routed",
+    "antidote_mesh_gather", "antidote_mesh_pmin", "antidote_ckpt_gather",
+    "antidote_evict_clear",
+]
+
+
+@pytest.mark.parametrize("program", PROGRAMS)
+def test_device_program_lowers_under_its_stable_name(lowered_names, program):
+    assert any(n.startswith(program) for n in lowered_names), (
+        program, sorted(set(lowered_names)))
+
+
+def test_no_device_program_lowers_under_an_inner_function_name(lowered_names):
+    # what is left without the prefix are jax's own eager primitives
+    # (jit(broadcast_in_dim), ...): none of them an inner function's name
+    anonymous = {"fn", "read", "append", "gc", "<lambda>", "_lambda_", "body",
+                 "step", "program", "<unnamed", "per_shard"}
+    bad = [n for n in lowered_names
+           if not n.startswith(trace.PROGRAM_PREFIX)
+           and any(n.startswith(a) for a in anonymous)]
+    assert not bad, sorted(set(bad))
+
+
+def test_device_program_module_name_scope_and_donation():
+    import jax.numpy as jnp
+
+    @trace.device_program("unit_double", donate_argnums=(0,))
+    def fn(x, y):
+        return x * 2 + y
+
+    low = fn.lower(jnp.ones((4,), jnp.float32), jnp.ones((4,), jnp.float32))
+    text = low.as_text(debug_info=True)
+    assert "module @jit_antidote_unit_double " in text
+    assert "antidote_unit_double/" in text          # the named_scope
+    assert "jax.buffer_donor" in text or "tf.aliasing_output" in text
+    assert fn.__name__ == "antidote_unit_double"
+
+    # static argument NAMES resolve through the body's own signature
+    @trace.device_program("unit_scale", static_argnames=("k",))
+    def scale(x, k: int):
+        return x * k if k > 1 else x
+
+    assert float(scale(jnp.ones(()), 3)) == 3.0
+
+
+@pytest.mark.parametrize("kernel", ["counter_fold", "stable_min",
+                                    "set_aw_fold", "orset_presence"])
+def test_pallas_kernel_carries_its_name(kernel):
+    import inspect
+
+    from antidote_tpu.materializer import pallas_kernels as pk
+
+    src = inspect.getsource(pk)
+    assert f'name="antidote_{kernel}"' in src
+    assert f'@device_program("{kernel}"' in src
