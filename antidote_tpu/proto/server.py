@@ -324,11 +324,26 @@ class ProtocolServer:
         self._epoch_chunk = self.EPOCH_LAUNCH_CHUNK * (
             mesh.n_devices if mesh is not None else 1)
         #: launched-but-unmaterialized epoch read batches between the
-        #: dispatcher and the writeback worker.  BOUNDED: a lagging
-        #: writeback stage backpressures the dispatcher (which then
-        #: backpressures the bounded batch gate) instead of queueing
-        #: device handles without limit.
-        self._writeback_q: "queue.Queue" = queue.Queue(maxsize=16)
+        #: dispatcher and the writeback worker.  BOUNDED by ``DEPTH``
+        #: slots, and the bound is taken BEFORE the launch (ISSUE 25):
+        #: the dispatcher waits for a slot with nothing launched in its
+        #: hands (``_take_wb_slot``), so a lagging writeback stage
+        #: backpressures the bounded batch gate — where requests carry a
+        #: deadline and a tenant lane and the next launch takes all of
+        #: them in one merged gather — instead of a queue of launched
+        #: device handles.  The handoff itself never blocks.
+        # bounded-by: DEPTH writeback slots, one taken before every launch
+        self._writeback_q: "queue.Queue" = queue.Queue()
+        #: guards ``_wb_unfinished``: batches launched and not yet
+        #: finished by the writeback stage (queued or in its hands)
+        self._wb_slots = threading.Condition(threading.Lock())
+        self._wb_unfinished = 0
+        #: node status ``pipeline.gate_hold``: dispatcher rounds that
+        #: launched, those that waited for a slot, seconds waited —
+        #: written by the dispatcher thread only
+        self._gate_hold = {"rounds": 0, "held": 0, "sum_s": 0.0}
+        #: seconds the dispatcher's current round waited for slots
+        self._round_held_s = 0.0
         #: the LOCKED plane's feed: update groups, interactive COMMITs
         #: (the cross-connection group-commit merge point, ISSUE 6) and
         #: reads the epoch cannot serve, processed by a dedicated worker
@@ -1060,20 +1075,43 @@ class ProtocolServer:
                 w.event.set()
 
     def _static_loop(self):
-        """The DISPATCHER stage of the serving pipeline: drain whatever
-        queued while the previous group executed, LAUNCH merged epoch
-        reads lock-free (device handles go to the writeback stage — this
-        thread never blocks on the device), and forward everything else
-        to the locked-plane worker.  Natural batching — no gather delay:
-        at low load a lone request runs immediately; under load the
-        batch grows to whatever queued during the previous launch, and
-        batch N+1 is being decoded by handler threads while batch N
-        executes on device and batch N-1's replies are serialized by the
-        writeback worker."""
+        """The DISPATCHER stage of the serving pipeline: take what is at
+        the gate, wait until a writeback slot is free, drain whatever
+        parked meanwhile, LAUNCH merged epoch reads lock-free (device
+        handles go to the writeback stage — this thread never blocks on
+        the device, and never with a launched batch in its hands), and
+        forward everything else to the locked-plane worker.  Natural
+        batching — no gather delay, no timer: with nothing in flight
+        there is no wait and a lone request runs immediately; under load
+        the backpressure is taken HERE, before the launch: while
+        ``DEPTH`` launched batches are unfinished the dispatcher holds
+        the gate (host span ``serve.gate_wait.slot``, node status
+        ``pipeline.gate_hold``), the hold ends the instant the writeback
+        stage finishes one, and the next launch carries everything that
+        parked in the meantime — batch N+1 accumulates at the gate while
+        batch N executes on device and batch N-1's replies are
+        serialized by the writeback worker.  The hold is part of a
+        request's ``parked`` stage: ``t_dequeued`` is stamped after the
+        drain that follows it, and deadline shedding at dequeue sees it."""
         q = self._static_q
         m = self.metrics
         while True:
             works, stop, _ = self._drain_batch(q, "serve.gate_wait")
+            slot = False
+            self._round_held_s = 0.0
+            if (self._epoch_reads and not stop
+                    and any(w.kind == "read" for w in works)):
+                slot = self._take_wb_slot()
+                # everything that parked during the hold rides this launch
+                while self._round_held_s and len(works) < self._batch_max:
+                    try:
+                        w = q.get_nowait()
+                    except queue.Empty:
+                        break
+                    if w is _STOP:
+                        stop = True
+                    else:
+                        works.append(w)
             m.commit_gate_depth.set(q.qsize())
             works = self._shed_expired(works, "batch gate",
                                        observe_parked=True)
@@ -1084,7 +1122,8 @@ class ProtocolServer:
                     # lock-split: reads pinned at/below the published
                     # serving epoch never park behind a commit group
                     t0 = time.monotonic()
-                    reads = self._launch_epoch_reads(reads)
+                    held, slot = slot, False
+                    reads = self._launch_epoch_reads(reads, held)
                     m.stage_launch_seconds.observe(time.monotonic() - t0)
                 # updates and unservable reads go to the locked-plane
                 # worker: a commit group (or the compile hiding inside
@@ -1119,6 +1158,9 @@ class ProtocolServer:
                     if not w.event.is_set():
                         w.error = e
                         w.event.set()
+            if slot:
+                # every read was shed at dequeue: nothing to launch
+                self._release_wb_slot()
             if stop:
                 self._locked_q.put(_STOP)
                 self._fail_queue_remainder(q)
@@ -1174,90 +1216,160 @@ class ProtocolServer:
     # ------------------------------------------------------------------
     # lock-split epoch reads (dispatcher launch stage)
     # ------------------------------------------------------------------
-    def _launch_epoch_reads(
-            self, works: List[_StaticWork]) -> List[_StaticWork]:
+    def _take_wb_slot(self) -> bool:
+        """Take one of the ``DEPTH`` writeback slots before a launch.
+        With a slot free this is one lock take; with ``DEPTH`` batches
+        launched and unfinished it waits — host span
+        ``serve.gate_wait.slot``, added to the round's hold — until the
+        writeback stage finishes one (``_release_wb_slot``; a condition,
+        never a poll or a timer).  False: shutdown ended the hold and
+        nothing may be launched."""
+        cv = self._wb_slots
+        with cv:
+            if self._wb_unfinished >= self.DEPTH:
+                t0 = time.monotonic()
+                with span("serve.gate_wait.slot"):
+                    while self._wb_unfinished >= self.DEPTH:
+                        if self._closing:
+                            return False
+                        cv.wait()
+                self._round_held_s += time.monotonic() - t0
+            self._wb_unfinished += 1
+        return True
+
+    def _release_wb_slot(self) -> None:
+        """Give a writeback slot back: the writeback stage finished a
+        batch, or a launch chunk handed it nothing."""
+        with self._wb_slots:
+            self._wb_unfinished -= 1
+            self._wb_slots.notify()
+
+    def _launch_epoch_reads(self, works: List[_StaticWork],
+                            slot: bool = False) -> List[_StaticWork]:
         """Launch epoch-eligible read works as merged lock-free gathers
         against the frozen serving epoch (async dispatch only — never a
         device sync) and hand the device handles to the writeback stage.
+        Every chunk launches under a writeback slot of its own: the
+        first under the one the dispatcher took before its drain
+        (``slot``), each further chunk of an oversized round under one
+        taken — and possibly waited for — here.
         Returns the works that must take the locked path: clocks ahead
         of the epoch, objects the epoch cannot serve (composite maps,
         promoted keys, unfrozen tables), or no epoch at all."""
         leftover: List[_StaticWork] = []
-        for chunk in self._chunk_epoch_works(works):
-            leftover.extend(self._launch_epoch_chunk(chunk))
+        seq0 = self._launch_seq
+        try:
+            for chunk in self._chunk_epoch_works(works):
+                if not slot and not self._take_wb_slot():
+                    for w in chunk:
+                        w.error = ConnectionError("server shutting down")
+                        w.event.set()
+                    continue
+                slot = False  # the chunk owns it now
+                leftover.extend(self._launch_epoch_chunk(chunk))
+        finally:
+            if slot:
+                self._release_wb_slot()
+        if self._launch_seq != seq0:
+            hold = self._gate_hold
+            hold["rounds"] += 1
+            if self._round_held_s:
+                hold["held"] += 1
+                hold["sum_s"] += self._round_held_s
         return leftover
 
     def _launch_epoch_chunk(
             self, works: List[_StaticWork]) -> List[_StaticWork]:
-        """One bounded launch chunk: pin the epoch, classify, launch ONE
-        merged gather, enqueue for writeback.  Returns locked-path works."""
-        txm = self.node.txm
-        store = txm.store
-        ep = store.pin_serving_epoch()
-        if ep is None:
-            return works
-        # a clockless read must still see every locally-ACKED commit.
-        # Commit groups publish BEFORE replying, so acked == covered —
-        # except across a deferred/failed publish, which raises the lag
-        # floor; an epoch below the floor cannot serve clockless reads.
-        # (Deliberately NOT commit_counter: a commit minted mid-flight
-        # has not acked yet, and gating on it would park reads behind
-        # every in-flight commit — the convoy this plane removes.)
-        if int(ep.vc[txm.my_dc]) < txm.epoch_lag_counter:
-            store.unpin_serving_epoch(ep)
-            return works
-        merged: List[_StaticWork] = []
-        locked: List[_StaticWork] = []
-        for w in works:
-            if w.clock is None or (w.clock <= ep.vc).all():
-                merged.append(w)
-            else:
-                locked.append(w)
-        if not merged:
-            store.unpin_serving_epoch(ep)
-            return works
-        objs: list = []
-        spans = []
-        for w in merged:
-            spans.append((len(objs), len(objs) + len(w.objects)))
-            objs.extend(w.objects)
-        self._launch_seq = batch_id = self._launch_seq + 1
+        """One bounded launch chunk, its writeback slot already taken:
+        pin the epoch, classify, launch ONE merged gather, hand it to
+        the writeback stage (never blocks).  Every path that hands
+        nothing over gives the slot back.  Returns locked-path works."""
+        handed = False
         try:
-            with span("serve.launch", batch=batch_id, objects=len(objs)):
-                pending, fallback = store.epoch_read_launch(objs, ep)
-        except BaseException:
-            store.unpin_serving_epoch(ep)
-            log.exception("epoch read launch failed; locked fallback")
-            return works
-        t_launched = time.monotonic()
-        keep, kspans = merged, spans
-        if fallback:
-            fb = set(fallback)
-            keep, kspans = [], []
-            for w, (lo, hi) in zip(merged, spans):
-                if fb.isdisjoint(range(lo, hi)):
-                    keep.append(w)
-                    kspans.append((lo, hi))
+            txm = self.node.txm
+            store = txm.store
+            ep = store.pin_serving_epoch()
+            if ep is None:
+                return works
+            # a clockless read must still see every locally-ACKED
+            # commit.  Commit groups publish BEFORE replying, so acked
+            # == covered — except across a deferred/failed publish,
+            # which raises the lag floor; an epoch below the floor
+            # cannot serve clockless reads.  (Deliberately NOT
+            # commit_counter: a commit minted mid-flight has not acked
+            # yet, and gating on it would park reads behind every
+            # in-flight commit — the convoy this plane removes.)
+            if int(ep.vc[txm.my_dc]) < txm.epoch_lag_counter:
+                store.unpin_serving_epoch(ep)
+                return works
+            merged: List[_StaticWork] = []
+            locked: List[_StaticWork] = []
+            for w in works:
+                if w.clock is None or (w.clock <= ep.vc).all():
+                    merged.append(w)
                 else:
-                    # a work with ANY unservable object reroutes whole —
-                    # its launched siblings' results are simply dropped
                     locked.append(w)
-        if not keep:
-            store.unpin_serving_epoch(ep)
+            if not merged:
+                store.unpin_serving_epoch(ep)
+                return works
+            objs: list = []
+            spans = []
+            for w in merged:
+                spans.append((len(objs), len(objs) + len(w.objects)))
+                objs.extend(w.objects)
+            self._launch_seq = batch_id = self._launch_seq + 1
+            try:
+                with span("serve.launch", batch=batch_id,
+                          objects=len(objs)):
+                    pending, fallback = store.epoch_read_launch(objs, ep)
+            except BaseException:
+                store.unpin_serving_epoch(ep)
+                log.exception("epoch read launch failed; locked fallback")
+                return works
+            t_launched = time.monotonic()
+            keep, kspans = merged, spans
+            if fallback:
+                fb = set(fallback)
+                keep, kspans = [], []
+                for w, (lo, hi) in zip(merged, spans):
+                    if fb.isdisjoint(range(lo, hi)):
+                        keep.append(w)
+                        kspans.append((lo, hi))
+                    else:
+                        # a work with ANY unservable object reroutes
+                        # whole — its launched siblings' results are
+                        # simply dropped
+                        locked.append(w)
+            if not keep:
+                store.unpin_serving_epoch(ep)
+                return locked
+            vc_list = [int(x) for x in ep.vc]
+            # the slot taken before the launch bounds this queue: the
+            # handoff never blocks
+            self._writeback_q.put(_EpochReadBatch(pending, keep, kspans,
+                                                  vc_list, batch_id,
+                                                  t_launched))
+            handed = True
             return locked
-        vc_list = [int(x) for x in ep.vc]
-        # bounded handoff: a lagging writeback stage backpressures this
-        # dispatcher (and through the bounded gate, the clients)
-        self._writeback_q.put(_EpochReadBatch(pending, keep, kspans,
-                                              vc_list, batch_id,
-                                              t_launched))
-        return locked
+        finally:
+            if not handed:
+                self._release_wb_slot()
 
     #: merged epoch-read launches are chunked at this many objects: one
     #: padded-batch XLA bucket serves every chunk, so a saturated gate
     #: can never mint a brand-new (bigger) bucket shape — and its
     #: multi-second compile — in the middle of serving traffic
     EPOCH_LAUNCH_CHUNK = 512
+    #: launched read batches the writeback stage has not finished, at
+    #: most: one in the writeback thread's hands and one launched and on
+    #: the device, so the device's gather and the transfer back overlap
+    #: the writeback's host work and a read that arrives during a
+    #: writeback launches at once.  On the chip (PERF.md §6, PR 25): 3
+    #: only queues one more launched batch (read cell -9% ops/s, +6%
+    #: p95); 1 reads the saturated read cell as well as 2, but makes a
+    #: lightly loaded pipeline's read wait out the writeback in progress
+    #: before it may launch (counter cell read p95 +15%).
+    DEPTH = 2
 
     def _chunk_epoch_works(self, works: List[_StaticWork]):
         """Split eligible works into launch chunks of ≤ the epoch chunk
@@ -1324,6 +1436,8 @@ class ProtocolServer:
             finally:
                 store.unpin_serving_epoch(batch.pending.ep)
                 m.stage_writeback_seconds.observe(time.monotonic() - t0)
+                # ends the dispatcher's hold at the gate, if it is in one
+                self._release_wb_slot()
 
     # ------------------------------------------------------------------
     # serving-epoch ticker (dedicated publication thread)
@@ -2027,6 +2141,13 @@ class ProtocolServer:
             },
             "serving_epoch_id": int(m.serving_epoch_id.value()),
             "writeback_depth": self._writeback_q.qsize(),
+            # how often, and for how long, the dispatcher held the gate
+            # for a writeback slot (of its rounds that launched)
+            "gate_hold": {
+                "rounds": self._gate_hold["rounds"],
+                "held": self._gate_hold["held"],
+                "sum_ms": round(self._gate_hold["sum_s"] * 1e3, 3),
+            },
             "locked_depth": self._locked_q.qsize(),
             "group_commit_window_us": round(self._group_window_s * 1e6, 1),
         }
@@ -2053,6 +2174,10 @@ class ProtocolServer:
 
     def close(self) -> None:
         self._closing = True
+        with self._wb_slots:
+            # a dispatcher holding the gate for a writeback slot stops
+            # waiting; what it holds fails as the gate's remainder does
+            self._wb_slots.notify_all()
         self._ticker_stop.set()
         if self.proxy is not None:
             self.proxy.close()
@@ -2082,19 +2207,8 @@ class ProtocolServer:
                     time.sleep(0.05)
             self._batcher.join(timeout=5)
             # stop the writeback stage AFTER the dispatcher: in-flight
-            # launched batches still get materialized and replied.
-            # Fresh grace window — the gate put loop + batcher join may
-            # have consumed the earlier one entirely, and giving up on
-            # the first Full would drop in-flight replies.
-            stop_by = time.monotonic() + 5.0
-            while True:
-                try:
-                    self._writeback_q.put_nowait(_STOP)
-                    break
-                except queue.Full:
-                    if time.monotonic() >= stop_by:
-                        break
-                    time.sleep(0.05)
+            # launched batches still get materialized and replied
+            self._writeback_q.put(_STOP)
             self._writeback.join(timeout=5)
             # the dispatcher's stop path forwarded _STOP to the locked
             # worker; it drains whatever raced in behind the sentinel

@@ -419,6 +419,317 @@ def test_pipeline_status_block_exposed():
                     "p99_us"} <= set(s)
         assert pl["serving_epoch_id"] >= 1
         assert "hit" in pl["snapshot_cache"] or pl["snapshot_cache"]
+        assert set(pl["gate_hold"]) == {"rounds", "held", "sum_ms"}
+        assert pl["gate_hold"]["rounds"] >= 1
+        assert pl["writeback_depth"] == 0
     finally:
         c.close()
         srv.close()
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 25: the backpressure is taken before the launch — the dispatcher
+# holds the batch gate until a writeback slot is free
+# ---------------------------------------------------------------------------
+DEPTH = ProtocolServer.DEPTH
+N_HELD = 9          # reads parked while every slot is taken
+
+
+class _Pipeline:
+    """A Python-plane server over N counters (key i reads i + 1) whose
+    writeback stage the test can hold inside ``epoch_read_finish``."""
+
+    def __init__(self, n_keys):
+        self.node = AntidoteNode(AntidoteConfig(
+            n_shards=4, max_dcs=2, keys_per_table=256))
+        self.srv = ProtocolServer(self.node, port=0, epoch_tick_ms=25,
+                                  native_frontend=False)
+        c = AntidoteClient(self.srv.host, self.srv.port)
+        try:
+            for i in range(n_keys):
+                c.update_objects(
+                    [(f"k{i}", "counter_pn", "b", ("increment", i + 1))])
+        finally:
+            c.close()
+        _wait_epoch_covers(self.node)
+        self.store = self.node.txm.store
+        self.gate = threading.Event()
+        self.gate.set()
+        finish = self.store.epoch_read_finish
+
+        def held_finish(pending):
+            assert self.gate.wait(30), "test never released the writeback"
+            return finish(pending)
+
+        self.store.epoch_read_finish = held_finish
+        self.results = {}
+        self.threads = []
+
+    def status(self):
+        return self.srv._pipeline_status()
+
+    def read(self, i, deadline=None):
+        """One one-object static read on a thread of its own (what a
+        connection thread does once the frame is decoded)."""
+        def run():
+            try:
+                vals, _vc = self.srv.static_read(
+                    [(f"k{i}", "counter_pn", "b")], None, deadline=deadline)
+                self.results[i] = vals
+            except BaseException as e:  # noqa: BLE001 — the test reads it
+                self.results[i] = e
+
+        t = threading.Thread(target=run, daemon=True)
+        t.start()
+        self.threads.append(t)
+        return t
+
+    def fill_slots(self):
+        """Hold the writeback stage and launch DEPTH one-read batches, one
+        after the other: every slot is taken, nothing is held yet."""
+        self.gate.clear()
+        seq0 = self.srv._launch_seq
+        for i in range(DEPTH):
+            self.read(i)
+            self.wait(lambda: self.srv._launch_seq == seq0 + i + 1)
+        assert self.srv._wb_unfinished == DEPTH
+
+    def wait(self, cond, timeout=10.0):
+        deadline = time.monotonic() + timeout
+        while not cond():
+            assert time.monotonic() < deadline, "condition never held"
+            time.sleep(0.002)
+
+    def wait_submitted(self, n):
+        """Until n reads beyond the DEPTH launched ones are inside
+        ``_submit``: parked at the gate, or in the hands of the
+        dispatcher, which took the first and holds."""
+        from antidote_tpu.tenancy import DEFAULT_TENANT
+
+        self.wait(lambda: self.srv.admission.tenant_in_flight(
+            DEFAULT_TENANT) == DEPTH + n)
+        self.wait(lambda: self.srv._static_q.qsize() < n)
+        time.sleep(0.05)
+
+    def join(self):
+        for t in self.threads:
+            t.join(10)
+            assert not t.is_alive()
+
+    def close(self):
+        self.gate.set()
+        self.srv.close()
+
+
+def test_held_gate_merges_everything_parked_into_one_launch():
+    p = _Pipeline(DEPTH + N_HELD)
+    try:
+        before = p.status()
+        p.fill_slots()
+        for i in range(DEPTH, DEPTH + N_HELD):
+            p.read(i)
+        p.wait_submitted(N_HELD)
+        time.sleep(0.1)
+        # every slot taken: nothing more was launched, whatever parked
+        held = p.status()
+        assert (held["stages"]["launch"]["count"]
+                - before["stages"]["launch"]["count"]) == DEPTH
+        assert held["reads"]["gather"] - before["reads"].get(
+            "gather", 0) == DEPTH
+        assert p.srv._wb_unfinished == DEPTH
+        p.gate.set()
+        p.join()
+        after = p.status()
+    finally:
+        p.close()
+    # ... and the rest rode ONE merged launch once a slot came free
+    assert (after["stages"]["launch"]["count"]
+            - before["stages"]["launch"]["count"]) == DEPTH + 1
+    assert after["reads"]["gather"] - before["reads"].get(
+        "gather", 0) == DEPTH + N_HELD
+    assert p.results == {i: [i + 1] for i in range(DEPTH + N_HELD)}
+    hold0, hold1 = before["gate_hold"], after["gate_hold"]
+    assert hold1["rounds"] - hold0["rounds"] == DEPTH + 1
+    assert hold1["held"] - hold0["held"] == 1
+    assert hold1["sum_ms"] - hold0["sum_ms"] >= 100.0
+    assert p.srv._wb_unfinished == 0
+
+
+def test_lone_read_on_an_idle_server_is_not_held():
+    p = _Pipeline(1)
+    try:
+        before = p.status()["gate_hold"]
+        p.read(0).join(10)
+        after = p.status()["gate_hold"]
+    finally:
+        p.close()
+    assert p.results == {0: [1]}
+    assert after["rounds"] == before["rounds"] + 1
+    assert after["held"] == before["held"]
+    assert after["sum_ms"] == before["sum_ms"]
+
+
+@pytest.mark.parametrize("fault", ["no_epoch", "below_lag_floor",
+                                   "launch_raises", "all_rerouted",
+                                   "clock_ahead"])
+def test_rounds_that_hand_nothing_to_writeback_leak_no_slot(fault):
+    """More than DEPTH consecutive rounds on each path of a launch chunk
+    that hands nothing over: a leaked slot would wedge the next read."""
+    import numpy as np
+
+    from antidote_tpu.proto.server import _StaticWork
+
+    n = DEPTH + 2
+    p = _Pipeline(n + 1)
+    store, txm = p.store, p.node.txm
+    try:
+        if fault == "clock_ahead":
+            # nothing mergeable: the locked plane would park such a read
+            # until its clock is covered, so the chunk is driven directly
+            ahead = np.asarray(store.serving_epoch.vc) + 1
+            for i in range(n):
+                w = _StaticWork("read", objects=[(f"k{i}", "counter_pn",
+                                                  "b")], clock=ahead)
+                assert p.srv._take_wb_slot()
+                assert p.srv._launch_epoch_chunk([w]) == [w]
+                assert p.srv._wb_unfinished == 0
+        else:
+            if fault == "no_epoch":
+                store.pin_serving_epoch = lambda: None
+            elif fault == "below_lag_floor":
+                floor, txm.epoch_lag_counter = txm.epoch_lag_counter, 1 << 60
+            elif fault == "launch_raises":
+                def launch(objs, ep):
+                    raise RuntimeError("planted launch fault")
+            else:
+                real = store.epoch_read_launch
+
+                def launch(objs, ep):
+                    pending, _fb = real(objs, ep)
+                    return pending, list(range(len(objs)))
+            if fault in ("launch_raises", "all_rerouted"):
+                store.epoch_read_launch = launch
+            for i in range(n):                  # one round each
+                p.read(i).join(10)
+                assert p.srv._wb_unfinished == 0
+            # the locked plane answered every one of them, exactly
+            assert p.results == {i: [i + 1] for i in range(n)}
+            assert p.status()["reads"]["locked"] == n
+            if fault == "below_lag_floor":
+                txm.epoch_lag_counter = floor
+            else:
+                for name in ("pin_serving_epoch", "epoch_read_launch"):
+                    store.__dict__.pop(name, None)
+        seq0 = p.srv._launch_seq
+        p.read(n).join(10)                      # a plain read is served
+        assert p.results[n] == [n + 1]
+        assert p.srv._launch_seq == seq0 + 1
+        assert p.srv._wb_unfinished == 0
+    finally:
+        p.close()
+
+
+def test_close_during_a_hold_fails_what_is_parked_promptly():
+    p = _Pipeline(DEPTH + 4)
+    try:
+        p.fill_slots()
+        for i in range(DEPTH, DEPTH + 4):
+            p.read(i)
+        p.wait_submitted(4)
+        seq0 = p.srv._launch_seq
+        t0 = time.monotonic()
+        closer = threading.Thread(target=p.srv.close, daemon=True)
+        closer.start()
+        # the hold ends on shutdown, not on a slot: the writeback stage is
+        # still held, and what was parked fails as the gate's remainder does
+        for t in p.threads[DEPTH:]:
+            t.join(5)
+            assert not t.is_alive()
+        assert time.monotonic() - t0 < 1.0
+        for i in range(DEPTH, DEPTH + 4):
+            assert isinstance(p.results[i], ConnectionError), p.results[i]
+        assert p.srv._launch_seq == seq0        # and nothing was launched
+        p.gate.set()
+        closer.join(5)
+        assert not closer.is_alive()
+        assert time.monotonic() - t0 < 2.0
+        # what was launched before the close is still answered
+        p.join()
+        assert [p.results[i] for i in range(DEPTH)] == [
+            [i + 1] for i in range(DEPTH)]
+    finally:
+        p.gate.set()
+
+
+def test_deadline_passing_during_a_hold_sheds_at_dequeue():
+    from antidote_tpu.overload import DeadlineExceeded
+
+    p = _Pipeline(DEPTH + 2)
+    try:
+        before = p.status()
+        p.fill_slots()
+        p.read(DEPTH, deadline=time.monotonic() + 0.05)
+        p.read(DEPTH + 1)
+        p.wait_submitted(2)
+        time.sleep(0.1)                 # the deadline passes in the hold
+        p.gate.set()
+        p.join()
+        after = p.status()
+    finally:
+        p.close()
+    assert isinstance(p.results[DEPTH], DeadlineExceeded), p.results[DEPTH]
+    assert p.results[DEPTH + 1] == [DEPTH + 2]
+    # the expired read was not launched: one object in the merged launch
+    assert after["reads"]["gather"] - before["reads"].get(
+        "gather", 0) == DEPTH + 1
+
+
+def test_slots_stay_bounded_and_balanced_under_a_thread_storm():
+    """More reader threads than cores against a slowed writeback stage,
+    with a short switch interval: never more than DEPTH batches launched
+    and unfinished, every slot given back, every answer exact."""
+    import sys
+
+    n_keys, n_threads, rounds = 48, 24, 6
+    p = _Pipeline(n_keys)
+    p.store.snapshot_cache_cap = 0          # every read is a gather
+    finish = p.store.epoch_read_finish
+    seen = []
+
+    def slow_finish(pending):
+        seen.append((p.srv._wb_unfinished, p.srv._writeback_q.qsize()))
+        time.sleep(0.002)
+        return finish(pending)
+
+    p.store.epoch_read_finish = slow_finish
+    wrong = []
+
+    def reader(t):
+        for r in range(rounds):
+            i = (t * rounds + r) % n_keys
+            vals, _vc = p.srv.static_read([(f"k{i}", "counter_pn", "b")],
+                                          None)
+            if vals != [i + 1]:
+                wrong.append((i, vals))
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=reader, args=(t,), daemon=True)
+                   for t in range(n_threads)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60)
+            assert not t.is_alive()
+        hold = p.status()["gate_hold"]
+    finally:
+        sys.setswitchinterval(interval)
+        p.close()
+    assert not wrong
+    assert seen and max(u for u, _q in seen) <= DEPTH
+    assert max(q for _u, q in seen) <= DEPTH - 1
+    assert p.srv._wb_unfinished == 0
+    assert hold["held"] >= 1 and hold["rounds"] == len(seen)
+    # held rounds merged what parked: fewer launches than reads
+    assert len(seen) < n_threads * rounds

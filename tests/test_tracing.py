@@ -184,6 +184,84 @@ def test_a_request_path_is_its_own_outcome_not_its_batch(monkeypatch):
     assert [p for p, *_ in seen] == ["cache", "gather", "shed"]
 
 
+def test_a_held_read_waits_under_parked_and_its_stages_still_sum(monkeypatch):
+    """ISSUE 25: with every writeback slot taken the dispatcher holds the
+    gate; the hold is inside the held read's ``parked`` stage (stamped after
+    the drain that follows the hold), not ``wb_wait``, and the stages of its
+    record still sum to ``total``."""
+    import threading
+
+    seen = _record_closes(monkeypatch)
+    node, srv = _boot(False)
+    depth = ProtocolServer.DEPTH
+    hold_s = 0.15
+    store = node.txm.store
+    release = threading.Event()
+    finish = store.epoch_read_finish
+
+    def held_finish(pending):
+        assert release.wait(30)
+        return finish(pending)
+
+    clients = [AntidoteClient(srv.host, srv.port) for _ in range(depth + 1)]
+    out = {}
+
+    def read(i):
+        out[i] = clients[i].read_objects([(f"k{i}", "counter_pn", "b")])[0]
+
+    try:
+        for i in range(depth + 1):
+            clients[0].update_objects(
+                [(f"k{i}", "counter_pn", "b", ("increment", 1))])
+        _wait_epoch_covers(node)
+        store.epoch_read_finish = held_finish
+        del seen[:]
+        threads = []
+        for i in range(depth + 1):      # the last one finds no slot free
+            seq = srv._launch_seq
+            threads.append(threading.Thread(target=read, args=(i,),
+                                            daemon=True))
+            threads[-1].start()
+            deadline = time.monotonic() + 10
+            while i < depth and srv._launch_seq == seq:
+                assert time.monotonic() < deadline
+                time.sleep(0.002)
+        time.sleep(hold_s)
+        assert srv._launch_seq == depth
+        release.set()
+        for t in threads:
+            t.join(10)
+            assert not t.is_alive()
+        # a record is closed after its reply was sent: wait for the last
+        deadline = time.monotonic() + 10
+        while True:
+            status = srv._pipeline_status()
+            if status["paths"]["gather"]["total"]["count"] > depth:
+                break
+            assert time.monotonic() < deadline
+            time.sleep(0.002)
+    finally:
+        release.set()
+        for c in clients:
+            c.close()
+        srv.close()
+    assert out == {i: [1] for i in range(depth + 1)}
+    gathers = [(rid, b, st) for path, rid, b, st in seen if path == "gather"]
+    assert len(gathers) == depth + 1
+    held = max(gathers, key=lambda g: g[1])          # the last batch
+    assert held[1] == depth + 1
+    (t_arrive, _taken, t_submit, t_dequeued, t_launched, t_wb_start,
+     _synced, _ready, t_sent) = held[2]
+    assert t_dequeued - t_submit >= hold_s * 0.9         # parked: the hold
+    assert t_wb_start - t_launched < hold_s * 0.5        # wb_wait: not it
+    assert status["gate_hold"]["held"] == 1
+    assert status["gate_hold"]["sum_ms"] >= hold_s * 900
+    g = status["paths"]["gather"]
+    assert sum(v["sum_ms"] for k, v in g.items() if k != "total") == (
+        pytest.approx(g["total"]["sum_ms"], rel=1e-9, abs=1e-9))
+    assert g["parked"]["sum_ms"] >= hold_s * 900
+
+
 # ---------------------------------------------------------------------------
 # (b) commit-group phases
 # ---------------------------------------------------------------------------
