@@ -104,6 +104,15 @@ class MeshServingPlane:
         self._giant_fold_fns: dict = {}
         #: giant-key folds dispatched through the mesh (node status)
         self.giant_folds = 0
+        #: routed epoch-read launches (node status ``pipeline.mesh``):
+        #: how many, their [P, M'] slots, the objects really gathered,
+        #: those by owning device, and the host seconds the routing took
+        #: — written by the dispatcher thread only (``note_routed``)
+        self.routed_launches = 0
+        self.routed_slots = 0
+        self.routed_rows = 0
+        self.routed_rows_by_device = np.zeros((n,), np.int64)
+        self.route_seconds = 0.0
 
     # ------------------------------------------------------------------
     # placement
@@ -159,6 +168,20 @@ class MeshServingPlane:
             t._mesh_gather_fn = fn
             t._mesh_gather_plane = self
         return fn(head, head_vc, row_mat, vc_mat)
+
+    def note_routed(self, shards: np.ndarray, slots: int,
+                    seconds: float) -> None:
+        """Tally one routed launch: ``shards`` i64[M] are its objects'
+        shards, ``slots`` the P × M' it padded them to, ``seconds`` what
+        the routing (host span ``serve.route``) took.  Per launch, never
+        per request; the launch stage's one thread is the only caller."""
+        self.routed_launches += 1
+        self.routed_slots += slots
+        self.routed_rows += len(shards)
+        self.routed_rows_by_device += np.bincount(
+            shards // (self.cfg.n_shards // self.n_devices),
+            minlength=self.n_devices)
+        self.route_seconds += seconds
 
     def _build_gather(self, t):
         ty, cfg = t.ty, t.cfg
@@ -295,6 +318,16 @@ class MeshServingPlane:
             "shards_per_device": self.cfg.n_shards // self.n_devices,
             "stable_collectives": self.stable_collectives,
             "giant_folds": self.giant_folds,
+            # routed epoch-read launches: padding share = 1 - rows/slots,
+            # skew = the busiest device's rows over the mean
+            "launches": self.routed_launches,
+            "slots": self.routed_slots,
+            "rows": self.routed_rows,
+            "rows_by_device": {
+                str(d): int(v)
+                for d, v in enumerate(self.routed_rows_by_device)},
+            "route": {"count": self.routed_launches,
+                      "sum_ms": round(self.route_seconds * 1e3, 3)},
         }
         m = self.metrics
         if m is not None:

@@ -1232,11 +1232,15 @@ class KVStore:
                 # no host-side concat on the hot path
                 ss = np.asarray([x[1] for x in items], np.int64)
                 rr = np.asarray([x[2] for x in items], np.int64)
-                row_mat, pos = t._route(ss, rr)
-                row_gather = np.minimum(row_mat, t.n_rows - 1)
-                p, mm = row_mat.shape
-                vc_mat = np.zeros((p, mm, ep.vc.shape[-1]), np.int32)
-                vc_mat[pos[:, 0], pos[:, 1]] = ep.vc
+                t_route = time.monotonic()
+                with span("serve.route", table=tname_t, rows=mcount):
+                    row_mat, pos = t._route(ss, rr)
+                    row_gather = np.minimum(row_mat, t.n_rows - 1)
+                    p, mm = row_mat.shape
+                    vc_mat = np.zeros((p, mm, ep.vc.shape[-1]), np.int32)
+                    vc_mat[pos[:, 0], pos[:, 1]] = ep.vc
+                self.mesh.note_routed(ss, p * mm,
+                                      time.monotonic() - t_route)
                 resolved, fresh = self.mesh.epoch_gather(
                     t, slot["head"], slot["head_vc"], row_gather, vc_mat
                 )

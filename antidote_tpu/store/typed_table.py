@@ -239,10 +239,9 @@ class TypedTable:
         spec = ty.state_spec(cfg)
 
         def mk(shape, dtype):
-            arr = jnp.zeros(shape, dtype)
-            if sharding is not None:
-                arr = jax.device_put(arr, sharding)
-            return arr
+            # created IN its placement: a mesh table's array is never
+            # whole on one device (at 2M set_aw rows the largest is 2.1 GB)
+            return jnp.zeros(shape, dtype, device=sharding)
 
         self.snap = {
             f: mk((p, n, v) + shape, dtype) for f, (shape, dtype) in spec.items()

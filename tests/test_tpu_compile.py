@@ -193,3 +193,58 @@ def test_mesh_routed_read_compiles(topo, no_persistent_cache, monkeypatch,
         *head, mk((p, m), jnp.int64), mk((p, m, cfg.max_dcs))
     ).compile().as_text()
     assert ("tpu_custom_call" in latest) == (tyname == "set_aw")
+
+
+@pytest.mark.parametrize("bucket", [0, 1])
+def test_mesh_gather_compiles_at_2m_rows(topo, no_persistent_cache,
+                                         monkeypatch, bucket):
+    """`jit_antidote_mesh_gather`, the routed epoch read of the deployment
+    `set_aw_2m_mesh4` (`--mesh-devices 4 --pallas --shards 16
+    --keys-per-table 131072`), at its real shapes on the described host:
+    frozen head buffers [16, 131072, ...] a quarter on each chip, and the
+    routed [16, M'] buckets its window meets (M' = 64: up to 64 one-object
+    reads merge; M' = 512: the warm walk's 1,024-object read).  The
+    program needs no collective, and what it reserves fits a 16 GB chip
+    beside that chip's 4.42 GB share of the tables."""
+    from antidote_tpu.parallel.mesh import MeshServingPlane
+
+    monkeypatch.setattr(pk, "_on_tpu", lambda: True)
+    mesh = Mesh(np.array(topo.devices), ("shard",))
+    placed = NamedSharding(mesh, PartitionSpec("shard"))
+    rows = 131_072
+    cfg = AntidoteConfig(n_shards=16, max_dcs=8, keys_per_table=rows,
+                         use_pallas=True)
+    # a few rows are allocated here; the shapes compiled are the real ones
+    table = TypedTable(get_type("set_aw"), cfg, n_rows=8)
+    # the plane builds its mesh from jax.devices(), the CPU's here: hand
+    # it the described one instead
+    plane = MeshServingPlane.__new__(MeshServingPlane)
+    plane.mesh = mesh
+    p, m = cfg.n_shards, cfg.batch_buckets[bucket]
+
+    def mk(dims, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=placed)
+
+    full = lambda x: mk((p, rows) + x.shape[2:], x.dtype)
+    compiled = plane._build_gather(table).lower(
+        *jax.tree.map(full, (table.head, table.head_vc)),
+        mk((p, m), jnp.int64), mk((p, m, cfg.max_dcs)),
+    ).compile()
+    text = compiled.as_text()
+    assert "jit_antidote_mesh_gather" in text
+    assert "tpu_custom_call" in text, "set_aw resolves in its Mosaic kernel"
+    assert "all-gather" not in text and "all-reduce" not in text
+    mem = compiled.memory_analysis()
+    head_share = sum(
+        int(np.prod((p // 4, rows) + x.shape[2:])) * x.dtype.itemsize
+        for x in jax.tree.leaves((table.head, table.head_vc)))
+    assert mem.argument_size_in_bytes >= head_share      # per device
+    reserved = (mem.temp_size_in_bytes + mem.output_size_in_bytes
+                + mem.generated_code_size_in_bytes)
+    table_share = 4.42e9       # ISSUE 26: 17.7 GB of tables over 4 chips
+    assert table_share + reserved < 16e9, (
+        f"the gather reserves {reserved / 1e9:.2f} GB on each device")
+    print(f"mesh_gather [16, {m}] at 16 x {rows} rows: arguments "
+          f"{mem.argument_size_in_bytes / 1e9:.3f} GB, temporaries "
+          f"{mem.temp_size_in_bytes / 1e9:.3f} GB, output "
+          f"{mem.output_size_in_bytes / 1e6:.3f} MB a device")
