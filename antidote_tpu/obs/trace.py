@@ -92,7 +92,8 @@ def _new_path() -> list:
 class StageAccumulator:
     """Per-path sums of request stages, and the slowest few records since
     the last status read.  ``close`` is the one call a request makes,
-    after its reply left: one lock take per request."""
+    after its reply left: one lock take per request — or, for the
+    requests one stage answered together, per ``close_many``."""
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -106,44 +107,53 @@ class StageAccumulator:
         """Fold one finished request.  ``stamps`` follows :data:`STAMPS`
         (0.0 = not taken; the first and last are always taken).  Returns
         the request's total (arrive → sent) in seconds."""
+        with self._lock:
+            return self._fold(path, rid, batch_id, stamps)
+
+    def close_many(self, records) -> List[float]:
+        """``close`` for the requests a stage answered together —
+        ``[(path, rid, batch_id, stamps)]`` — under ONE lock take."""
+        with self._lock:
+            return [self._fold(*r) for r in records]
+
+    def _fold(self, path, rid, batch_id, stamps) -> float:
         (t_arrive, t_taken, t_submit, t_dequeued, t_launched, t_wb_start,
          t_synced, t_ready, t_sent) = stamps
         total = t_sent - t_arrive
-        with self._lock:
-            p = self._paths.get(path)
-            if p is None:
-                p = self._paths[path] = _new_path()
-            s, c = p[0], p[1]
-            # unrolled over STAMPS (this runs once per request): each
-            # stamp taken closes its stage, from the previous one taken
-            prev = t_arrive
-            if t_taken:
-                s[0] += t_taken - prev; c[0] += 1; prev = t_taken
-            if t_submit:
-                s[1] += t_submit - prev; c[1] += 1; prev = t_submit
-            if t_dequeued:
-                s[2] += t_dequeued - prev; c[2] += 1; prev = t_dequeued
-            if t_launched:
-                s[3] += t_launched - prev; c[3] += 1; prev = t_launched
-            if t_wb_start:
-                s[4] += t_wb_start - prev; c[4] += 1; prev = t_wb_start
-            if t_synced:
-                s[5] += t_synced - prev; c[5] += 1; prev = t_synced
-            if t_ready:
-                i = _WB_HOST if t_synced else _EXEC
-                s[i] += t_ready - prev; c[i] += 1; prev = t_ready
-            s[8] += t_sent - prev; c[8] += 1
-            p[2] += total
-            p[3] += 1
-            slow = self._slow
-            if len(slow) < SLOW_KEPT:
-                self._n += 1
-                heapq.heappush(slow, (total, self._n, path, rid, batch_id,
-                                      tuple(stamps)))
-            elif total > slow[0][0]:
-                self._n += 1
-                heapq.heapreplace(slow, (total, self._n, path, rid,
-                                         batch_id, tuple(stamps)))
+        p = self._paths.get(path)
+        if p is None:
+            p = self._paths[path] = _new_path()
+        s, c = p[0], p[1]
+        # unrolled over STAMPS (this runs once per request): each
+        # stamp taken closes its stage, from the previous one taken
+        prev = t_arrive
+        if t_taken:
+            s[0] += t_taken - prev; c[0] += 1; prev = t_taken
+        if t_submit:
+            s[1] += t_submit - prev; c[1] += 1; prev = t_submit
+        if t_dequeued:
+            s[2] += t_dequeued - prev; c[2] += 1; prev = t_dequeued
+        if t_launched:
+            s[3] += t_launched - prev; c[3] += 1; prev = t_launched
+        if t_wb_start:
+            s[4] += t_wb_start - prev; c[4] += 1; prev = t_wb_start
+        if t_synced:
+            s[5] += t_synced - prev; c[5] += 1; prev = t_synced
+        if t_ready:
+            i = _WB_HOST if t_synced else _EXEC
+            s[i] += t_ready - prev; c[i] += 1; prev = t_ready
+        s[8] += t_sent - prev; c[8] += 1
+        p[2] += total
+        p[3] += 1
+        slow = self._slow
+        if len(slow) < SLOW_KEPT:
+            self._n += 1
+            heapq.heappush(slow, (total, self._n, path, rid, batch_id,
+                                  tuple(stamps)))
+        elif total > slow[0][0]:
+            self._n += 1
+            heapq.heapreplace(slow, (total, self._n, path, rid,
+                                     batch_id, tuple(stamps)))
         return total
 
     def status(self) -> dict:

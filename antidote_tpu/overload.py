@@ -302,9 +302,9 @@ class AdmissionGate:
             self._streaks.pop(("tenant", tenant), None)
             self._per_tenant[tenant] = self._per_tenant.get(tenant, 0) + 1
 
-    def tenant_exit(self, tenant: str) -> None:
+    def tenant_exit(self, tenant: str, count: int = 1) -> None:
         with self._lock:
-            n = self._per_tenant.get(tenant, 0) - 1
+            n = self._per_tenant.get(tenant, 0) - count
             if n <= 0:
                 self._per_tenant.pop(tenant, None)
             else:

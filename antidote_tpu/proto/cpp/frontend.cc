@@ -269,7 +269,9 @@ long now_us() {
 
 struct Frame {
   long conn_id;
-  int kind;  // 0 = conn closed, 1 = admitted frame, 2 = shed (aux = hint)
+  // 0 = conn closed, 1 = admitted frame (aux = the conn's frames already
+  // in Python), 2 = shed (aux = hint)
+  int kind;
   long aux;
   std::vector<uint8_t> payload;
   long t_arrive_us = 0;  // io thread, frame complete (stage stamp t_arrive)
@@ -344,6 +346,8 @@ struct Frontend {
   // admitted frames, and frontend_send -> last byte written
   long st_cross_wait_us = 0, st_cross_frames = 0, st_send_wait_us = 0,
        st_send_frames = 0;
+  // calls of frontend_send / frontend_send_many (one per reply batch)
+  long st_send_calls = 0;
   std::atomic<long> st_drains{0};
 
   std::vector<ObjSpan> scratch_objs;
@@ -656,7 +660,10 @@ void on_frame(Frontend* f, std::unique_lock<std::mutex>& lk, long cid,
   c->pending += 1;
   c->admitted += 1;
   ++f->st_fwd;
-  Frame fr{cid, 1, 0, {}, now_us()};
+  // aux: this conn's frames Python still owes a reply, not counting this
+  // one — 0 tells the bridge nothing of the conn is in Python, so the
+  // reply to this frame may leave from whichever stage answers it
+  Frame fr{cid, 1, c->pending - 1, {}, now_us()};
   fr.payload.assign(payload, payload + len);
   enqueue(f, lk, std::move(fr));
 }
@@ -807,6 +814,44 @@ void io_loop(Frontend* f) {
   }
 }
 
+// append one fully-framed reply for `conn_id` (len may be 0: account
+// only), release `n_admitted` admission slots, keep per-conn order.
+// True when the io thread has something to do for it (mu held).
+bool send_locked(Frontend* f, long conn_id, const uint8_t* buf, long len,
+                 long n_admitted) {
+  auto it = f->conns.find(conn_id);
+  if (n_admitted > 0) {
+    f->g_inflight -= n_admitted;
+    if (f->g_inflight < 0) f->g_inflight = 0;
+    if (it != f->conns.end()) {
+      auto hi = f->host_inflight.find(it->second.host);
+      if (hi != f->host_inflight.end()) {
+        hi->second -= n_admitted;
+        if (hi->second <= 0) f->host_inflight.erase(hi);
+      }
+    }
+  }
+  if (it == f->conns.end()) return false;
+  Conn& c = it->second;
+  bool work = false;
+  c.pending -= 1;
+  c.admitted -= n_admitted;
+  if (!c.closed && len > 0) {
+    bool was_empty = c.out.empty();
+    c.out.insert(c.out.end(), buf, buf + len);
+    c.sends.emplace_back(c.out_base + c.out.size(), now_us());
+    if (was_empty) f->out_dirty.push_back(conn_id);
+    work = true;
+  } else if (!c.closed && c.rd_eof && c.pending <= 0) {
+    // half-closed conn just got its last (empty) reply: have the io
+    // thread run the deferred close
+    f->out_dirty.push_back(conn_id);
+    work = true;
+  }
+  if (c.closed && c.pending <= 0) f->conns.erase(it);
+  return work;
+}
+
 }  // namespace
 
 #ifndef ANTIDOTE_SRC_SHA
@@ -916,41 +961,29 @@ long frontend_take_batch(void* h, uint8_t* out, long cap, long* descs,
   return n;
 }
 
-// append one fully-framed reply for `conn_id` (len may be 0: account
-// only), release `n_admitted` admission slots, keep per-conn order.
 void frontend_send(void* h, long conn_id, const uint8_t* buf, long len,
                    long n_admitted) {
   Frontend* f = static_cast<Frontend*>(h);
   std::lock_guard<std::mutex> lk(f->mu);
-  auto it = f->conns.find(conn_id);
-  if (n_admitted > 0) {
-    f->g_inflight -= n_admitted;
-    if (f->g_inflight < 0) f->g_inflight = 0;
-    if (it != f->conns.end()) {
-      auto hi = f->host_inflight.find(it->second.host);
-      if (hi != f->host_inflight.end()) {
-        hi->second -= n_admitted;
-        if (hi->second <= 0) f->host_inflight.erase(hi);
-      }
-    }
+  ++f->st_send_calls;
+  if (send_locked(f, conn_id, buf, len, n_admitted)) wake(f);
+}
+
+// a batch of replies in one crossing: `n` frames back-to-back in `buf`,
+// 3 longs per frame in `descs` (conn_id, len, n_admitted).  Per frame the
+// accounting of frontend_send; one mu take and one wake for all of them.
+void frontend_send_many(void* h, long n, const long* descs,
+                        const uint8_t* buf) {
+  Frontend* f = static_cast<Frontend*>(h);
+  std::lock_guard<std::mutex> lk(f->mu);
+  ++f->st_send_calls;
+  bool work = false;
+  for (long i = 0; i < n; ++i) {
+    long len = descs[i * 3 + 1];
+    work |= send_locked(f, descs[i * 3], buf, len, descs[i * 3 + 2]);
+    buf += len;
   }
-  if (it == f->conns.end()) return;
-  Conn& c = it->second;
-  c.pending -= 1;
-  c.admitted -= n_admitted;
-  if (!c.closed && len > 0) {
-    bool was_empty = c.out.empty();
-    c.out.insert(c.out.end(), buf, buf + len);
-    c.sends.emplace_back(c.out_base + c.out.size(), now_us());
-    if (was_empty) f->out_dirty.push_back(conn_id);
-    wake(f);
-  } else if (!c.closed && c.rd_eof && c.pending <= 0) {
-    // half-closed conn just got its last (empty) reply: have the io
-    // thread run the deferred close
-    f->out_dirty.push_back(conn_id);
-    wake(f);
-  }
-  if (c.closed && c.pending <= 0) f->conns.erase(it);
+  if (work) wake(f);
 }
 
 void frontend_close_conn(void* h, long conn_id) {
@@ -1032,17 +1065,18 @@ void frontend_set_clockless_ok(void* h, int on) {
 // stats snapshot: [accepted, closed, frames, native_hits, hit_objects,
 //                  sheds, forwarded, drains, mirror_size, in_flight,
 //                  open_conns, bad_frames, cross_wait_us, cross_frames,
-//                  send_wait_us, send_frames]
+//                  send_wait_us, send_frames, send_calls]
 void frontend_stats(void* h, long* out, int n) {
   Frontend* f = static_cast<Frontend*>(h);
   std::lock_guard<std::mutex> lk(f->mu);
-  long vals[16] = {f->st_accept, f->st_closed, f->st_frames, f->st_hits,
+  long vals[17] = {f->st_accept, f->st_closed, f->st_frames, f->st_hits,
                    f->st_hit_objs, f->st_shed, f->st_fwd,
                    f->st_drains.load(), long(f->mirror.size()),
                    f->g_inflight, f->n_open, f->st_bad_frame,
                    f->st_cross_wait_us, f->st_cross_frames,
-                   f->st_send_wait_us, f->st_send_frames};
-  for (int i = 0; i < n && i < 16; ++i) out[i] = vals[i];
+                   f->st_send_wait_us, f->st_send_frames,
+                   f->st_send_calls};
+  for (int i = 0; i < n && i < 17; ++i) out[i] = vals[i];
 }
 
 void frontend_stop(void* h) {
@@ -1057,8 +1091,17 @@ void frontend_stop(void* h) {
   std::lock_guard<std::mutex> lk(f->mu);
   for (auto& kv : f->conns) {
     if (kv.second.fd >= 0) {
-      ::close(kv.second.fd);
-      kv.second.fd = -1;
+      // replies handed over since the io thread's last round (the typed
+      // errors of a shutdown): one non-blocking write each, then close
+      Conn& c = kv.second;
+      if (c.out_off < c.out.size()) {
+        ssize_t w = send(c.fd, c.out.data() + c.out_off,
+                         c.out.size() - c.out_off,
+                         MSG_DONTWAIT | MSG_NOSIGNAL);
+        (void)w;
+      }
+      ::close(c.fd);
+      c.fd = -1;
     }
   }
   f->conns.clear();

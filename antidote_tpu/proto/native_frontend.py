@@ -88,6 +88,18 @@ def _load_lib():
             ctypes.c_void_p, ctypes.c_long, ctypes.c_char_p,
             ctypes.c_long, ctypes.c_long,
         ]
+        # a second handle on the same library whose calls KEEP the GIL:
+        # send_many never blocks (it takes the front end's mutex for a
+        # few microseconds), and the stage that calls it has a batch to
+        # finish — giving the GIL up around the call would put it back
+        # in the queue for it
+        send_many = ctypes.PyDLL(str(_SO)).frontend_send_many
+        send_many.restype = None
+        send_many.argtypes = [
+            ctypes.c_void_p, ctypes.c_long, ctypes.POINTER(ctypes.c_long),
+            ctypes.c_char_p,
+        ]
+        lib.send_many_keeping_gil = send_many
         lib.frontend_close_conn.restype = None
         lib.frontend_close_conn.argtypes = [ctypes.c_void_p, ctypes.c_long]
         lib.frontend_advance.restype = None
@@ -133,9 +145,11 @@ def _packb(v) -> bytes:
 
 class NativeFrontend:
     """Owns the client listen socket; yields (conn_id, kind, aux,
-    payload) frames.  kind 0 = conn closed, 1 = admitted frame,
-    2 = admission-shed frame (aux carries the retry hint); every frame
-    carries the io thread's arrival stamp."""
+    payload) frames.  kind 0 = conn closed, 1 = admitted frame (aux =
+    how many of the connection's earlier frames Python still owes a
+    reply: 0 = nothing of it is in Python), 2 = admission-shed frame
+    (aux carries the retry hint); every frame carries the io thread's
+    arrival stamp."""
 
     _BATCH = 512
 
@@ -145,12 +159,14 @@ class NativeFrontend:
 
     #: cross_wait_us/cross_frames: frame complete on the io thread ->
     #: taken by Python, over admitted frames; send_wait_us/send_frames:
-    #: ``send`` -> the reply's last byte written to the socket
+    #: ``send`` -> the reply's last byte written to the socket;
+    #: send_calls: ``send`` + ``send_many`` calls (a writeback batch's
+    #: replies are one)
     STAT_FIELDS = ("accepted", "closed", "frames", "native_hits",
                    "hit_objects", "sheds", "forwarded", "drains",
                    "mirror_size", "in_flight", "open_conns", "bad_frames",
                    "cross_wait_us", "cross_frames", "send_wait_us",
-                   "send_frames")
+                   "send_frames", "send_calls")
     #: longs per frame in the take_batch descriptor
     _DESC = 5
 
@@ -221,6 +237,20 @@ class NativeFrontend:
             return
         self._lib.frontend_send(h, int(conn_id), buf, len(buf),
                                 int(admitted))
+
+    def send_many(self, replies) -> None:
+        """``send`` for a batch — ``[(conn_id, buf, admitted)]`` — in ONE
+        native call: one lock take and one io-thread wakeup for all of
+        them, per reply the accounting of ``send``."""
+        h = self._h
+        if h is None or not replies:
+            return
+        flat = []
+        for conn_id, buf, admitted in replies:
+            flat += (conn_id, len(buf), admitted)
+        descs = (ctypes.c_long * len(flat))(*flat)
+        self._lib.send_many_keeping_gil(
+            h, len(replies), descs, b"".join([r[1] for r in replies]))
 
     def close_conn(self, conn_id: int) -> None:
         h = self._h

@@ -72,10 +72,11 @@ class _StaticWork:
                  "error", "deadline", "t_submit", "wants_bytes",
                  "reply_bytes", "txid", "tenant", "t_dequeued",
                  "t_launched", "t_wb_start", "t_synced", "t_ready",
-                 "batch_id")
+                 "batch_id", "rec")
 
     def __init__(self, kind, objects=None, updates=None, clock=None,
-                 deadline=None, wants_bytes=False, txid=None, tenant=None):
+                 deadline=None, wants_bytes=False, txid=None, tenant=None,
+                 rec=None):
         self.kind = kind
         self.objects = objects
         self.updates = updates
@@ -87,6 +88,15 @@ class _StaticWork:
         #: the locked worker resolves it to the registered Transaction
         #: at the merge point
         self.txid = txid
+        #: how the work completes (``_complete``).  None: a thread waits
+        #: on ``event`` (a Handler, a connection worker) and sends the
+        #: reply itself.  A :class:`_RequestTrace`: the work CARRIES its
+        #: connection — a static read the native drain thread parked
+        #: without waiting — and the stage that answers it sends the
+        #: reply frame; ``event`` is then set once that frame was handed
+        #: to the native plane (what a later frame of the same
+        #: connection waits for, so replies keep the requests' order)
+        self.rec: Optional[_RequestTrace] = rec
         self.event = threading.Event()
         self.result = None
         self.error: Optional[BaseException] = None
@@ -98,14 +108,16 @@ class _StaticWork:
         #: stamps below, this request's STAGE RECORD (ISSUE 24): plain
         #: ``time.monotonic()`` values written by whichever thread moves
         #: the work on (0.0 = stage not taken), folded into the per-path
-        #: accumulator by ONE call on the connection thread once the
-        #: reply has been handed to the socket (``_close_request``)
+        #: accumulator once the reply has been handed to the socket: by
+        #: ONE call on the connection thread (``_close_request``), or for
+        #: works that carry their connection one call a batch
+        #: (``_reply_direct``)
         self.t_submit = 0.0
         self.t_dequeued = 0.0   # _shed_expired: batch gate / locked plane
         self.t_launched = 0.0   # its batch's epoch_read_launch returned
         self.t_wb_start = 0.0   # the writeback stage took its batch
         self.t_synced = 0.0     # last device->host transfer of its batch
-        self.t_ready = 0.0      # event.set(): the result is there
+        self.t_ready = 0.0      # the result (and its frame) is there
         #: launch batch (reads) or commit group (writes) that served it
         self.batch_id = 0
         #: native-dialect reads ask the writeback stage to serialize the
@@ -116,11 +128,13 @@ class _StaticWork:
 
 
 class _RequestTrace:
-    """One connection thread's request-in-progress: the stamps taken
-    before a :class:`_StaticWork` exists (arrival on the io thread, the
-    crossing into Python), the request id (connection id, sequence
-    number) and the work the request parked, if any.  Reused for every
-    request of the connection; lives in the thread's ``_tls``."""
+    """One request-in-progress: the stamps taken before a
+    :class:`_StaticWork` exists (arrival on the io thread, the crossing
+    into Python), the request id (connection id, sequence number) and
+    the work the request parked, if any.  A connection thread reuses one
+    for every request of its connection and keeps it in its ``_tls``; a
+    read the native drain thread parks gets one of its own, which rides
+    on the work (``_StaticWork.rec``)."""
 
     __slots__ = ("conn", "seq", "t_arrive", "t_taken", "t_ready", "work",
                  "path")
@@ -130,8 +144,10 @@ class _RequestTrace:
         self.seq = 0
         self.begin(0.0, 0.0)
 
-    def begin(self, t_arrive: float, t_taken: float) -> None:
-        self.seq += 1
+    def begin(self, t_arrive: float, t_taken: float,
+              seq: Optional[int] = None) -> None:
+        # the native drain loop numbers a connection's frames itself
+        self.seq = self.seq + 1 if seq is None else seq
         self.t_arrive = t_arrive
         self.t_taken = t_taken
         self.t_ready = 0.0
@@ -145,6 +161,24 @@ class _RequestTrace:
         like a parked request's."""
         if self.work is None:
             self.t_ready = time.monotonic()
+
+
+class _NativeConn:
+    """What the native drain loop keeps of one connection: its frames
+    so far (a request's sequence number), its worker's queue once it
+    needed a worker, and the read the loop last parked for it — until a
+    later frame takes that over as what it has to wait for."""
+
+    __slots__ = ("seq", "q", "direct")
+
+    def __init__(self):
+        self.seq = 0
+        self.q: Optional["queue.SimpleQueue"] = None
+        self.direct: Optional[_StaticWork] = None
+
+
+#: first byte of a native-dialect static read frame
+_STATIC_READ = bytes([MessageCode.STATIC_READ_OBJECTS])
 
 
 class RawReply:
@@ -183,6 +217,12 @@ def _decode_objects(objs):
 def _decode_updates(ups):
     return [(freeze(k), t, b, freeze(op)) for k, t, b, op in
             (freeze(u) for u in ups)]
+
+
+def _read_resp(vals, vc) -> dict:
+    """Body of a READ_OBJECTS_RESP to a static read."""
+    return {"values": [encode_value(v) for v in vals],
+            "commit_clock": [int(x) for x in vc]}
 
 
 def _vc(x) -> Optional[np.ndarray]:
@@ -289,6 +329,10 @@ class ProtocolServer:
         #: per-path request stage sums + the slowest records (ISSUE 24;
         #: node status ``pipeline.paths`` / ``pipeline.slow_requests``)
         self._stages = StageAccumulator()
+        #: node status ``pipeline.direct``: static reads off the native
+        #: drain that the drain thread served or parked itself, against
+        #: those it gave a connection worker — written by that thread only
+        self._direct = {"served": 0, "worker": 0}
         #: launched read chunks so far (written by the dispatcher only)
         self._launch_seq = 0
         #: (seconds, waits) the locked worker spent blocked on an empty
@@ -575,8 +619,8 @@ class ProtocolServer:
     def _frame_reply(self, frame: bytes, conn_txns) -> bytes:
         """One request frame → one fully-framed reply, both dialects —
         the serving core behind the socket Handler AND the native drain
-        workers (admission is the caller's job; the error mapping here
-        mirrors antidote_pb_protocol:handle's error replies)."""
+        workers (admission is the caller's job; what raises is answered
+        through ``_error_body``)."""
         # dialect dispatch on the code byte: antidote_pb request codes
         # (apb.APB_REQUEST_CODES) are disjoint from the native msgpack
         # codes, so existing antidotec_pb clients connect to the same
@@ -595,96 +639,90 @@ class ProtocolServer:
             elif code in (MessageCode.COMMIT_TRANSACTION,
                           MessageCode.ABORT_TRANSACTION):
                 conn_txns.discard(body.get("txid"))
-        except AbortError as e:
-            if code == MessageCode.UPDATE_OBJECTS:
+        except Exception as e:  # error reply, keep the conn
+            # a refusal that closed the txn server-side (an aborted
+            # update; an escrow refusal of an update or a COMMIT) must
+            # not leave its descriptor lingering in conn_txns
+            if isinstance(e, InsufficientRightsError):
+                closed = code in (MessageCode.UPDATE_OBJECTS,
+                                  MessageCode.COMMIT_TRANSACTION)
+            else:
+                closed = (isinstance(e, AbortError)
+                          and code == MessageCode.UPDATE_OBJECTS)
+            if closed:
                 conn_txns.discard(body.get("txid"))
-            resp_code, resp = MessageCode.ERROR_RESP, {
-                "error": "aborted", "detail": str(e)
-            }
-        except InsufficientRightsError as e:
+            resp_code, resp = MessageCode.ERROR_RESP, self._error_body(e)
+        if isinstance(resp, RawReply):
+            # the writeback stage already framed the reply
+            return resp.buf
+        return encode(resp_code, resp)
+
+    def _error_body(self, e: BaseException) -> dict:
+        """The ONE exception → typed native-dialect error reply mapping
+        (it mirrors antidote_pb_protocol:handle's error replies): what a
+        connection thread answers when its request raises, and what the
+        stage that fails a work carrying its connection sends for it
+        (``_reply_direct``) — the same frame, byte for byte."""
+        if isinstance(e, AbortError):
+            return {"error": "aborted", "detail": str(e)}
+        if isinstance(e, InsufficientRightsError):
             # escrow refusal (ISSUE 18): the counter_b decrement/transfer
             # exceeded this DC's locally-held rights — nothing executed;
             # the hint tracks the background transfer loop's expected
-            # grant arrival (a COMMIT refusal closed the txn server-side,
-            # so the descriptor must not linger in conn_txns)
-            if code in (MessageCode.UPDATE_OBJECTS,
-                        MessageCode.COMMIT_TRANSACTION):
-                conn_txns.discard(body.get("txid"))
-            resp_code, resp = MessageCode.ERROR_RESP, {
-                "error": "insufficient_rights", "detail": str(e),
-                "retry_after_ms": int(e.retry_after_ms),
-            }
-        except TenantBusyError as e:
+            # grant arrival
+            return {"error": "insufficient_rights", "detail": str(e),
+                    "retry_after_ms": int(e.retry_after_ms)}
+        if isinstance(e, TenantBusyError):
             # tenant-scoped quota/lane refusal (ISSUE 19): typed
             # distinctly from global busy — the client learns its OWN
             # quota (not the node) is the bottleneck, so failover to a
             # sibling node won't help but backing off will
-            resp_code, resp = MessageCode.ERROR_RESP, {
-                "error": "tenant_busy", "detail": str(e),
-                "retry_after_ms": int(e.retry_after_ms),
-                "tenant": e.tenant,
-            }
-        except BusyError as e:
+            return {"error": "tenant_busy", "detail": str(e),
+                    "retry_after_ms": int(e.retry_after_ms),
+                    "tenant": e.tenant}
+        if isinstance(e, BusyError):
             # downstream cap (commit backlog / batch gate): same typed
             # shape as the admission shed
-            resp_code, resp = MessageCode.ERROR_RESP, {
-                "error": "busy", "detail": str(e),
-                "retry_after_ms": int(e.retry_after_ms),
-            }
-        except DeadlineExceeded as e:
-            resp_code, resp = MessageCode.ERROR_RESP, {
-                "error": "deadline", "detail": str(e)
-            }
-        except ReplicaLagging as e:
+            return {"error": "busy", "detail": str(e),
+                    "retry_after_ms": int(e.retry_after_ms)}
+        if isinstance(e, DeadlineExceeded):
+            return {"error": "deadline", "detail": str(e)}
+        if isinstance(e, ReplicaLagging):
             # follower session gate: the read was NOT served — the
             # client retries after the hint or fails over (the redirect
             # names the owner)
-            resp_code, resp = MessageCode.ERROR_RESP, {
-                "error": "lagging", "detail": str(e),
-                "retry_after_ms": int(e.retry_after_ms),
-                "redirect": e.redirect,
-            }
+            resp = {"error": "lagging", "detail": str(e),
+                    "retry_after_ms": int(e.retry_after_ms),
+                    "redirect": e.redirect}
             self._attach_hint(resp)
-        except ColdMiss as e:
+            return resp
+        if isinstance(e, ColdMiss):
             # cold-tier fault-in refused (rate cap / I/O fault / CRC
             # failure): the key's device row stays cold this round —
             # the client retries after the hint; the value was NEVER
             # served wrong
-            resp_code, resp = MessageCode.ERROR_RESP, {
-                "error": "cold_miss", "detail": str(e),
-                "retry_after_ms": int(e.retry_after_ms),
-                "permanent": bool(e.permanent),
-            }
-        except NotOwnerError as e:
-            resp_code, resp = MessageCode.ERROR_RESP, {
-                "error": "not_owner", "detail": str(e),
-                "redirect": e.redirect,
-            }
+            return {"error": "cold_miss", "detail": str(e),
+                    "retry_after_ms": int(e.retry_after_ms),
+                    "permanent": bool(e.permanent)}
+        if isinstance(e, NotOwnerError):
+            resp = {"error": "not_owner", "detail": str(e),
+                    "redirect": e.redirect}
             self._attach_hint(resp)
-        except ForwardFailed as e:
+            return resp
+        if isinstance(e, ForwardFailed):
             # a server-side forwarded write lost the owner connection
             # AFTER the request left the socket: at-most-once forbids a
             # blind resend, so the typed reply tells the CLIENT the op
             # may have executed (re-read at the session token to learn
             # the outcome)
-            resp_code, resp = MessageCode.ERROR_RESP, {
-                "error": "forward_failed", "detail": str(e),
-                "maybe_executed": True,
-            }
+            resp = {"error": "forward_failed", "detail": str(e),
+                    "maybe_executed": True}
             self._attach_hint(resp)
-        except ReadOnlyError as e:
-            resp_code, resp = MessageCode.ERROR_RESP, {
-                "error": "read_only", "detail": str(e)
-            }
-        except Exception as e:  # error reply, keep the conn
-            log.exception("request failed")
-            resp_code, resp = MessageCode.ERROR_RESP, {
-                "error": type(e).__name__, "detail": str(e)
-            }
-        if isinstance(resp, RawReply):
-            # the writeback stage already framed the reply
-            return resp.buf
-        return encode(resp_code, resp)
+            return resp
+        if isinstance(e, ReadOnlyError):
+            return {"error": "read_only", "detail": str(e)}
+        log.error("request failed", exc_info=e)
+        return {"error": type(e).__name__, "detail": str(e)}
 
     def _frame_fault(self, frame: bytes) -> Optional[bytes]:
         """Apply an armed ``frontend.recv`` fault rule to one inbound
@@ -728,48 +766,128 @@ class ProtocolServer:
     # native front-end drain plane (ISSUE 16)
     # ------------------------------------------------------------------
     def _native_drain_loop(self):
-        """Fans batch-drain crossings out to per-connection workers.
+        """Takes the batch-drain crossings and, per frame, either serves
+        it here or hands it to its connection's worker.
 
         The C++ loop serves whole-batch cache hits itself; everything it
         can't (misses, writes, interactive txns, apb frames, admission
         sheds) crosses here in packed batches — ONE GIL acquisition per
-        drain, then per-conn queues so one slow device batch never
-        head-of-line-blocks another connection's frames.  Reply order
-        per connection is preserved: the native loop only fast-serves a
-        conn with no frame still pending in Python."""
+        drain.  A native-dialect static read of a connection with
+        nothing else in Python (the frame says so: ``aux`` 0) is
+        decoded, probed against the snapshot cache and parked at the
+        batch gate RIGHT HERE, without waiting (``_direct_read``): the
+        stage that answers it sends the reply, and no other Python
+        thread is woken for it.  Every other frame goes to a
+        per-connection worker thread, created when a connection first
+        needs one, so one slow commit never head-of-line-blocks another
+        connection's frames.
+
+        Reply order per connection is preserved: the native loop only
+        fast-serves a conn with no frame still pending in Python, this
+        loop only parks a read of such a conn, and a worker serves a
+        frame that followed a parked read only after that read's reply
+        was handed over (``after``)."""
         nf = self.native
-        workers: Dict[int, "queue.SimpleQueue"] = {}
+        # a static read on this node parks at the gate and its reply can
+        # leave from the answering stage: not where reads run inline
+        # under the dispatch lock, nor on a follower, whose session gate
+        # parks and proxies on the calling thread
+        can_park = self.batch_static and self.follower is None
+        conns: Dict[int, _NativeConn] = {}
         while not self._closing:
             batch = nf.take_batch(200)
             now = time.monotonic()
             for conn_id, kind, aux, payload, t_arrive in batch:
                 if kind == nf.K_CONN_DROP:
-                    q = workers.pop(conn_id, None)
-                    if q is not None:
-                        q.put(None)
+                    # a read of this conn still in the pipeline completes
+                    # there: its send releases the slots it holds
+                    c = conns.pop(conn_id, None)
+                    if c is not None and c.q is not None:
+                        c.q.put(None)
                     continue
-                q = workers.get(conn_id)
-                if q is None:
+                c = conns.get(conn_id)
+                if c is None:
+                    c = conns[conn_id] = _NativeConn()
+                c.seq += 1
+                if kind == nf.K_FRAME and payload[:1] == _STATIC_READ:
+                    if aux == 0 and can_park:
+                        self._direct["served"] += 1
+                        rec = _RequestTrace(conn_id)
+                        rec.begin(t_arrive, now, c.seq)
+                        c.direct = self._direct_read(payload, rec)
+                        continue
+                    self._direct["worker"] += 1
+                if c.q is None:
                     # admitted frames hold admission slots until
                     # frontend_send releases them, and the native loop
                     # stops reading sockets when its crossing queue
                     # fills — so this queue's depth is
                     # bounded-by: admission caps + native QUEUE_CAP
-                    q = queue.SimpleQueue()
-                    workers[conn_id] = q
+                    c.q = queue.SimpleQueue()
                     threading.Thread(
                         target=self._native_conn_worker, daemon=True,
-                        args=(conn_id, q),
+                        args=(conn_id, c.q),
                         name=f"antidote-native-conn-{conn_id}",
                     ).start()
-                q.put((kind, aux, payload, t_arrive, now))
-        for q in workers.values():
-            q.put(None)
+                after, c.direct = c.direct, None
+                c.q.put((kind, aux, payload, t_arrive, now, c.seq, after))
+        for c in conns.values():
+            if c.q is not None:
+                c.q.put(None)
+
+    def _direct_read(self, frame: bytes,
+                     rec: _RequestTrace) -> Optional[_StaticWork]:
+        """One admitted native-dialect static read, served on the drain
+        thread as far as that goes without waiting: fault site, decode,
+        snapshot-cache probe — a hit is answered at once — else the work
+        enters its tenant's account and parks at the batch gate carrying
+        its connection (``rec``), and whichever stage answers or refuses
+        it sends its reply (``_complete``).  It passes the checks a
+        worker's read passes — the same gate, the same epoch and lag
+        floor checks at launch — and skips only the threads.  A refusal
+        on the way is the typed error frame the worker path answers.
+        Returns the parked work, None when the request is over."""
+        nf = self.native
+        frame = self._frame_fault(frame)
+        if frame is None:
+            # chaos drop: account the slot, then drop the conn
+            nf.send(rec.conn, b"", 1)
+            nf.close_conn(rec.conn)
+            return None
+        try:
+            # in the order _process and static_read take them, so that a
+            # malformed request raises what it raises there
+            _code, body = decode(frame)
+            deadline = deadline_from_ms(
+                body.get("deadline_ms") if isinstance(body, dict) else None,
+                self.default_deadline_ms)
+            objs = _decode_objects(body["objects"])
+            tenant = self.tenants.resolve(body.get("tenant"),
+                                          (o[2] for o in objs))
+            clock = _vc(body.get("clock"))
+            out = self._try_cache_read(objs, clock, True)
+            if out is None:
+                w = _StaticWork("read", objects=objs, clock=clock,
+                                deadline=deadline, wants_bytes=True,
+                                tenant=tenant, rec=rec)
+                self._park(w, self._static_q)
+                return w
+            rec.path = "cache"
+            buf = out.buf
+        except Exception as e:
+            buf = encode(MessageCode.ERROR_RESP, self._error_body(e))
+        rec.reply_built()
+        nf.send(rec.conn, buf, 1)
+        self._close_request(rec)
+        return None
 
     def _native_conn_worker(self, conn_id: int, q: "queue.SimpleQueue"):
         """One drained connection's serving thread — the moral twin of a
         socketserver Handler: same fault site, same serving core, same
-        orphan-txn rollback when the conn drops."""
+        orphan-txn rollback when the conn drops.  It exists only for a
+        connection that sent something the drain thread does not serve
+        itself: an update, an interactive transaction, an apb frame, a
+        shed, or a frame pipelined behind another."""
         nf = self.native
         conn_txns = set()
         rec = self._tls.rec = _RequestTrace(conn_id)
@@ -778,7 +896,11 @@ class ProtocolServer:
                 item = q.get()
                 if item is None or self._closing:
                     return
-                kind, aux, frame, t_arrive, t_taken = item
+                kind, aux, frame, t_arrive, t_taken, seq, after = item
+                if after is not None:
+                    # a read the drain thread parked for this connection
+                    # is still in the pipeline: its reply leaves first
+                    after.event.wait(timeout=300)
                 admitted = 1 if kind == nf.K_FRAME else 0
                 frame = self._frame_fault(frame)
                 if frame is None:
@@ -796,7 +918,7 @@ class ProtocolServer:
                     continue
                 # t_arrive: the io thread's stamp of the complete frame;
                 # t_taken: the drain loop's, after take_batch returned
-                rec.begin(t_arrive, t_taken)
+                rec.begin(t_arrive, t_taken, seq)
                 try:
                     buf = self._frame_reply(frame, conn_txns)
                 except Exception as e:  # never wedge the admission slot
@@ -810,37 +932,40 @@ class ProtocolServer:
             for txid in conn_txns:
                 self._abort_orphan(txid)
 
+    @staticmethod
+    def _request_record(rec: _RequestTrace, now: float) -> tuple:
+        """A finished request's ``(path, id, batch, stamps)`` for the
+        stage accumulator; ``now`` = its reply was handed to the socket.
+        The path is what served THIS request, whatever else rode in its
+        batch."""
+        w = rec.work
+        if w is None:
+            return (rec.path, (rec.conn, rec.seq), 0,
+                    (rec.t_arrive, rec.t_taken, 0.0, 0.0, 0.0, 0.0, 0.0,
+                     rec.t_ready, now))
+        if not w.t_ready:
+            # no stage answered it: refused at a full gate, expired
+            # while parked, or failed by a stage's error path
+            path = "shed"
+        elif w.kind != "read":
+            path = w.kind                   # "update" | "commit"
+        elif w.t_synced:
+            path = "gather"                 # a device gather served it
+        elif w.t_wb_start:
+            path = "cache"                  # all hits at launch time
+        else:
+            path = "locked"
+        return (path, (rec.conn, rec.seq), w.batch_id,
+                (rec.t_arrive, rec.t_taken, w.t_submit, w.t_dequeued,
+                 w.t_launched, w.t_wb_start, w.t_synced, w.t_ready, now))
+
     def _close_request(self, rec: _RequestTrace) -> None:
         """The ONE closing call of a request's stage record, after its
         reply has been handed to the socket (``sendall`` / ``nf.send``
         returned): folded into its path's sums (one lock take), and the
-        request histogram (arrival → sent).  The path is what served THIS
-        request, whatever else rode in its batch."""
-        now = time.monotonic()
-        w = rec.work
-        if w is None:
-            path, batch = rec.path, 0
-            stamps = (rec.t_arrive, rec.t_taken, 0.0, 0.0, 0.0, 0.0, 0.0,
-                      rec.t_ready, now)
-        else:
-            if not w.t_ready:
-                # no stage answered it: refused at a full gate, expired
-                # while parked, or failed by a stage's error path
-                path = "shed"
-            elif w.kind != "read":
-                path = w.kind                   # "update" | "commit"
-            elif w.t_synced:
-                path = "gather"                 # a device gather served it
-            elif w.t_wb_start:
-                path = "cache"                  # all hits at launch time
-            else:
-                path = "locked"
-            batch = w.batch_id
-            stamps = (rec.t_arrive, rec.t_taken, w.t_submit, w.t_dequeued,
-                      w.t_launched, w.t_wb_start, w.t_synced, w.t_ready,
-                      now)
-        self.metrics.server_request_seconds.observe(
-            self._stages.close(path, (rec.conn, rec.seq), batch, stamps))
+        request histogram (arrival → sent)."""
+        self.metrics.server_request_seconds.observe(self._stages.close(
+            *self._request_record(rec, time.monotonic())))
 
     def _busy_reply_bytes(self, frame: bytes, hint_ms: int) -> bytes:
         """Framed admission-shed reply in the frame's dialect (the
@@ -950,14 +1075,32 @@ class ProtocolServer:
     def _submit(self, work: _StaticWork, q: Optional[TenantLanes] = None):
         """Park a work on a pipeline queue (default: the batch gate;
         interactive commits go straight to the locked-plane merge point
-        — one hop fewer) and wait for its stage to reply.  Tenant
-        discipline (ISSUE 19): the work enters its tenant's in-flight
-        account (typed ``tenant_busy`` past a configured cap) and its
-        tenant's bounded LANE — never the shared budget."""
+        — one hop fewer) and wait for its stage to reply."""
+        tenant = self._park(work, self._static_q if q is None else q)
+        try:
+            if not work.event.wait(timeout=300):
+                raise TimeoutError("static batch dispatcher stalled")
+        finally:
+            self._tenant_done(tenant)
+        # tenant-label-ok: clamped by TenantRegistry.label in _park
+        self.metrics.tenant_request_seconds.observe(
+            time.monotonic() - work.t_submit, tenant=tenant)
+        if work.error is not None:
+            raise work.error
+        return work.result
+
+    def _park(self, work: _StaticWork, q: TenantLanes) -> str:
+        """The half of a submit that never waits, shared by the threads
+        that then wait for the work (``_submit``) and the native drain
+        thread, which does not (``_direct_read``).  Tenant discipline
+        (ISSUE 19): the work enters its tenant's in-flight account
+        (typed ``tenant_busy`` past a configured cap) and its tenant's
+        bounded LANE — never the shared budget.  Returns the tenant
+        label the work is accounted to; whoever completes the work
+        leaves the account (``_tenant_done``).  A refusal raises typed
+        with the account left as it was."""
         if self._closing:
             raise ConnectionError("server shutting down")
-        if q is None:
-            q = self._static_q
         tenant = self.tenants.label(work.tenant)
         m = self.metrics
         try:
@@ -969,7 +1112,7 @@ class ProtocolServer:
             raise
         now = time.monotonic()
         work.t_submit = now
-        rec = getattr(self._tls, "rec", None)
+        rec = work.rec or getattr(self._tls, "rec", None)
         if rec is not None and rec.work is None:
             rec.work = work
             m.stage_decode_seconds.observe(
@@ -994,21 +1137,68 @@ class ProtocolServer:
                     f"parked)",
                     retry_after_ms=100,
                 ) from None
-            if q is self._static_q:
-                m.commit_gate_depth.set(q.qsize())
-            if not work.event.wait(timeout=300):
-                raise TimeoutError("static batch dispatcher stalled")
-        finally:
-            self.admission.tenant_exit(tenant)
+        except BaseException:
+            self._tenant_done(tenant)
+            raise
+        if q is self._static_q:
+            m.commit_gate_depth.set(q.qsize())
+        return tenant
+
+    def _tenant_done(self, tenant: str, count: int = 1) -> None:
+        """``count`` works of ``tenant`` (a clamped label) are over:
+        they leave its in-flight account."""
+        self.admission.tenant_exit(tenant, count)
+        # tenant-label-ok: callers pass TenantRegistry.label's value
+        self.metrics.tenant_in_flight.set(
+            self.admission.tenant_in_flight(tenant), tenant=tenant)
+
+    def _complete(self, w: _StaticWork) -> None:
+        """The ONE way a stage ends a work whose ``result`` or ``error``
+        it has set: wake the thread that waits on it, or — for a work
+        that carries its connection — send its reply."""
+        if w.rec is None:
+            w.event.set()
+        else:
+            self._reply_direct((w,))
+
+    def _reply_direct(self, works) -> None:
+        """Complete works that carry their connection, together: their
+        reply frames (the frame the writeback stage encoded, else the
+        result's, else the typed error's) go to the native plane in ONE
+        call, then the works leave their tenants' accounts and their
+        stage records close under one lock take (``t_sent`` = the native
+        send returned).  Last, their events: a later frame of one of
+        these connections may now be served."""
+        replies = []
+        for w in works:
+            if w.error is None and w.reply_bytes is None:
+                # answered by a stage that frames nothing (locked plane)
+                try:
+                    w.reply_bytes = encode(MessageCode.READ_OBJECTS_RESP,
+                                           _read_resp(*w.result))
+                except Exception as e:  # never wedge the admission slot
+                    w.error = e
+            # a drained frame holds one admission slot
+            replies.append((w.rec.conn, w.reply_bytes if w.error is None
+                            else encode(MessageCode.ERROR_RESP,
+                                        self._error_body(w.error)), 1))
+        self.native.send_many(replies)
+        now = time.monotonic()
+        m = self.metrics
+        leaving: Dict[str, int] = {}
+        for w in works:
+            tenant = self.tenants.label(w.tenant)
+            leaving[tenant] = leaving.get(tenant, 0) + 1
             # tenant-label-ok: clamped by TenantRegistry.label above
-            m.tenant_in_flight.set(
-                self.admission.tenant_in_flight(tenant), tenant=tenant)
-        # tenant-label-ok: clamped by TenantRegistry.label above
-        m.tenant_request_seconds.observe(time.monotonic() - now,
-                                         tenant=tenant)
-        if work.error is not None:
-            raise work.error
-        return work.result
+            m.tenant_request_seconds.observe(now - w.t_submit,
+                                             tenant=tenant)
+        for tenant, n in leaving.items():
+            self._tenant_done(tenant, n)
+        for total in self._stages.close_many(
+                [self._request_record(w.rec, now) for w in works]):
+            m.server_request_seconds.observe(total)
+        for w in works:
+            w.event.set()
 
     def _drain_batch(self, q, wait_span: str, window_s: float = 0.0):
         """Block for one work (under the host span ``wait_span``), drain
@@ -1055,16 +1245,16 @@ class ProtocolServer:
                 w.error = DeadlineExceeded(
                     f"request deadline passed while parked at the "
                     f"{where}; not executed")
-                w.event.set()
+                self._complete(w)
             else:
                 live.append(w)
         return live
 
-    @staticmethod
-    def _fail_queue_remainder(q) -> None:
+    def _fail_queue_remainder(self, q) -> None:
         """Shutdown drain: fail anything that raced the stop sentinel
         into the queue — a handler parked behind it must not wait out
-        its submit timeout."""
+        its submit timeout, and a read that carries its connection is
+        answered typed, not left silent."""
         while True:
             try:
                 w = q.get_nowait()
@@ -1072,7 +1262,7 @@ class ProtocolServer:
                 return
             if w is not _STOP:
                 w.error = ConnectionError("server shutting down")
-                w.event.set()
+                self._complete(w)
 
     def _static_loop(self):
         """The DISPATCHER stage of the serving pipeline: take what is at
@@ -1141,7 +1331,7 @@ class ProtocolServer:
                         # tenant-label-ok: clamped via TenantRegistry.label
                         m.tenant_shed.inc(tenant=e.tenant, plane="locked")
                         w.error = e
-                        w.event.set()
+                        self._complete(w)
                         continue
                     except (BusyError, queue.Full):
                         m.shed.inc(plane="server_queue")
@@ -1149,7 +1339,7 @@ class ProtocolServer:
                             f"static batch gate full (locked plane: "
                             f"{self._locked_q.maxsize} parked)",
                             retry_after_ms=100)
-                        w.event.set()
+                        self._complete(w)
                         continue
                     if w.kind == "read":
                         m.serving_reads.inc(len(w.objects), path="locked")
@@ -1157,7 +1347,7 @@ class ProtocolServer:
                 for w in works:
                     if not w.event.is_set():
                         w.error = e
-                        w.event.set()
+                        self._complete(w)
             if slot:
                 # every read was shed at dequeue: nothing to launch
                 self._release_wb_slot()
@@ -1208,7 +1398,7 @@ class ProtocolServer:
                 for w in works:
                     if not w.event.is_set():
                         w.error = e
-                        w.event.set()
+                        self._complete(w)
             if stop:
                 self._fail_queue_remainder(q)
                 return
@@ -1263,7 +1453,7 @@ class ProtocolServer:
                 if not slot and not self._take_wb_slot():
                     for w in chunk:
                         w.error = ConnectionError("server shutting down")
-                        w.event.set()
+                        self._complete(w)
                     continue
                 slot = False  # the chunk owns it now
                 leftover.extend(self._launch_epoch_chunk(chunk))
@@ -1390,9 +1580,13 @@ class ProtocolServer:
     def _writeback_loop(self):
         """The WRITEBACK stage: the only pipeline stage allowed to block
         on the device.  Materializes launched epoch-read batches, decodes
-        values (back-filling the hot-key snapshot cache), serializes the
-        native reply frames in one tight loop, and wakes the parked
-        handler threads."""
+        values (back-filling the hot-key snapshot cache) and serializes
+        the native reply frames in one tight loop.  A work a thread
+        waits on is woken as soon as its own result is there; the works
+        that carry their connection are completed together once the
+        whole batch is done — their frames leave in ONE native send
+        (``_reply_direct``) — so no thread wakes up while this one still
+        encodes the rest."""
         q = self._writeback_q
         m = self.metrics
         while True:
@@ -1402,6 +1596,7 @@ class ProtocolServer:
                 return
             store = self.node.txm.store
             t0 = time.monotonic()
+            direct: List[_StaticWork] = []
             try:
                 # sync-ok: the writeback stage owns the device sync
                 vals = store.epoch_read_finish(batch.pending)
@@ -1426,13 +1621,20 @@ class ProtocolServer:
                         if not gathered.isdisjoint(range(lo, hi)):
                             w.t_synced = t_synced
                         w.t_ready = time.monotonic()
-                        w.event.set()
+                        if w.rec is None:
+                            w.event.set()
+                        else:
+                            direct.append(w)
+                if direct:
+                    with span("serve.wb_host.reply", batch=batch.id,
+                              works=len(direct)):
+                        self._reply_direct(direct)
             except BaseException as e:
                 log.exception("epoch read writeback failed")
                 for w in batch.works:
                     if not w.event.is_set():
                         w.error = e
-                        w.event.set()
+                        self._complete(w)
             finally:
                 store.unpin_serving_epoch(batch.pending.ep)
                 m.stage_writeback_seconds.observe(time.monotonic() - t0)
@@ -1554,7 +1756,7 @@ class ProtocolServer:
                 for i, w in enumerate(merged):
                     w.result = (vals[offs[i]:offs[i + 1]], vc)
                     w.t_ready = time.monotonic()
-                    w.event.set()
+                    self._complete(w)
             except Exception:
                 solo = merged + solo  # isolate the offender
         for w in solo:
@@ -1567,7 +1769,7 @@ class ProtocolServer:
             except Exception as e:
                 w.error = e
             w.t_ready = time.monotonic()
-            w.event.set()
+            self._complete(w)
 
     def _covered_vc(self):
         """Freshest locally-covered clock (entry-wise), or None when the
@@ -1860,11 +2062,7 @@ class ProtocolServer:
                     tenant=body.get("tenant"),
                 )
                 if via_proxy:
-                    vals, vc = out
-                    resp = {
-                        "values": [encode_value(v) for v in vals],
-                        "commit_clock": [int(x) for x in vc],
-                    }
+                    resp = _read_resp(*out)
                     # teach the mis-routed client the ring so it
                     # converges back to zero-hop
                     self._attach_hint(resp)
@@ -1879,11 +2077,7 @@ class ProtocolServer:
                 # batched reply serialization: the writeback stage framed
                 # the response; the handler sends the bytes as-is
                 return MessageCode.READ_OBJECTS_RESP, out
-            vals, vc = out
-            return MessageCode.READ_OBJECTS_RESP, {
-                "values": [encode_value(v) for v in vals],
-                "commit_clock": [int(x) for x in vc],
-            }
+            return MessageCode.READ_OBJECTS_RESP, _read_resp(*out)
         if code == MessageCode.STATIC_UPDATE_OBJECTS:
             vc = self.static_update(
                 _decode_updates(body["updates"]), body.get("clock"),
@@ -2150,6 +2344,10 @@ class ProtocolServer:
             },
             "locked_depth": self._locked_q.qsize(),
             "group_commit_window_us": round(self._group_window_s * 1e6, 1),
+            # static reads off the native drain: served (or parked, the
+            # reply then leaving from the stage that answers) by the
+            # drain thread itself / handed to a connection worker
+            "direct": dict(self._direct),
         }
         # per-path stage split of every request since boot, and the
         # slowest stage records since the last status read (ISSUE 24)
@@ -2183,16 +2381,6 @@ class ProtocolServer:
             self.proxy.close()
         self._server.shutdown()
         self._server.server_close()
-        if self.native is not None:
-            # unwire the mirror FIRST: kv.py must stop pushing into a
-            # handle about to be quarantined
-            txm = getattr(self.node, "txm", None)
-            if txm is not None and getattr(txm.store, "native_mirror",
-                                           None) is self.native:
-                txm.store.native_mirror = None
-            self.native.close()
-            if self._native_drain is not None:
-                self._native_drain.join(timeout=5)
         if self.batch_static:
             # the gate is bounded now: a full queue + wedged dispatcher
             # must not turn close() into a forever-blocking put
@@ -2213,6 +2401,19 @@ class ProtocolServer:
             # the dispatcher's stop path forwarded _STOP to the locked
             # worker; it drains whatever raced in behind the sentinel
             self._locked_worker.join(timeout=5)
+        if self.native is not None:
+            # AFTER the pipeline: what it still answered, and the typed
+            # errors of what it failed, leave through the native plane
+            # (frontend_stop writes out what is buffered).
+            # unwire the mirror FIRST: kv.py must stop pushing into a
+            # handle about to be quarantined
+            txm = getattr(self.node, "txm", None)
+            if txm is not None and getattr(txm.store, "native_mirror",
+                                           None) is self.native:
+                txm.store.native_mirror = None
+            self.native.close()
+            if self._native_drain is not None:
+                self._native_drain.join(timeout=5)
         if self._ticker_runs:
             self._ticker.join(timeout=5)
         self._thread.join(timeout=5)

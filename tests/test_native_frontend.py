@@ -429,6 +429,491 @@ def test_frontend_recv_faults_fire_on_native_path():
 
 
 # ---------------------------------------------------------------------------
+# ISSUE 28: a static read the drain thread parks carries its connection
+# — no per-connection thread, the answering stage sends the reply
+# ---------------------------------------------------------------------------
+def _read_frame_of(key, bucket="b", **extra):
+    return _raw_frame(MessageCode.STATIC_READ_OBJECTS, {
+        "objects": [[key, "counter_pn", bucket]], "clock": None, **extra})
+
+
+def _boot_gathering(**kw):
+    """A native server on which every static read crosses to Python and
+    misses the snapshot cache: the read takes the gather path."""
+    kw.setdefault("max_in_flight_per_client", 256)
+    node, srv = _boot(True, epoch_tick_ms=25, **kw)
+    node.txm.store.snapshot_cache_cap = 0
+    srv.native.set_fast_serve(False)
+    return node, srv
+
+
+def _wait(cond, timeout=10.0):
+    deadline = time.monotonic() + timeout
+    while not cond():
+        assert time.monotonic() < deadline, "condition never held"
+        time.sleep(0.002)
+
+
+def _settle(node):
+    """Until the published serving epoch covers every acked commit (the
+    ticker covers a deferred publish within a tick)."""
+    txm = node.txm
+    _wait(lambda: node.store.serving_epoch is not None
+          and int(node.store.serving_epoch.vc[txm.my_dc])
+          >= max(txm.commit_counter, txm.epoch_lag_counter))
+    time.sleep(0.05)
+
+
+def _conn_threads():
+    import threading
+
+    return sorted(t.name for t in threading.enumerate()
+                  if t.name.startswith("antidote-native-conn-"))
+
+
+def _hold_writeback(node):
+    """Hold the writeback stage inside ``epoch_read_finish`` until the
+    returned event is set."""
+    import threading
+
+    gate = threading.Event()
+    finish = node.txm.store.epoch_read_finish
+
+    def held(pending):
+        assert gate.wait(30), "test never released the writeback"
+        return finish(pending)
+
+    node.txm.store.epoch_read_finish = held
+    return gate
+
+
+@pytest.mark.smoke
+def test_direct_reads_create_no_connection_thread_and_count():
+    """One static read at a time per connection: served by the drain
+    thread and the pipeline's stages alone; a batch's replies are one
+    native send; the Python plane counts nothing."""
+    import threading
+
+    node, srv = _boot_gathering()
+    w = AntidoteClient(port=srv.port)
+    try:
+        for i in range(12):
+            w.update_objects([(f"d{i}", "counter_pn", "b",
+                               ("increment", i + 1))])
+        _settle(node)
+        before = _conn_threads()        # the writer's worker, at most
+        st0 = srv._pipeline_status()
+        wrong = []
+
+        def reader(t):
+            c = AntidoteClient(port=srv.port)
+            try:
+                for r in range(40):
+                    i = (t * 5 + r) % 12
+                    vals, _ = c.read_objects([(f"d{i}", "counter_pn", "b")])
+                    if vals != [i + 1]:
+                        wrong.append((i, vals))
+                    # no thread was made for a connection that only reads
+                    assert _conn_threads() == before
+            finally:
+                c.close()
+
+        ts = [threading.Thread(target=reader, args=(t,)) for t in range(6)]
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join(60)
+            assert not t.is_alive()
+        st1 = srv._pipeline_status()
+    finally:
+        w.close()
+        srv.close()
+    assert not wrong
+    assert st1["direct"]["served"] - st0["direct"]["served"] == 240
+    assert st1["direct"]["worker"] == st0["direct"]["worker"] == 0
+    assert st1["reads"]["gather"] - st0["reads"].get("gather", 0) == 240
+    n0, n1 = st0["native"], st1["native"]
+    launches = (st1["stages"]["launch"]["count"]
+                - st0["stages"]["launch"]["count"])
+    assert n1["send_frames"] - n0["send_frames"] == 240
+    # one native send a writeback batch, whatever rode in it
+    assert n1["send_calls"] - n0["send_calls"] == launches
+    # every stage of the record is there, reply included
+    g = st1["paths"]["gather"]
+    assert g["reply"]["count"] == g["total"]["count"] >= 240
+    assert g["cross"]["count"] == g["decode"]["count"] == g["total"]["count"]
+
+    # the Python plane: every work is waited on, nothing is counted
+    node_p, srv_p = _boot(False)
+    c = AntidoteClient(port=srv_p.port)
+    try:
+        c.update_objects([("p", "counter_pn", "b", ("increment", 1))])
+        for _ in range(5):
+            assert c.read_objects([("p", "counter_pn", "b")])[0] == [1]
+        assert srv_p._pipeline_status()["direct"] == {"served": 0,
+                                                      "worker": 0}
+    finally:
+        c.close()
+        srv_p.close()
+
+
+def test_direct_share_metric_file_reads_the_counter():
+    """`frontend.direct_share`: its file agrees with its BENCHMARK.json
+    entry, reads the share off two node statuses, and reads nothing —
+    without raising — off a program that has no such counter."""
+    from types import SimpleNamespace
+
+    from benchmarks.readers import status_delta
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "layer_metrics",
+                           "frontend.direct_share.json")) as f:
+        spec = json.load(f)
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        entry = [m for m in json.load(f)["per_layer"]
+                 if m["name"] == "frontend.direct_share"]
+    assert len(entry) == 1 and entry[0]["source"] == "program_counter"
+    for key in ("unit", "better", "layer", "moves", "workloads"):
+        assert spec[key] == entry[0][key], key
+
+    def ctx(pre, post):
+        return SimpleNamespace(status={"window": (
+            {"pipeline": pre}, {"pipeline": post})})
+
+    assert status_delta.read(spec, ctx(
+        {"direct": {"served": 10, "worker": 30}},
+        {"direct": {"served": 1000, "worker": 40}})) == pytest.approx(99.0)
+    # no static read crossed in the window / the parent's status
+    assert status_delta.read(spec, ctx(
+        {"direct": {"served": 7, "worker": 1}},
+        {"direct": {"served": 7, "worker": 1}})) is None
+    assert status_delta.read(spec, ctx({}, {})) is None
+
+
+def test_pipelined_connection_gets_replies_in_request_order():
+    """read-miss, read-hit, update, read written without waiting, 200
+    rounds: the first frame parks from the drain thread, the rest take
+    the worker, which may not overtake it; and the next round's first
+    read sees the update the round before acknowledged."""
+    node, srv = _boot(True, epoch_tick_ms=25, max_in_flight_per_client=256)
+    c = AntidoteClient(port=srv.port)
+    s = None
+    try:
+        c.update_objects([("o-hit", "counter_pn", "b",
+                           ("increment", 1000003))])
+        c.update_objects([("o-hit2", "counter_pn", "b",
+                           ("increment", 1000005))])
+        time.sleep(0.3)
+        for k in ("o-hit", "o-hit2"):       # into the cache and the mirror
+            c.read_objects([(k, "counter_pn", "b")])
+        s = socket.create_connection(("127.0.0.1", srv.port), timeout=10)
+        s.settimeout(30)
+        round_ = (_read_frame_of("o-miss") + _read_frame_of("o-hit")
+                  + _raw_frame(MessageCode.STATIC_UPDATE_OBJECTS, {
+                      "updates": [["o-miss", "counter_pn", "b",
+                                   ["increment", 1]]], "clock": None})
+                  + _read_frame_of("o-hit2"))
+        for r in range(200):
+            s.sendall(round_)
+            got = [decode(read_frame(s)) for _ in range(4)]
+            assert [code for code, _ in got] == [
+                MessageCode.READ_OBJECTS_RESP, MessageCode.READ_OBJECTS_RESP,
+                MessageCode.COMMIT_RESP, MessageCode.READ_OBJECTS_RESP], \
+                (r, got)
+            assert [b.get("values") for _, b in got] == [
+                [r], [1000003], None, [1000005]], (r, got)
+        d = srv._pipeline_status()["direct"]
+        # each round's first frame found nothing of its connection in
+        # Python; the two reads behind it did
+        assert d["served"] >= 200 and d["worker"] >= 400, d
+    finally:
+        if s is not None:
+            s.close()
+        c.close()
+        srv.close()
+
+
+@pytest.mark.parametrize("refusal", ["gate_full", "tenant_busy", "deadline"])
+def test_direct_read_refusal_is_the_worker_paths_frame(refusal):
+    """Two identical reads in one write: the first is parked (or
+    refused) by the drain thread, the second, pipelined behind it, by
+    the connection's worker.  Both are refused the same way, and the two
+    error frames are the same bytes — the one mapping, ``_error_body``."""
+    from antidote_tpu.overload import (BusyError, DeadlineExceeded,
+                                       TenantBusyError)
+    from antidote_tpu.tenancy import TenantRegistry
+
+    kw = {}
+    if refusal == "tenant_busy":
+        kw["tenants"] = TenantRegistry.from_flags(["gold:1,max_in_flight=1"])
+    node, srv = _boot_gathering(**kw)
+    bucket = "gold/b" if refusal == "tenant_busy" else "b"
+    c = AntidoteClient(port=srv.port)
+    s = None
+    try:
+        c.update_objects([("rf", "counter_pn", bucket, ("increment", 2))])
+        _settle(node)
+        extra = {}
+        if refusal == "gate_full":
+            srv._static_q.lane_caps = dict.fromkeys(
+                srv._static_q.lane_caps, 0)
+            want, kind = BusyError, "busy"
+        elif refusal == "tenant_busy":
+            srv.admission.tenant_enter("gold")      # its one slot is taken
+            want, kind = TenantBusyError, "tenant_busy"
+        else:
+            extra["deadline_ms"] = 0.001            # passes while parked
+            want, kind = DeadlineExceeded, "deadline"
+        d0 = dict(srv._direct)
+        s = socket.create_connection(("127.0.0.1", srv.port), timeout=10)
+        s.settimeout(20)
+        s.sendall(_read_frame_of("rf", bucket, **extra) * 2)
+        first, second = read_frame(s), read_frame(s)
+        assert {k: srv._direct[k] - d0[k] for k in d0} == {
+            "served": 1, "worker": 1}
+        assert first == second, (first, second)
+        code, body = decode(first)
+        assert code == MessageCode.ERROR_RESP and body["error"] == kind
+        # ... and it is what the mapping makes of that exception
+        if want is DeadlineExceeded:
+            e = want(body["detail"])
+        elif want is BusyError:
+            e = want(body["detail"], retry_after_ms=body["retry_after_ms"])
+        else:
+            e = want(body["detail"], tenant="gold",
+                     retry_after_ms=body["retry_after_ms"])
+        assert srv._error_body(e) == body
+        # nothing stays accounted: slots, tenant account, stage records
+        _wait(lambda: srv.native.stats()["in_flight"] == 0)
+        assert srv.admission.tenant_in_flight("default") == 0
+        assert srv.admission.tenant_in_flight("gold") == (
+            1 if refusal == "tenant_busy" else 0)
+        # (a refusal at the tenant's account comes before the work is
+        # the request's: its record closes under "other")
+        _wait(lambda: sum(
+            p["total"]["count"]
+            for name, p in srv._pipeline_status()["paths"].items()
+            if name in ("shed", "other")) == 2)
+    finally:
+        if s is not None:
+            s.close()
+        c.close()
+        srv.close()
+
+
+def test_connection_dropped_with_its_direct_read_in_the_pipeline():
+    """The reply has nowhere to go, but what the read holds is given
+    back: the admission slot (global and per host), the tenant account,
+    the writeback slot — the next connection of that host is served."""
+    node, srv = _boot_gathering(max_in_flight=64,
+                                max_in_flight_per_client=1)
+    c = AntidoteClient(port=srv.port)
+    try:
+        c.update_objects([("dr", "counter_pn", "b", ("increment", 9))])
+        _settle(node)
+        threads0 = _conn_threads()                   # c's worker
+        gate = _hold_writeback(node)
+        seq0 = srv._launch_seq
+        closed0 = srv.native.stats()["closed"]
+        s = socket.create_connection(("127.0.0.1", srv.port), timeout=10)
+        s.sendall(_read_frame_of("dr"))
+        _wait(lambda: srv._launch_seq == seq0 + 1)   # launched, held
+        assert srv.native.stats()["in_flight"] == 1
+        assert srv.admission.tenant_in_flight("default") == 1
+        # a reset, not a half-close (after which the reply is still owed)
+        s.setsockopt(socket.SOL_SOCKET, socket.SO_LINGER,
+                     struct.pack("ii", 1, 0))
+        s.close()
+        _wait(lambda: srv.native.stats()["closed"] == closed0 + 1)
+        assert srv.native.stats()["in_flight"] == 1  # still owed a send
+        gate.set()
+        _wait(lambda: srv.native.stats()["in_flight"] == 0)
+        _wait(lambda: srv.admission.tenant_in_flight("default") == 0)
+        _wait(lambda: srv._wb_unfinished == 0)
+        assert _conn_threads() == threads0
+        # the host's one slot came back (max_in_flight_per_client=1)
+        assert c.read_objects([("dr", "counter_pn", "b")])[0] == [9]
+        assert srv.native.stats()["sheds"] == 0
+    finally:
+        c.close()
+        srv.close()
+
+
+def test_send_many_is_n_sends():
+    """frontend_send_many of n frames against n frontend_send calls: the
+    sockets get the same bytes, the front end's counters end equal
+    (``send_calls`` apart, which is the point)."""
+    from antidote_tpu.proto.native_frontend import NativeFrontend
+
+    n = 5
+
+    def run(many: bool):
+        nf = NativeFrontend.create("127.0.0.1", 0, 64, 32, 32)
+        if nf is None:
+            pytest.skip("native frontend unavailable (no g++/epoll)")
+        socks = []
+        try:
+            for i in range(n):
+                s = socket.create_connection(("127.0.0.1", nf.port),
+                                             timeout=10)
+                s.settimeout(10)
+                # an update crosses to Python whatever the mirror holds
+                s.sendall(_raw_frame(MessageCode.STATIC_UPDATE_OBJECTS,
+                                     {"i": i}))
+                socks.append(s)
+            frames = []
+            deadline = time.monotonic() + 10
+            while len(frames) < n:
+                assert time.monotonic() < deadline
+                frames += nf.take_batch(200)
+            assert all(k == nf.K_FRAME and aux == 0
+                       for _c, k, aux, _p, _t in frames)
+            by_i = {msgpack.unpackb(p[1:])["i"]: cid
+                    for cid, _k, _a, p, _t in frames}
+            assert nf.stats()["in_flight"] == n
+            replies = [(by_i[i], _raw_frame(MessageCode.ERROR_RESP,
+                                            {"n": i, "pad": "x" * (7 * i)}),
+                        1) for i in range(n)]
+            # one reply of a batch may be the account-only empty one
+            replies[2] = (replies[2][0], b"", 1)
+            calls0 = nf.stats()["send_calls"]
+            if many:
+                nf.send_many(replies)
+            else:
+                for r in replies:
+                    nf.send(*r)
+            got = [read_frame(s) if i != 2 else None
+                   for i, s in enumerate(socks)]
+            _wait(lambda: nf.stats()["send_frames"] == n - 1)
+            st = nf.stats()
+            return got, st, st["send_calls"] - calls0
+        finally:
+            for s in socks:
+                s.close()
+            nf.close()
+
+    got_m, st_m, calls_m = run(True)
+    got_s, st_s, calls_s = run(False)
+    assert got_m == got_s and got_m[0] is not None
+    assert (calls_m, calls_s) == (1, n)
+    for k in ("in_flight", "send_frames", "forwarded", "frames",
+              "cross_frames", "sheds", "open_conns"):
+        assert st_m[k] == st_s[k], k
+    assert st_m["in_flight"] == 0
+
+
+def test_direct_read_rerouted_to_the_locked_plane_is_answered():
+    """No serving epoch to pin: the dispatcher hands the parked read to
+    the locked plane, whose worker answers it — by sending, since
+    nobody waits on it."""
+    node, srv = _boot_gathering()
+    c = AntidoteClient(port=srv.port)
+    s = None
+    try:
+        c.update_objects([("lk", "counter_pn", "b", ("increment", 4))])
+        _settle(node)
+        node.txm.store.pin_serving_epoch = lambda: None
+        st0 = srv._pipeline_status()
+        threads0 = _conn_threads()
+        s = socket.create_connection(("127.0.0.1", srv.port), timeout=10)
+        s.settimeout(20)
+        for _ in range(3):
+            s.sendall(_read_frame_of("lk"))
+            code, body = decode(read_frame(s))
+            assert code == MessageCode.READ_OBJECTS_RESP
+            assert body["values"] == [4]
+        st1 = srv._pipeline_status()
+        assert st1["reads"]["locked"] - st0["reads"].get("locked", 0) == 3
+        assert st1["direct"]["served"] - st0["direct"]["served"] == 3
+        assert st1["paths"]["locked"]["total"]["count"] >= 3
+        assert _conn_threads() == threads0
+        _wait(lambda: srv.native.stats()["in_flight"] == 0)
+        assert srv.admission.tenant_in_flight("default") == 0
+    finally:
+        if s is not None:
+            s.close()
+        c.close()
+        srv.close()
+
+
+def test_shutdown_answers_parked_direct_reads_typed():
+    """close() with direct reads in the pipeline: what is parked at the
+    gate is failed with a typed error frame, what was launched is still
+    answered — no connection is left in silence."""
+    import threading
+
+    node, srv = _boot_gathering()
+    depth = ProtocolServer.DEPTH
+    c = AntidoteClient(port=srv.port)
+    socks = []
+    try:
+        for i in range(depth + 2):
+            c.update_objects([(f"sd{i}", "counter_pn", "b",
+                               ("increment", i + 1))])
+        _settle(node)
+        c.close()
+        gate = _hold_writeback(node)
+        seq0 = srv._launch_seq
+        for i in range(depth + 2):
+            s = socket.create_connection(("127.0.0.1", srv.port),
+                                         timeout=10)
+            s.settimeout(20)
+            s.sendall(_read_frame_of(f"sd{i}"))
+            socks.append(s)
+            if i < depth:       # one launch each: every slot is taken
+                _wait(lambda: srv._launch_seq == seq0 + i + 1)
+        _wait(lambda: srv.admission.tenant_in_flight("default")
+              == depth + 2)
+        time.sleep(0.05)
+        closer = threading.Thread(target=srv.close, daemon=True)
+        closer.start()
+        for s in socks[depth:]:
+            code, body = decode(read_frame(s))
+            assert code == MessageCode.ERROR_RESP
+            assert body == {"error": "ConnectionError",
+                            "detail": "server shutting down"}
+        gate.set()
+        for i, s in enumerate(socks[:depth]):
+            code, body = decode(read_frame(s))
+            assert code == MessageCode.READ_OBJECTS_RESP
+            assert body["values"] == [i + 1]
+        closer.join(20)
+        assert not closer.is_alive()
+        assert srv.admission.tenant_in_flight("default") == 0
+    finally:
+        node.txm.store.__dict__.pop("epoch_read_finish", None)
+        for s in socks:
+            s.close()
+        srv.close()
+
+
+def test_clockless_direct_read_sees_every_acknowledged_commit():
+    """The direct path skips threads, not checks: a read sent on a fresh
+    connection after a commit's acknowledgement shows that commit."""
+    node, srv = _boot(True, epoch_tick_ms=25, max_in_flight_per_client=256)
+    w = AntidoteClient(port=srv.port)
+    r = AntidoteClient(port=srv.port)
+    try:
+        threads0 = None
+        for n in range(1, 61):
+            w.update_objects([("ack", "counter_pn", "b", ("increment", 1))])
+            threads0 = threads0 or _conn_threads()      # w's worker
+            vals, _ = r.read_objects([("ack", "counter_pn", "b")],
+                                     clock=None)
+            assert vals == [n]
+        d = srv._pipeline_status()["direct"]
+        st = srv.native.stats()
+        # r's reads were served natively or by the drain thread: it
+        # never had a worker
+        assert d["worker"] == 0 and d["served"] + st["native_hits"] >= 60
+        assert _conn_threads() == threads0
+    finally:
+        w.close()
+        r.close()
+        srv.close()
+
+
+# ---------------------------------------------------------------------------
 # chaos acceptance: SIGKILL under a >=1k-socket storm with seeded
 # drop/truncate faults on the native accept path — every ack made it to
 # the WAL (acked ⊆ recovered), and no connection wedges

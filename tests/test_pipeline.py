@@ -422,6 +422,8 @@ def test_pipeline_status_block_exposed():
         assert set(pl["gate_hold"]) == {"rounds", "held", "sum_ms"}
         assert pl["gate_hold"]["rounds"] >= 1
         assert pl["writeback_depth"] == 0
+        # the Python plane: a thread waits on every work, none is direct
+        assert pl["direct"] == {"served": 0, "worker": 0}
     finally:
         c.close()
         srv.close()
@@ -733,3 +735,59 @@ def test_slots_stay_bounded_and_balanced_under_a_thread_storm():
     assert hold["held"] >= 1 and hold["rounds"] == len(seen)
     # held rounds merged what parked: fewer launches than reads
     assert len(seen) < n_threads * rounds
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 28: a submit is a park and a wait; a batch's stage records close
+# under one lock take
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("refusal", ["gate_full", "closing"])
+def test_refused_park_leaves_the_tenant_account_as_it_was(refusal):
+    from antidote_tpu.overload import BusyError
+    from antidote_tpu.tenancy import DEFAULT_TENANT
+
+    p = _Pipeline(1)
+    try:
+        p.store.snapshot_cache_cap = 0
+        p.read(0).join(10)                       # a park that is served
+        assert p.results.pop(0) == [1]
+        if refusal == "gate_full":
+            caps = p.srv._static_q.lane_caps
+            p.srv._static_q.lane_caps = dict.fromkeys(caps, 0)
+            want = BusyError
+        else:
+            p.srv._closing = True
+            want = ConnectionError
+        p.read(0).join(10)
+        assert isinstance(p.results[0], want), p.results[0]
+        assert p.srv.admission.tenant_in_flight(DEFAULT_TENANT) == 0
+        if refusal == "gate_full":
+            p.srv._static_q.lane_caps = caps
+        else:
+            p.srv._closing = False
+        p.read(0).join(10)                       # and the gate still serves
+        assert p.results[0] == [1]
+        assert p.srv.admission.tenant_in_flight(DEFAULT_TENANT) == 0
+    finally:
+        p.close()
+
+
+def test_close_many_folds_like_one_close_each():
+    from antidote_tpu.obs.trace import StageAccumulator
+
+    recs = [
+        ("gather", (1, 1), 7, (1.0, 1.1, 1.2, 1.5, 1.6, 1.9, 2.0, 2.2, 2.3)),
+        ("gather", (2, 1), 7, (1.05, 1.1, 1.3, 1.5, 1.6, 1.9, 2.0, 2.25, 2.3)),
+        ("cache", (3, 4), 7, (1.0, 1.1, 1.2, 1.5, 1.6, 1.9, 0.0, 2.2, 2.3)),
+        ("shed", (4, 2), 0, (1.0, 1.1, 1.2, 1.5, 0.0, 0.0, 0.0, 0.0, 1.6)),
+        ("other", (5, 9), 0, (1.0, 0.0, 0.0, 0.0, 0.0, 0.0, 0.0, 1.4, 1.45)),
+    ]
+    one, many = StageAccumulator(), StageAccumulator()
+    totals = [one.close(*r) for r in recs]
+    assert many.close_many(recs) == totals
+    assert totals[0] == pytest.approx(1.3)
+    a, b = one.status(), many.status()
+    assert a == b
+    assert a["paths"]["gather"]["total"]["count"] == 2
+    assert a["paths"]["gather"]["reply"]["sum_ms"] == pytest.approx(150.0)
+    assert len(a["slow_requests"]) == len(recs)
