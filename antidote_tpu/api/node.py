@@ -87,7 +87,7 @@ class AntidoteNode:
                     "would replay its log on top of them (double-apply)"
                 )
             log = store.log
-        elif log_dir is not None and self.cfg.enable_logging:
+        elif log_dir is not None:
             import glob
             import os
 
@@ -115,7 +115,7 @@ class AntidoteNode:
             )
         elif recover:
             raise RuntimeError(
-                "recover=True requires log_dir and cfg.enable_logging"
+                "recover=True requires log_dir"
             )
         self.store = store if store is not None else KVStore(
             self.cfg, sharding=sharding, log=log

@@ -1705,8 +1705,7 @@ class ClusterMember:
         rows (stable_time_functions:get_min_time aggregated across nodes,
         /root/reference/src/meta_data_sender.erl:224-255)."""
         self.advance_idle_shards()
-        return stable_min_of(self.clock_matrix(),
-                             getattr(self.cfg, "use_pallas", False))
+        return stable_min_of(self.clock_matrix())
 
     def close(self) -> None:
         self.rpc.close()

@@ -50,7 +50,6 @@ class AntidoteConfig:
     batch_buckets: tuple = (64, 512, 4096)
 
     # --- durability (reference: antidote.app.src:44-48) ---------------
-    enable_logging: bool = True
     sync_log: bool = False
     #: parallel append segments per shard WAL (ISSUE 6): a commit group's
     #: records land on one segment while the group-fsync coordinator
@@ -64,7 +63,7 @@ class AntidoteConfig:
     # --- kernels --------------------------------------------------------
     #: dispatch the materializer hot loops to the hand-tiled Pallas TPU
     #: kernels (materializer/pallas_kernels.py) where a type-specific fused
-    #: kernel exists (counter fold, OR-set presence, stable-VC min); the
+    #: kernel exists (counter fold, OR-set fold and presence); the
     #: generic XLA scan fold remains the fallback and the semantics oracle
     use_pallas: bool = False
     #: over-ring fold routing threshold (store/kv.py::_replay_read_many):
@@ -74,11 +73,6 @@ class AntidoteConfig:
     #: and each strategy's pad-to-multiple keeps XLA compile families
     #: bounded instead of one fresh compile per log length
     fold_chunk: int = 4096
-
-    # --- misc ----------------------------------------------------------
-    #: store a fresh snapshot version only if at least this many ops were
-    #: folded (?MIN_OP_STORE_SS=5, include/antidote.hrl:47)
-    min_op_store_ss: int = 5
 
     def __post_init__(self):
         assert self.n_shards >= 1

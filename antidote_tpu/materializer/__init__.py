@@ -2,7 +2,6 @@ from antidote_tpu.materializer.fold import fold_batch, fold_key, eager_fold_batc
 from antidote_tpu.materializer.pallas_kernels import (
     counter_fold,
     orset_presence,
-    stable_min,
 )
 
 __all__ = [
@@ -11,5 +10,4 @@ __all__ = [
     "eager_fold_batch",
     "counter_fold",
     "orset_presence",
-    "stable_min",
 ]

@@ -2,11 +2,9 @@
 
 The generic fold (`fold.fold_batch`) runs the CRDT-specific ``apply`` under
 a ``lax.scan`` — correct for every type, but for the monoid counter family
-the fold is a *masked reduction*, and the stable-snapshot merge is a
-*masked min-reduction* over per-shard clock rows
-(/root/reference/src/stable_time_functions.erl:51-85).  Both are
-bandwidth-bound VPU work with tiny per-element compute: one pass over the
-op ring in VMEM, inclusion mask (the vectorized ``is_op_in_snapshot``,
+the fold is a *masked reduction*, bandwidth-bound VPU work with tiny
+per-element compute: one pass over the op ring in VMEM, inclusion mask
+(the vectorized ``is_op_in_snapshot``,
 /root/reference/src/clocksi_materializer.erl:214-268) fused with the
 reduction, no [B, K] intermediates materialized in HBM.
 
@@ -229,53 +227,6 @@ def counter_fold(base_cnt, deltas, ops_vc, n_ops, base_vc, read_vc,
         deltas, ops_vc, n_ops, base_vc, read_vc, block, interpret,
     )
     return jnp.asarray(base_cnt, jnp.int64) + dcnt.astype(jnp.int64), applied
-
-
-# ---------------------------------------------------------------------------
-# stable-snapshot min: entry-wise min over N clock rows
-# ---------------------------------------------------------------------------
-def _stable_min_kernel(clocks_ref, out_ref):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _():
-        out_ref[:] = jnp.full_like(out_ref, _I32_MAX)
-
-    out_ref[:] = jnp.minimum(
-        out_ref[:], jnp.min(clocks_ref[:], axis=0, keepdims=True)
-    )
-
-
-@device_program("stable_min", static_argnames=("block", "interpret"))
-def _stable_min_call(clocks, block: int, interpret: bool):
-    clocks = _pad_to(clocks, block, 0, fill=_I32_MAX)
-    n, d = clocks.shape
-    out = pl.pallas_call(
-        _stable_min_kernel,
-        grid=(n // block,),
-        in_specs=[_row(block, d)],
-        out_specs=pl.BlockSpec((1, d), lambda i: (_Z, _Z)),
-        out_shape=jax.ShapeDtypeStruct((1, d), jnp.int32),
-        interpret=interpret,
-        name="antidote_stable_min",
-    )(clocks)
-    return out[0]
-
-
-def stable_min(clocks, block: int = 512, interpret: bool | None = None):
-    """Entry-wise min over ``clocks`` i32[N, D] → i32[D].
-
-    The DC-wide stable snapshot = min over all partitions' applied clocks
-    (/root/reference/src/stable_time_functions.erl:51-85, gossiped once a
-    second there; here one streaming device pass).  Rows with value
-    INT32_MAX (e.g. not-yet-started shards) are identity elements.
-    """
-    if interpret is None:
-        interpret = not _on_tpu()
-    clocks = jnp.asarray(clocks, jnp.int32)
-    if clocks.shape[0] == 0:
-        return jnp.full((clocks.shape[1],), _I32_MAX, jnp.int32)
-    return _stable_min_call(clocks, block, interpret)
 
 
 # ---------------------------------------------------------------------------

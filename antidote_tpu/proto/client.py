@@ -168,7 +168,8 @@ class AntidoteClient:
         # hot-path plumbing: a buffered reader coalesces the header+body
         # reads into ~one syscall per reply, and one persistent Packer
         # skips per-call packer construction — this client is the load
-        # generator in bench_wire, where its CPU bills against the server
+        # generator in benchmarks/loadgen.py, where its CPU bills against
+        # the server's
         self._rfile = self._sock.makefile("rb")
         self._packer = msgpack.Packer(use_bin_type=True)
         #: last ring hint a follower attached to a reply (ISSUE 17):
@@ -406,9 +407,8 @@ class HashRing:
         return [pref] + tail
 
     def arc_share(self) -> Dict[Tuple[str, int], float]:
-        """Fraction of the hash space each endpoint owns (console/bench
-        observability: ring balance, and the fleet-smoke 'all arcs
-        served' gate)."""
+        """Fraction of the hash space each endpoint owns (console
+        observability: ring balance)."""
         if not self._points:
             return {}
         span = float(1 << 64)
@@ -422,7 +422,7 @@ class HashRing:
     def arc_share_by_name(self, digits: int = 4) -> Dict[str, float]:
         """:meth:`arc_share` keyed ``"host:port"`` and rounded — the one
         presentation every surface (console replica-status, session
-        stats, the bench artifact) shows."""
+        stats) shows."""
         return {f"{h}:{p}": round(v, digits)
                 for (h, p), v in self.arc_share().items()}
 
@@ -602,7 +602,7 @@ class SessionClient:
         self._dead: Dict[Tuple[str, int], float] = {}
         #: session observability: typed lagging/not_owner redirects
         #: honored, endpoint failovers on connection death, and reads
-        #: served per endpoint (the fleet-smoke arc coverage signal)
+        #: served per endpoint (the arc coverage signal)
         self.redirects = 0
         self.failovers = 0
         #: ring hints absorbed from server replies (ISSUE 17): each one

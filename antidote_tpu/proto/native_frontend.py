@@ -44,6 +44,8 @@ log = logging.getLogger(__name__)
 _DIR = pathlib.Path(__file__).parent / "cpp"
 _SRC = _DIR / "frontend.cc"
 _SO = _DIR / "_frontend.so"
+#: entries the C++ mirror holds; past it a fill evicts an arbitrary one
+_MIRROR_CAP = 1 << 18
 
 _lib = None
 _lib_tried = False
@@ -178,8 +180,8 @@ class NativeFrontend:
 
     @staticmethod
     def create(host: str, port: int, max_connections: int,
-               max_in_flight: int, max_per_host: int,
-               mirror_cap: int = 1 << 18) -> Optional["NativeFrontend"]:
+               max_in_flight: int,
+               max_per_host: int) -> Optional["NativeFrontend"]:
         if os.environ.get("ANTIDOTE_NATIVE_FRONTEND", "on") == "off":
             return None
         if faults.hit("native_frontend.load") is not None:
@@ -189,7 +191,7 @@ class NativeFrontend:
             return _fallback("compile/load failed")
         h = lib.frontend_create(host.encode(), int(port),
                                 int(max_connections), int(max_in_flight),
-                                int(max_per_host), int(mirror_cap))
+                                int(max_per_host), _MIRROR_CAP)
         if not h:
             return _fallback(f"bind/listen on {host}:{port} failed")
         return NativeFrontend(lib, h)

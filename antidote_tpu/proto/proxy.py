@@ -251,7 +251,7 @@ class ProxyPlane:
         self.forwarded_txns: set = set()
         self._fleet_v = object()  # always != first observed version
         self._closed = False
-        #: forwarded-traffic counters for node_status / the bench gate
+        #: forwarded-traffic counters for node_status
         self._stats_lock = threading.Lock()
         self.counts: Dict[str, int] = {
             "read": 0, "write": 0, "txn": 0, "failover": 0}

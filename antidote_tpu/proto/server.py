@@ -233,14 +233,13 @@ def _vc(x) -> Optional[np.ndarray]:
 class ProtocolServer:
     def __init__(self, node: AntidoteNode, host: str = "127.0.0.1",
                  port: int = 0, interdc=None, max_connections: int = 1024,
-                 batch_static: bool = True, max_in_flight: int = 256,
+                 max_in_flight: int = 256,
                  max_in_flight_per_client: int = 64, queue_max: int = 4096,
                  default_deadline_ms: Optional[float] = None,
                  epoch_tick_ms: float = 100.0,
                  snapshot_cache_size: Optional[int] = None,
                  group_commit_window_us: float = 0.0,
                  follower=None, native_frontend: bool = False,
-                 native_mirror_cap: int = 1 << 18,
                  server_proxy: bool = True, tenants=None):
         self.node = node
         #: multi-tenant QoS (ISSUE 19): weights + caps for every tenant
@@ -257,17 +256,6 @@ class ProtocolServer:
         self.follower = follower
         if follower is not None and interdc is None:
             self.interdc = follower
-        if follower is not None and not batch_static:
-            # the inline (batch_static=False) read path calls
-            # node.read_objects under only the dispatch lock, but a
-            # follower's pump thread mutates the live head buffers via
-            # apply_effects — the commit-lock read discipline lives in
-            # the batch workers, so the combination would race (the
-            # "buffer donated" crash class); refuse it loudly
-            raise ValueError(
-                "a follower server requires batch_static=True (the "
-                "inline read path bypasses the replica's commit-lock "
-                "read discipline)")
         #: symmetric serving fabric (ISSUE 17): on a follower, out-of-arc
         #: session reads proxy one hop to the arc owner and writes/txns
         #: forward to the owner write plane instead of bouncing typed
@@ -306,14 +294,13 @@ class ProtocolServer:
         #: None = requests without a deadline_ms field never expire
         self.default_deadline_ms = default_deadline_ms
         self._conn_ids = itertools.count(1)
+        self._closing = False
         #: cross-connection batch gate (r4 VERDICT item 3): static
         #: reads/updates from concurrent connections coalesce into single
         #: device launches instead of one launch per socket — the wire
         #: analogue of SURVEY §2.10 "batch thousands of reads per launch"
         #: (the reference scales the same path with 20 read servers per
-        #: partition, /root/reference/include/antidote.hrl:28)
-        self.batch_static = batch_static
-        self._closing = False
+        #: partition, /root/reference/include/antidote.hrl:28).
         #: BOUNDED: a full gate answers busy instead of buffering without
         #: limit (admission usually sheds first; this cap is the backstop
         #: against a stalled dispatcher).  Per-tenant bounded LANES with
@@ -347,11 +334,10 @@ class ProtocolServer:
             # share of one merged batch (weight-proportional rounds)
             txm.tenants = self.tenants
         #: lock-split epoch reads need the single-node txn manager (the
-        #: cluster facade routes through 2PC) and the batch dispatcher;
-        #: epoch_tick_ms <= 0 disables the whole epoch plane (operator
+        #: cluster facade routes through 2PC); epoch_tick_ms <= 0
+        #: disables the whole epoch plane (operator
         #: escape hatch back to the locked serving path)
-        self._epoch_reads = bool(batch_static and txm is not None
-                                 and epoch_tick_ms > 0)
+        self._epoch_reads = bool(txm is not None and epoch_tick_ms > 0)
         if self._epoch_reads:
             txm.enable_serving_epochs()
             self._epoch_reads = txm.serving_epochs  # clocksi-only
@@ -405,27 +391,26 @@ class ProtocolServer:
         #: queued during the previous group's execution).
         self._group_window_s = max(0.0, float(group_commit_window_us)) / 1e6
         self._ticker_stop = threading.Event()
-        if batch_static:
-            self._batcher = threading.Thread(
-                target=self._static_loop, daemon=True,
-                name="antidote-proto-batch",
-            )
-            self._batcher.start()
-            self._writeback = threading.Thread(
-                target=self._writeback_loop, daemon=True,
-                name="antidote-proto-writeback",
-            )
-            self._writeback.start()
-            self._locked_worker = threading.Thread(
-                target=self._locked_loop, daemon=True,
-                name="antidote-proto-locked",
-            )
-            self._locked_worker.start()
+        self._batcher = threading.Thread(
+            target=self._static_loop, daemon=True,
+            name="antidote-proto-batch",
+        )
+        self._batcher.start()
+        self._writeback = threading.Thread(
+            target=self._writeback_loop, daemon=True,
+            name="antidote-proto-writeback",
+        )
+        self._writeback.start()
+        self._locked_worker = threading.Thread(
+            target=self._locked_loop, daemon=True,
+            name="antidote-proto-locked",
+        )
+        self._locked_worker.start()
         #: the ticker runs whenever a txn manager exists — even with the
         #: epoch plane disabled (gr protocol / epoch_tick_ms <= 0) it
         #: still drives the LOCKED path's per-table epoch ladder, which
         #: used to piggyback on static-batch traffic
-        self._ticker_runs = bool(batch_static and txm is not None)
+        self._ticker_runs = txm is not None
         if self._ticker_runs:
             self._ticker = threading.Thread(
                 target=self._epoch_ticker, daemon=True,
@@ -490,7 +475,7 @@ class ProtocolServer:
 
             self.native = NativeFrontend.create(
                 host, port, max_connections, max_in_flight,
-                max_in_flight_per_client, mirror_cap=native_mirror_cap)
+                max_in_flight_per_client)
         self._server = Server(
             (host, port if self.native is None else 0), handler)
         self.host, self.port = self._server.server_address
@@ -789,10 +774,9 @@ class ProtocolServer:
         was handed over (``after``)."""
         nf = self.native
         # a static read on this node parks at the gate and its reply can
-        # leave from the answering stage: not where reads run inline
-        # under the dispatch lock, nor on a follower, whose session gate
-        # parks and proxies on the calling thread
-        can_park = self.batch_static and self.follower is None
+        # leave from the answering stage: not on a follower, whose
+        # session gate parks and proxies on the calling thread
+        can_park = self.follower is None
         conns: Dict[int, _NativeConn] = {}
         while not self._closing:
             batch = nf.take_batch(200)
@@ -1006,10 +990,6 @@ class ProtocolServer:
         :class:`RawReply` when ``wants_bytes`` and the writeback stage
         serialized the native reply frame itself."""
         tenant = self.tenants.resolve(tenant, (o[2] for o in objects))
-        if not self.batch_static:
-            with self._lock:
-                check_deadline(deadline, "dispatch")
-                return self.node.read_objects(objects, clock=_vc(clock))
         clock_vc = _vc(clock)
         fast = self._try_cache_read(objects, clock_vc, wants_bytes)
         if fast is not None:
@@ -1063,10 +1043,6 @@ class ProtocolServer:
         queue hop + thread wakeup per write was measurable on the
         2-core write-plane floor (ISSUE 6)."""
         tenant = self.tenants.resolve(tenant, (u[2] for u in updates))
-        if not self.batch_static:
-            with self._lock:
-                check_deadline(deadline, "dispatch")
-                return self.node.update_objects(updates, clock=_vc(clock))
         return self._submit(_StaticWork("update", updates=updates,
                                         clock=_vc(clock),
                                         deadline=deadline, tenant=tenant),
@@ -1364,8 +1340,8 @@ class ProtocolServer:
         once, with per-source acks fanned back out.  Also serves the
         reads the epoch path cannot (clocks ahead of the epoch,
         composite maps, promoted keys, no epoch yet).  Runs under
-        ``self._lock`` — serialized against nothing but itself and
-        inline (batch_static off) dispatch; the epoch read plane never
+        ``self._lock`` — serialized against nothing but itself and the
+        interactive-transaction dispatch; the epoch read plane never
         waits for it."""
         q = self._locked_q
         while True:
@@ -2086,7 +2062,7 @@ class ProtocolServer:
             return MessageCode.COMMIT_RESP, {
                 "commit_clock": [int(x) for x in vc]
             }
-        if (code == MessageCode.COMMIT_TRANSACTION and self.batch_static
+        if (code == MessageCode.COMMIT_TRANSACTION
                 and getattr(self.node, "txm", None) is not None):
             # interactive commits join the cross-connection merge point
             # (ISSUE 6): instead of serializing through the dispatch
@@ -2296,8 +2272,8 @@ class ProtocolServer:
     # ------------------------------------------------------------------
     def _pipeline_status(self) -> dict:
         """Stage-timing + serving-plane block for node status — the
-        server-side breakdown the wire bench freezes into its artifact
-        (decode / parked / launch / writeback µs per stage)."""
+        server-side breakdown the benchmark's `status_delta` metrics
+        read (decode / parked / launch / writeback µs per stage)."""
         m = self.metrics
 
         def us(h):
@@ -2381,26 +2357,25 @@ class ProtocolServer:
             self.proxy.close()
         self._server.shutdown()
         self._server.server_close()
-        if self.batch_static:
-            # the gate is bounded now: a full queue + wedged dispatcher
-            # must not turn close() into a forever-blocking put
-            stop_by = time.monotonic() + 5.0
-            while True:
-                try:
-                    self._static_q.put_nowait(_STOP)
-                    break
-                except queue.Full:
-                    if time.monotonic() >= stop_by:
-                        break  # dispatcher wedged; it is a daemon thread
-                    time.sleep(0.05)
-            self._batcher.join(timeout=5)
-            # stop the writeback stage AFTER the dispatcher: in-flight
-            # launched batches still get materialized and replied
-            self._writeback_q.put(_STOP)
-            self._writeback.join(timeout=5)
-            # the dispatcher's stop path forwarded _STOP to the locked
-            # worker; it drains whatever raced in behind the sentinel
-            self._locked_worker.join(timeout=5)
+        # the gate is bounded now: a full queue + wedged dispatcher
+        # must not turn close() into a forever-blocking put
+        stop_by = time.monotonic() + 5.0
+        while True:
+            try:
+                self._static_q.put_nowait(_STOP)
+                break
+            except queue.Full:
+                if time.monotonic() >= stop_by:
+                    break  # dispatcher wedged; it is a daemon thread
+                time.sleep(0.05)
+        self._batcher.join(timeout=5)
+        # stop the writeback stage AFTER the dispatcher: in-flight
+        # launched batches still get materialized and replied
+        self._writeback_q.put(_STOP)
+        self._writeback.join(timeout=5)
+        # the dispatcher's stop path forwarded _STOP to the locked
+        # worker; it drains whatever raced in behind the sentinel
+        self._locked_worker.join(timeout=5)
         if self.native is not None:
             # AFTER the pipeline: what it still answered, and the typed
             # errors of what it failed, leave through the native plane

@@ -26,9 +26,6 @@ from antidote_tpu.store.typed_table import TypedTable, _bucket
 
 BoundObject = Tuple[Any, str, str]  # (key, type_name, bucket)
 
-#: below this many clock rows the host numpy min beats a device launch
-_PALLAS_MIN_ROWS = 2048
-
 # ---------------------------------------------------------------------------
 # slot tiers — the overflow escape hatch
 #
@@ -70,19 +67,13 @@ def scaled_cfg(cfg: AntidoteConfig, tier: int) -> AntidoteConfig:
     )
 
 
-def stable_min_of(clock_rows: np.ndarray, use_pallas: bool = False) -> np.ndarray:
+def stable_min_of(clock_rows: np.ndarray) -> np.ndarray:
     """Entry-wise min over a clock matrix ``i32[N, D]`` — the stable-time
     merge for ANY collection of per-shard / per-node clocks
     (stable_time_functions:get_min_time,
-    /root/reference/src/stable_time_functions.erl:51-85).  Large matrices
-    (multi-node aggregation: nodes × shards rows) dispatch to the streaming
-    Pallas kernel; small ones stay on host."""
-    clock_rows = np.asarray(clock_rows)
-    if use_pallas and clock_rows.shape[0] >= _PALLAS_MIN_ROWS:
-        from antidote_tpu.materializer import pallas_kernels as pk
-
-        return np.asarray(pk.stable_min(clock_rows))
-    return clock_rows.min(axis=0)
+    /root/reference/src/stable_time_functions.erl:51-85), on the host,
+    where the matrix lives."""
+    return np.asarray(clock_rows).min(axis=0)
 
 
 def _canon(v: Any) -> Any:
@@ -1906,13 +1897,11 @@ class KVStore:
         /root/reference/src/stable_time_functions.erl:51-85).  A
         mesh-resident store (ISSUE 10) computes it as the ``pmin``
         collective over the per-device applied clocks — identical by
-        construction, cached per clock version; otherwise it routes
-        through :func:`stable_min_of`, which keeps the usual
-        ``n_shards``-row matrix on host and dispatches large matrices
-        (many nodes × shards) to the streaming Pallas kernel."""
+        construction, cached per clock version; otherwise it is
+        :func:`stable_min_of`, the host ``min``."""
         if self.mesh is not None:
             return self.mesh.stable_vc(self.applied_vc)
-        return stable_min_of(self.applied_vc, getattr(self.cfg, "use_pallas", False))
+        return stable_min_of(self.applied_vc)
 
     def dc_max_vc(self) -> np.ndarray:
         """Entry-wise max of per-shard clocks — the freshest local view."""

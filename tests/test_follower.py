@@ -314,10 +314,6 @@ def test_apb_dialect_refused_on_follower(cfg, tmp_path):
             fc.close()
         finally:
             srv2.close()
-        # a follower server also refuses the unsafe inline-read mode
-        with pytest.raises(ValueError, match="batch_static"):
-            ProtocolServer(fnode, port=0, follower=fol,
-                           batch_static=False)
     finally:
         srv.close()
         owner.store.log.close(), fnode.store.log.close()
@@ -746,6 +742,18 @@ def test_wire_session_survives_follower_kill_and_rejoin(cfg, tmp_path):
                 vals, _ = sc.read_objects([("k", "counter_pn", "b")])
                 assert vals == [total], (i, vals, total)
             assert sc.failovers == 0
+            # coverage: every follower's ring arcs serve reads (a ring
+            # that sent everything to one endpoint, or a follower wedged
+            # behind its gate, fails here)
+            fleet = [(f["srv"].host, f["srv"].port) for f in (f1, f2)]
+            deadline = time.monotonic() + 30
+            n_cov = 0
+            while not all(sc.served_by.get(ep, 0) > 0 for ep in fleet):
+                assert time.monotonic() < deadline, sc.served_by
+                vals, _ = sc.read_objects([(f"cov{n_cov}", "counter_pn",
+                                            "b")])
+                assert vals == [0]
+                n_cov += 1
             # kill follower 1 mid-session: its replication stops (fabric
             # closed) and its server winds down — the session keeps
             # holding read-your-writes by redirecting/failing over (f2,

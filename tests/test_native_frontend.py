@@ -1,7 +1,7 @@
 """Native serving front-end tests (ISSUE 16): frame-fuzz parity with
 the Python decoder, whole-batch hit byte-parity, admission-shed parity,
 fault-site coverage on the native accept path, graceful fallback, and
-the SIGKILL-under-socket-storm chaos scenario.
+the chaos scenario of a SIGKILL under a socket storm.
 
 The contract under test: the C++ front-end (accept / framing / decode /
 admission / whole-batch cache hits off the GIL) is BEHAVIORALLY
@@ -66,6 +66,17 @@ def _boot(native: bool, **kw):
 def _raw_frame(code: int, body) -> bytes:
     payload = bytes([code]) + msgpack.packb(body, use_bin_type=True)
     return _HDR.pack(len(payload)) + payload
+
+
+def _take_frames(buf: bytearray):
+    """Pop every whole frame off a receive buffer, decoded."""
+    while len(buf) >= 4:
+        (n,) = _HDR.unpack(buf[:4])
+        if len(buf) < 4 + n:
+            return
+        frame = bytes(buf[4:4 + n])
+        del buf[:4 + n]
+        yield decode(frame)
 
 
 def _probe(port: int, raw: bytes, timeout: float = 10.0):
@@ -916,23 +927,85 @@ def test_clockless_direct_read_sees_every_acknowledged_commit():
 # ---------------------------------------------------------------------------
 # chaos acceptance: SIGKILL under a >=1k-socket storm with seeded
 # drop/truncate faults on the native accept path — every ack made it to
-# the WAL (acked ⊆ recovered), and no connection wedges
+# the WAL (acked ⊆ recovered), and no connection wedges.  Its fault-free
+# case first holds the accept plane's own property: every one of the
+# sockets, all attached at once, is answered
 # ---------------------------------------------------------------------------
-def test_sigkill_under_socket_storm_acked_subset_recovered(tmp_path):
+def _every_socket_answered(port, n_socks, n_keys):
+    """``n_socks`` connections at once, one clockless static read at a
+    time on each until it has two answers: no socket stays silent,
+    nothing but a read reply or a typed busy comes back, and the native
+    plane, where it serves, holds them all and answers hits itself."""
+    c = AntidoteClient(port=port)
+    socks = []
+    try:
+        c.update_objects([(f"r{k}", "counter_pn", "b", ("increment", k + 1))
+                          for k in range(n_keys)])
+
+        def read_frame_for(k):
+            return _raw_frame(MessageCode.STATIC_READ_OBJECTS, {
+                "objects": [[f"r{k}", "counter_pn", "b"]], "clock": None})
+
+        sel = selectors.DefaultSelector()
+        for i in range(n_socks):
+            s = socket.create_connection(("127.0.0.1", port), timeout=30)
+            s.settimeout(None)
+            socks.append(s)
+            # state: [rxbuf, key, read replies]
+            sel.register(s, selectors.EVENT_READ, [bytearray(), i % n_keys, 0])
+        native = c.node_status()["pipeline"].get("native")
+        hits0 = 0 if native is None else native["native_hits"]
+        if native is not None:
+            assert native["open_conns"] >= n_socks, native
+        for s in socks:
+            s.sendall(read_frame_for(sel.get_key(s).data[1]))
+        unanswered = n_socks
+        deadline = time.monotonic() + 120
+        while unanswered:
+            assert time.monotonic() < deadline, \
+                f"{unanswered}/{n_socks} sockets never got two answers"
+            for sk, _ in sel.select(timeout=0.2):
+                st = sk.data
+                data = sk.fileobj.recv(1 << 16)
+                assert data, "the server closed a storm connection"
+                st[0] += data
+                for code, body in _take_frames(st[0]):
+                    if code == MessageCode.READ_OBJECTS_RESP:
+                        assert body["values"] == [st[1] + 1], body
+                        st[2] += 1
+                        if st[2] == 2:
+                            unanswered -= 1
+                            continue
+                    else:  # the admission cap against 1k closed loops
+                        assert code == MessageCode.ERROR_RESP, body
+                        assert body["error"] == "busy", body
+                        assert body["retry_after_ms"] > 0, body
+                    sk.fileobj.sendall(read_frame_for(st[1]))
+        if native is not None:
+            native = c.node_status()["pipeline"]["native"]
+            assert native["native_hits"] > hits0, native
+    finally:
+        for s in socks:
+            s.close()
+        c.close()
+
+
+@pytest.mark.parametrize("faulted", [True, False],
+                         ids=["seeded_faults", "every_socket_answered"])
+def test_sigkill_under_socket_storm_acked_subset_recovered(tmp_path, faulted):
     n_socks = 1024
     n_keys = 128  # sockets share keys: per-key acked sums stay testable
     log_dir = str(tmp_path / "wal")
-    env = dict(
-        os.environ, JAX_PLATFORMS="cpu",
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    if faulted:
         # seeded frame wreckage on the accept path for the whole run:
         # drops close conns mid-storm, truncates produce typed errors
-        ANTIDOTE_FAULT_PLAN=json.dumps({"seed": 23, "rules": [
+        env["ANTIDOTE_FAULT_PLAN"] = json.dumps({"seed": 23, "rules": [
             {"site": "frontend.recv", "action": "drop", "p": 0.002,
              "times": 64},
             {"site": "frontend.recv", "action": "truncate", "p": 0.002,
              "times": 64, "arg": 6},
-        ]}),
-    )
+        ]})
     proc = subprocess.Popen(
         [sys.executable, "-m", "antidote_tpu.console", "serve",
          "--port", "0", "--shards", "2", "--max-dcs", "2",
@@ -949,6 +1022,8 @@ def test_sigkill_under_socket_storm_acked_subset_recovered(tmp_path):
         info = json.loads(proc.stdout.readline())
         assert info["ready"] is True
         port = info["port"]
+        if not faulted:
+            _every_socket_answered(port, n_socks, n_keys)
 
         def upd_frame(key_i):
             return _raw_frame(MessageCode.STATIC_UPDATE_OBJECTS, {
@@ -987,13 +1062,7 @@ def test_sigkill_under_socket_storm_acked_subset_recovered(tmp_path):
                     st[2] = False
                     continue
                 st[0] += data
-                while len(st[0]) >= 4:
-                    (n,) = _HDR.unpack(st[0][:4])
-                    if len(st[0]) < 4 + n:
-                        break
-                    frame = bytes(st[0][4:4 + n])
-                    del st[0][:4 + n]
-                    code, body = decode(frame)
+                for code, body in _take_frames(st[0]):
                     if code != MessageCode.ERROR_RESP:
                         assert "commit_clock" in body, body
                         acked[st[1]] += 1

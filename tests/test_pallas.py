@@ -60,22 +60,6 @@ def test_counter_fold_empty_ring():
     assert np.asarray(applied).sum() == 0
 
 
-def test_stable_min_matches_numpy(rng):
-    clocks = rng.integers(0, 1000, size=(777, 5)).astype(np.int32)
-    out = pk.stable_min(clocks, block=64)
-    np.testing.assert_array_equal(np.asarray(out), clocks.min(axis=0))
-
-
-def test_stable_min_single_row():
-    clocks = np.asarray([[7, 3, 9]], np.int32)
-    np.testing.assert_array_equal(np.asarray(pk.stable_min(clocks)), [7, 3, 9])
-
-
-def test_stable_min_empty_is_identity():
-    out = np.asarray(pk.stable_min(np.zeros((0, 3), np.int32)))
-    np.testing.assert_array_equal(out, np.full(3, np.iinfo(np.int32).max))
-
-
 def test_counter_fold_overflow_guard():
     b, k, d = 2, 8, 2
     deltas = np.zeros((b, k), np.int64)

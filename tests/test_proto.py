@@ -183,7 +183,6 @@ def test_static_batch_concurrent_reads_and_updates():
                          batch_buckets=(16, 64))
     node = AntidoteNode(cfg)
     srv = ProtocolServer(node, port=0)
-    assert srv.batch_static
     try:
         n_cli, per = 8, 12
         errs = []

@@ -190,16 +190,29 @@ def test_kvstore_read_resolved_matches_read_values():
     assert int(bottom["count"]) == 0
 
 
-def test_stable_min_of_pallas_path():
+def test_stable_vc_is_the_min_of_the_applied_clocks():
     from antidote_tpu.store.kv import stable_min_of
 
-    cfg = _mk_cfg(use_pallas=True)
-    store = KVStore(cfg)
+    store = KVStore(_mk_cfg())
     store.applied_vc[:] = np.asarray([[3, 1, 9], [2, 5, 4]], np.int32)
     assert (store.stable_vc() == np.asarray([2, 1, 4])).all()
-    # the large-matrix path (multi-node aggregation) takes the kernel
+    # a large matrix (multi-node aggregation: members × shards rows)
     big = np.random.default_rng(1).integers(0, 1000, size=(4096, 3)).astype(np.int32)
-    assert (stable_min_of(big, use_pallas=True) == big.min(axis=0)).all()
+    assert (stable_min_of(big) == big.min(axis=0)).all()
+
+
+@pytest.mark.parametrize("clocks", [
+    np.random.default_rng(0).integers(0, 1000, size=(777, 5)).astype(np.int32),
+    np.asarray([[7, 3, 9]], np.int32),
+], ids=["random_777x5", "single_row"])
+def test_stable_min_of_matches_a_plain_loop(clocks):
+    from antidote_tpu.store.kv import stable_min_of
+
+    want = [min(int(row[j]) for row in clocks)
+            for j in range(clocks.shape[1])]
+    got = stable_min_of(clocks)
+    assert got.dtype == np.int32
+    assert got.tolist() == want
 
 
 def test_handoff_preserves_serving_gates():

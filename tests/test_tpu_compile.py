@@ -28,7 +28,7 @@ from antidote_tpu.materializer import pallas_kernels as pk
 from antidote_tpu.store import TypedTable
 
 #: kernel batch and the `console serve` default widths (set_slots,
-#: ops_per_key); D = max_dcs is 4 in bench.py and 8 in `console serve`
+#: ops_per_key); D = max_dcs is 8 in `console serve`; 4 is the narrow case
 ROWS, E, K = 16_384, 16, 16
 DCS = (4, 8)
 
@@ -85,11 +85,6 @@ def test_counter_fold_compiles(shape, d):
         shape((ROWS, K)), shape((ROWS, K, d)), shape((ROWS,)),
         shape((ROWS, d)), shape((ROWS, d)),
     )
-
-
-@pytest.mark.parametrize("d", DCS)
-def test_stable_min_compiles(shape, d):
-    _compile(lambda c: pk.stable_min(c, interpret=False), shape((ROWS, d)))
 
 
 @pytest.mark.parametrize("d", DCS)

@@ -515,8 +515,8 @@ def test_device_program_module_name_scope_and_donation():
     assert float(scale(jnp.ones(()), 3)) == 3.0
 
 
-@pytest.mark.parametrize("kernel", ["counter_fold", "stable_min",
-                                    "set_aw_fold", "orset_presence"])
+@pytest.mark.parametrize("kernel", ["counter_fold", "set_aw_fold",
+                                    "orset_presence"])
 def test_pallas_kernel_carries_its_name(kernel):
     import inspect
 

@@ -30,7 +30,7 @@ Checks (each one has caught a real bug class in this codebase's history):
     its applied clock silently violates causality (ISSUE 9).
 
 Usage: python tools/lint.py [paths...]   (default: antidote_tpu tests
-bench.py bench_suite.py bench_wire.py chip_smoke.py __graft_entry__.py)
+chip_smoke.py __graft_entry__.py tools)
 """
 
 from __future__ import annotations
@@ -518,8 +518,7 @@ def _check_swallow_loops(tree, path, noqa, problems) -> None:
 
 
 def main(argv):
-    paths = argv[1:] or ["antidote_tpu", "tests", "bench.py",
-                         "bench_suite.py", "bench_wire.py", "chip_smoke.py",
+    paths = argv[1:] or ["antidote_tpu", "tests", "chip_smoke.py",
                          "__graft_entry__.py", "tools"]
     all_problems = []
     n = 0
