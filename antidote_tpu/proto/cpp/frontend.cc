@@ -348,6 +348,9 @@ struct Frontend {
        st_send_frames = 0;
   // calls of frontend_send / frontend_send_many (one per reply batch)
   long st_send_calls = 0;
+  // calls of frontend_fill / frontend_fill_many, and the entries they
+  // carried (a writeback launch's gathered keys are one call)
+  long st_fill_calls = 0, st_fill_keys = 0;
   std::atomic<long> st_drains{0};
 
   std::vector<ObjSpan> scratch_objs;
@@ -852,6 +855,22 @@ bool send_locked(Frontend* f, long conn_id, const uint8_t* buf, long len,
   return work;
 }
 
+// one mirror entry, caller holds f->mu
+void fill_locked(Frontend* f, const uint8_t* key, long key_len,
+                 const uint8_t* type_frag, long type_len,
+                 const uint8_t* val, long val_len, long epoch_id) {
+  std::string k(reinterpret_cast<const char*>(key), size_t(key_len));
+  if (f->mirror.size() >= f->mirror_cap && !f->mirror.count(k)) {
+    f->mirror.erase(f->mirror.begin());  // capacity cap, arbitrary victim
+  }
+  Entry& e = f->mirror[k];
+  e.stamp = epoch_id;
+  e.type_frag.assign(reinterpret_cast<const char*>(type_frag),
+                     size_t(type_len));
+  e.val.assign(reinterpret_cast<const char*>(val), size_t(val_len));
+  ++f->st_fill_keys;
+}
+
 }  // namespace
 
 #ifndef ANTIDOTE_SRC_SHA
@@ -1024,15 +1043,25 @@ void frontend_fill(void* h, const uint8_t* key, long key_len,
                    const uint8_t* val, long val_len, long epoch_id) {
   Frontend* f = static_cast<Frontend*>(h);
   std::lock_guard<std::mutex> lk(f->mu);
-  std::string k(reinterpret_cast<const char*>(key), size_t(key_len));
-  if (f->mirror.size() >= f->mirror_cap && !f->mirror.count(k)) {
-    f->mirror.erase(f->mirror.begin());  // capacity cap, arbitrary victim
+  ++f->st_fill_calls;
+  fill_locked(f, key, key_len, type_frag, type_len, val, val_len,
+              epoch_id);
+}
+
+// a batch of fills in one crossing: `n` entries back-to-back in `buf`
+// (key, type fragment, value), 3 longs per entry in `descs` (their
+// lengths), all stamped `epoch_id`.  Per entry what frontend_fill does;
+// one mu take for all of them.
+void frontend_fill_many(void* h, long n, const long* descs,
+                        const uint8_t* buf, long epoch_id) {
+  Frontend* f = static_cast<Frontend*>(h);
+  std::lock_guard<std::mutex> lk(f->mu);
+  ++f->st_fill_calls;
+  for (long i = 0; i < n; ++i) {
+    long kl = descs[i * 3], tl = descs[i * 3 + 1], vl = descs[i * 3 + 2];
+    fill_locked(f, buf, kl, buf + kl, tl, buf + kl + tl, vl, epoch_id);
+    buf += kl + tl + vl;
   }
-  Entry& e = f->mirror[k];
-  e.stamp = epoch_id;
-  e.type_frag.assign(reinterpret_cast<const char*>(type_frag),
-                     size_t(type_len));
-  e.val.assign(reinterpret_cast<const char*>(val), size_t(val_len));
 }
 
 void frontend_invalidate(void* h, const uint8_t* key, long key_len) {
@@ -1065,18 +1094,20 @@ void frontend_set_clockless_ok(void* h, int on) {
 // stats snapshot: [accepted, closed, frames, native_hits, hit_objects,
 //                  sheds, forwarded, drains, mirror_size, in_flight,
 //                  open_conns, bad_frames, cross_wait_us, cross_frames,
-//                  send_wait_us, send_frames, send_calls]
+//                  send_wait_us, send_frames, send_calls, fill_calls,
+//                  fill_keys]
 void frontend_stats(void* h, long* out, int n) {
   Frontend* f = static_cast<Frontend*>(h);
   std::lock_guard<std::mutex> lk(f->mu);
-  long vals[17] = {f->st_accept, f->st_closed, f->st_frames, f->st_hits,
+  long vals[] = {f->st_accept, f->st_closed, f->st_frames, f->st_hits,
                    f->st_hit_objs, f->st_shed, f->st_fwd,
                    f->st_drains.load(), long(f->mirror.size()),
                    f->g_inflight, f->n_open, f->st_bad_frame,
                    f->st_cross_wait_us, f->st_cross_frames,
                    f->st_send_wait_us, f->st_send_frames,
-                   f->st_send_calls};
-  for (int i = 0; i < n && i < 17; ++i) out[i] = vals[i];
+                   f->st_send_calls, f->st_fill_calls, f->st_fill_keys};
+  const int have = int(sizeof(vals) / sizeof(vals[0]));
+  for (int i = 0; i < n && i < have; ++i) out[i] = vals[i];
 }
 
 void frontend_stop(void* h) {

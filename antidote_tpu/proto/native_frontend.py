@@ -11,8 +11,10 @@ one packed batch-drain crossing per wakeup (``take_batch`` — the
 The mirror protocol (kv.py pushes, epoch-id-stamped entries):
 
 * ``fill(key, bucket, type_name, value, epoch_id)`` — pushed wherever
-  Python itself fills/serves from the snapshot cache (kv.py
-  ``snapshot_cache_fill`` + the whole-batch bottom path);
+  Python itself fills/serves from the snapshot cache (the whole-batch
+  bottom path, a re-proved entry); ``fill_many(entries, epoch_id)`` —
+  a writeback launch's gathered keys in one call (kv.py
+  ``snapshot_cache_fill``);
 * ``invalidate(key, bucket)`` — pushed EAGERLY under the commit lock for
   every applied effect (kv.py ``_apply_effect_groups_inner``) and from
   ``drop_cached_value`` / ``mark_epoch_fallback``;
@@ -91,17 +93,27 @@ def _load_lib():
             ctypes.c_long, ctypes.c_long,
         ]
         # a second handle on the same library whose calls KEEP the GIL:
-        # send_many never blocks (it takes the front end's mutex for a
-        # few microseconds), and the stage that calls it has a batch to
-        # finish — giving the GIL up around the call would put it back
-        # in the queue for it
-        send_many = ctypes.PyDLL(str(_SO)).frontend_send_many
+        # send_many and fill_many never block (they take the front end's
+        # mutex for a few microseconds), and the stage that calls them
+        # has a batch to finish — giving the GIL up around the call
+        # would put it back in the queue for it.  Nothing that runs
+        # under that mutex calls back into Python (the io thread never
+        # needs the GIL), so holding the GIL across it cannot deadlock
+        keeping_gil = ctypes.PyDLL(str(_SO))
+        send_many = keeping_gil.frontend_send_many
         send_many.restype = None
         send_many.argtypes = [
             ctypes.c_void_p, ctypes.c_long, ctypes.POINTER(ctypes.c_long),
             ctypes.c_char_p,
         ]
         lib.send_many_keeping_gil = send_many
+        fill_many = keeping_gil.frontend_fill_many
+        fill_many.restype = None
+        fill_many.argtypes = [
+            ctypes.c_void_p, ctypes.c_long, ctypes.POINTER(ctypes.c_long),
+            ctypes.c_char_p, ctypes.c_long,
+        ]
+        lib.fill_many_keeping_gil = fill_many
         lib.frontend_close_conn.restype = None
         lib.frontend_close_conn.argtypes = [ctypes.c_void_p, ctypes.c_long]
         lib.frontend_advance.restype = None
@@ -163,12 +175,14 @@ class NativeFrontend:
     #: taken by Python, over admitted frames; send_wait_us/send_frames:
     #: ``send`` -> the reply's last byte written to the socket;
     #: send_calls: ``send`` + ``send_many`` calls (a writeback batch's
-    #: replies are one)
+    #: replies are one); fill_calls: ``fill`` + ``fill_many`` calls (a
+    #: writeback launch's gathered keys are one), fill_keys: the entries
+    #: they carried
     STAT_FIELDS = ("accepted", "closed", "frames", "native_hits",
                    "hit_objects", "sheds", "forwarded", "drains",
                    "mirror_size", "in_flight", "open_conns", "bad_frames",
                    "cross_wait_us", "cross_frames", "send_wait_us",
-                   "send_frames", "send_calls")
+                   "send_frames", "send_calls", "fill_calls", "fill_keys")
     #: longs per frame in the take_batch descriptor
     _DESC = 5
 
@@ -267,13 +281,13 @@ class NativeFrontend:
         except Exception:
             return None  # unpackable key shapes are simply never mirrored
 
-    def fill(self, key, bucket, type_name: str, value, epoch_id: int):
-        h = self._h
-        if h is None:
-            return
-        k = self._mirror_key(key, bucket)
+    @classmethod
+    def _mirror_entry(cls, key, bucket, type_name: str, value):
+        """(key, type fragment, value) as the mirror stores them, or None
+        for a key or value that does not pack (never mirrored)."""
+        k = cls._mirror_key(key, bucket)
         if k is None:
-            return
+            return None
         try:
             # the SAME wire shape the Python reply path produces
             # (tuple-keyed CRDT maps ride as tagged pair lists) — the
@@ -281,10 +295,40 @@ class NativeFrontend:
             # not v
             val = _packb(encode_value(value))
         except Exception:
+            return None
+        return k, _packb(type_name), val
+
+    def fill(self, key, bucket, type_name: str, value, epoch_id: int):
+        h = self._h
+        if h is None:
             return
-        t = _packb(type_name)
+        ent = self._mirror_entry(key, bucket, type_name, value)
+        if ent is None:
+            return
+        k, t, val = ent
         self._lib.frontend_fill(h, k, len(k), t, len(t), val,
                                 len(val), int(epoch_id))
+
+    def fill_many(self, entries, epoch_id: int) -> None:
+        """``fill`` for a batch — ``[(key, bucket, type_name, value)]``,
+        all at ``epoch_id`` — in ONE native call: one lock take for all
+        of them, the GIL kept.  An entry that does not pack is skipped
+        alone."""
+        h = self._h
+        if h is None:
+            return
+        lens = []
+        frags = []
+        for key, bucket, type_name, value in entries:
+            ent = self._mirror_entry(key, bucket, type_name, value)
+            if ent is not None:
+                lens += map(len, ent)
+                frags += ent
+        if not frags:
+            return
+        descs = (ctypes.c_long * len(lens))(*lens)
+        self._lib.fill_many_keeping_gil(h, len(lens) // 3, descs,
+                                        b"".join(frags), int(epoch_id))
 
     def invalidate(self, key, bucket) -> None:
         h = self._h

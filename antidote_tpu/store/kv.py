@@ -1129,16 +1129,28 @@ class KVStore:
             m.snapshot_cache.inc(event="miss")
         return _CACHE_MISS
 
-    def snapshot_cache_fill(self, dk, ep: "ServingEpoch", loc, value) -> None:
-        with self._snapshot_cache_lock:
-            self.snapshot_cache[dk] = (ep.id, loc, _copy_out(value))
-            while len(self.snapshot_cache) > self.snapshot_cache_cap:
-                self.snapshot_cache.popitem(last=False)
-                if self.metrics is not None:
-                    self.metrics.snapshot_cache.inc(event="evict")
-        nm = self.native_mirror
-        if nm is not None:
-            nm.fill(dk[0], dk[1], split_tier(loc[0])[0], value, ep.id)
+    def snapshot_cache_fill(self, ep: "ServingEpoch", tname: str,
+                            filled) -> None:
+        """Back-fill one launch's gathered keys — ``[(dk, shard, row,
+        value)]`` of table ``tname`` — at epoch ``ep``: one take of the
+        cache lock for every insert and the eviction, then one native
+        call for the mirror (a launch of one key is a batch of one)."""
+        with span("serve.wb_host.fill", table=tname, rows=len(filled)):
+            cache = self.snapshot_cache
+            with self._snapshot_cache_lock:
+                for dk, shard, row, value in filled:
+                    cache[dk] = (ep.id, (tname, shard, row),
+                                 _copy_out(value))
+                evicted = len(cache) - self.snapshot_cache_cap
+                for _ in range(evicted):
+                    cache.popitem(last=False)
+            if evicted > 0 and self.metrics is not None:
+                self.metrics.snapshot_cache.inc(evicted, event="evict")
+            nm = self.native_mirror
+            if nm is not None:
+                type_name = split_tier(tname)[0]
+                nm.fill_many([(dk[0], dk[1], type_name, value)
+                              for dk, _s, _r, value in filled], ep.id)
 
     def _bottom_value(self, type_name: str):
         """Decoded client-visible value of a never-written key."""
@@ -1276,6 +1288,7 @@ class KVStore:
             has_resolve = ty.resolve_spec(t.cfg) is not None
             slot = ep.tables[tname_t]
             with span("serve.wb_host", table=tname_t, rows=len(items)):
+                filled = []
                 for j, (i, shard, row) in enumerate(items):
                     if pos is not None:
                         view = {f: x[pos[j, 0], pos[j, 1]]
@@ -1296,8 +1309,11 @@ class KVStore:
                         v = ty.value(view, self.blobs, t.cfg)
                     vals[i] = v
                     key, _tn, bucket = pending.objects[i]
-                    self.snapshot_cache_fill((key, bucket), ep,
-                                             (tname_t, shard, row), v)
+                    filled.append(((key, bucket), shard, row, v))
+                # after the launch's last decode and before any reply of
+                # the batch: a client that has its answer finds the key
+                # in the mirror, at an epoch that is still pinned
+                self.snapshot_cache_fill(ep, tname_t, filled)
         return vals
 
     # ------------------------------------------------------------------

@@ -43,3 +43,85 @@ def cfg():
         set_slots=8, mv_slots=4, rga_slots=16, keys_per_table=64,
         batch_buckets=(16, 64),
     )
+
+
+class RecordingMirror:
+    """Stand-in for ``KVStore.native_mirror``: records the fills the store
+    pushes (and whether the serving epoch was pinned then)."""
+
+    def __init__(self, store, log=None):
+        self.store = store
+        self.log = [] if log is None else log
+        self.many = []      # (entries, epoch_id, pins) per fill_many call
+        self.single = []    # (key, bucket, type_name, value, epoch_id)
+
+    def fill_many(self, entries, epoch_id):
+        ep = self.store.serving_epoch
+        self.many.append((list(entries), epoch_id,
+                          ep.pins if ep is not None else 0))
+        self.log.append("fill")
+
+    def fill(self, *args):
+        self.single.append(args)
+
+    def invalidate(self, key, bucket):
+        pass
+
+    def reset(self):
+        pass
+
+
+def check_writeback_fill(store, objs, mirror: bool, cap: int = 5):
+    """One cold epoch read of ``objs`` through launch + finish (what the
+    dispatcher and the writeback stage do with a batch): one mirror fill
+    per launch, the snapshot cache and its evict counter as one fill per
+    key in launch order leaves them, alike with no mirror at all."""
+    from collections import OrderedDict
+
+    rec = RecordingMirror(store) if mirror else None
+    store.native_mirror = rec
+    store.snapshot_cache_cap = cap
+    evict0 = store.metrics.snapshot_cache.value(event="evict")
+    ep = store.pin_serving_epoch()
+    assert ep is not None
+    try:
+        pending, fallback = store.epoch_read_launch(objs, ep)
+        assert not fallback, fallback
+        assert pending.gathered == set(range(len(objs))), "read not cold"
+        # the model: one fill, one eviction loop per key
+        model = OrderedDict(
+            (dk, ent[0]) for dk, ent in store.snapshot_cache.items())
+        evicted = 0
+        for _tn, items, *_rest in pending.launches:
+            for i, _shard, _row in items:
+                model[(objs[i][0], objs[i][2])] = ep.id
+                while len(model) > cap:
+                    model.popitem(last=False)
+                    evicted += 1
+        vals = store.epoch_read_finish(pending)
+    finally:
+        store.unpin_serving_epoch(ep)
+    assert [(dk, ent[0]) for dk, ent in store.snapshot_cache.items()] \
+        == list(model.items())
+    assert evicted == len(objs) - cap > 0
+    assert store.metrics.snapshot_cache.value(event="evict") - evict0 \
+        == evicted
+    for dk, (_eid, loc, value) in store.snapshot_cache.items():
+        i = next(j for j, o in enumerate(objs) if (o[0], o[2]) == dk)
+        assert value == vals[i]
+        assert store.directory[dk] == loc
+    if rec is not None:
+        assert not rec.single
+        assert len(rec.many) == len(pending.launches)
+        filled = {}
+        for (tn, items, *_rest), (entries, eid, pins) in zip(
+                pending.launches, rec.many):
+            assert eid == ep.id and pins >= 1
+            assert [(k, b) for k, b, _t, _v in entries] == [
+                (objs[i][0], objs[i][2]) for i, _s, _r in items]
+            for k, b, t, v in entries:
+                assert tn.startswith(t)
+                filled[(k, b, t)] = v
+        assert filled == {(o[0], o[2], o[1]): v
+                          for o, v in zip(objs, vals)}
+    return vals

@@ -85,3 +85,36 @@ def test_every_path_and_make_target_a_document_names_exists(doc):
                  for t in _MAKE.findall(span)}
     gone = sorted(asked - _make_targets())
     assert not gone, f"{doc} names make targets the Makefile lacks: {gone}"
+
+
+def _operations_guide():
+    with open(os.path.join(ROOT, "docs/operations.md")) as f:
+        return f.read()
+
+
+def test_operations_guide_lists_every_host_span():
+    """Every ``obs.trace.span`` name the program opens is in the
+    operations guide's list ("Tracing a live node"): a trace shows no
+    span the guide does not explain."""
+    rx = re.compile(r"""\bspan\(\s*["']([a-z_.]+)["']""")
+    names = set()
+    for base, _dirs, files in os.walk(os.path.join(ROOT, "antidote_tpu")):
+        for n in files:
+            if n.endswith(".py"):
+                with open(os.path.join(base, n)) as f:
+                    names |= set(rx.findall(f.read()))
+    assert {"serve.launch", "serve.wb_host.fill", "commit.group"} <= names
+    listed = set(_CODE.findall(_operations_guide()))
+    missing = sorted(n for n in names if f"`{n}`" not in listed)
+    assert not missing, missing
+
+
+def test_operations_guide_names_every_native_stat():
+    """Every counter of the status block's ``native`` section is named
+    in the operations guide."""
+    from antidote_tpu.proto.native_frontend import NativeFrontend
+
+    text = _operations_guide()
+    missing = [f for f in NativeFrontend.STAT_FIELDS
+               if not re.search(rf"\b{f}\b", text)]
+    assert not missing, missing

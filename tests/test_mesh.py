@@ -152,6 +152,30 @@ def test_degenerate_1_device_mesh():
     assert plane.status()["shards_per_device"] == MESH_CFG.n_shards
 
 
+@pytest.mark.parametrize("mirror", [True, False],
+                         ids=["recording_mirror", "no_mirror"])
+def test_routed_writeback_fills_cache_and_mirror_once_a_launch(mirror):
+    """The routed (``pos``) branch of ``epoch_read_finish``: a launch's
+    gathered keys reach cache and mirror in one fill, with the values
+    the one-chip plane reads."""
+    from conftest import check_writeback_fill
+
+    chip, _ = _mk_node()
+    node, _plane = _mk_node(mesh_devices=4)
+    for n in (chip, node):
+        _apply_workload(n)
+        n.txm.publish_serving_epoch()
+    _, _, cvals = _epoch_read(chip.store, _WORKLOAD_OBJS)
+    ep = node.store.pin_serving_epoch()
+    try:
+        pending, _fb = node.store.epoch_read_launch(_WORKLOAD_OBJS[:1], ep)
+        assert pending.launches[0][4] is not None, "launch was not routed"
+    finally:
+        node.store.unpin_serving_epoch(ep)
+    vals = check_writeback_fill(node.store, _WORKLOAD_OBJS, mirror)
+    assert _wire_bytes(vals, [0]) == _wire_bytes(cvals, [0])
+
+
 def test_mesh_rejects_indivisible_device_count():
     with pytest.raises(ValueError):
         MeshServingPlane(MESH_CFG, 3)  # 8 % 3 != 0

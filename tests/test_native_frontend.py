@@ -568,21 +568,17 @@ def test_direct_reads_create_no_connection_thread_and_count():
         srv_p.close()
 
 
-def test_direct_share_metric_file_reads_the_counter():
-    """`frontend.direct_share`: its file agrees with its BENCHMARK.json
-    entry, reads the share off two node statuses, and reads nothing —
-    without raising — off a program that has no such counter."""
+def _metric_file_and_ctx(name):
+    """A status_delta metric's file, held to its BENCHMARK.json entry,
+    and a maker of reader contexts from two ``pipeline`` status blocks."""
     from types import SimpleNamespace
-
-    from benchmarks.readers import status_delta
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmarks", "layer_metrics",
-                           "frontend.direct_share.json")) as f:
+                           name + ".json")) as f:
         spec = json.load(f)
     with open(os.path.join(root, "BENCHMARK.json")) as f:
-        entry = [m for m in json.load(f)["per_layer"]
-                 if m["name"] == "frontend.direct_share"]
+        entry = [m for m in json.load(f)["per_layer"] if m["name"] == name]
     assert len(entry) == 1 and entry[0]["source"] == "program_counter"
     for key in ("unit", "better", "layer", "moves", "workloads"):
         assert spec[key] == entry[0][key], key
@@ -591,6 +587,16 @@ def test_direct_share_metric_file_reads_the_counter():
         return SimpleNamespace(status={"window": (
             {"pipeline": pre}, {"pipeline": post})})
 
+    return spec, ctx
+
+
+def test_direct_share_metric_file_reads_the_counter():
+    """`frontend.direct_share`: its file agrees with its BENCHMARK.json
+    entry, reads the share off two node statuses, and reads nothing —
+    without raising — off a program that has no such counter."""
+    from benchmarks.readers import status_delta
+
+    spec, ctx = _metric_file_and_ctx("frontend.direct_share")
     assert status_delta.read(spec, ctx(
         {"direct": {"served": 10, "worker": 30}},
         {"direct": {"served": 1000, "worker": 40}})) == pytest.approx(99.0)
@@ -599,6 +605,32 @@ def test_direct_share_metric_file_reads_the_counter():
         {"direct": {"served": 7, "worker": 1}},
         {"direct": {"served": 7, "worker": 1}})) is None
     assert status_delta.read(spec, ctx({}, {})) is None
+
+
+def test_mirror_fill_keys_metric_file_reads_the_counters():
+    """`store.mirror_fill_keys`: keys per native fill call over the
+    window, off the counters a served node's status really carries;
+    nothing, without raising, off the parent's status (no such pair)."""
+    from benchmarks.readers import status_delta
+
+    spec, ctx = _metric_file_and_ctx("store.mirror_fill_keys")
+    assert status_delta.read(spec, ctx(
+        {"native": {"fill_keys": 100, "fill_calls": 90}},
+        {"native": {"fill_keys": 2100, "fill_calls": 190}})) \
+        == pytest.approx(20.0)
+    assert status_delta.read(spec, ctx(
+        {"native": {"fill_keys": 5, "fill_calls": 5}},
+        {"native": {"fill_keys": 5, "fill_calls": 5}})) is None
+    parent = {"native": {"send_calls": 3, "cross_frames": 9}}
+    assert status_delta.read(spec, ctx(parent, parent)) is None
+    assert status_delta.read(spec, ctx({}, {})) is None
+    node, srv = _boot(True)
+    try:
+        native = srv._pipeline_status()["native"]
+        for term in spec["num"] + spec["den"]:
+            assert term["path"].split(".")[-1] in native, term
+    finally:
+        srv.close()
 
 
 def test_pipelined_connection_gets_replies_in_request_order():
@@ -811,6 +843,200 @@ def test_send_many_is_n_sends():
               "cross_frames", "sheds", "open_conns"):
         assert st_m[k] == st_s[k], k
     assert st_m["in_flight"] == 0
+
+
+def _bare_frontend(mirror_cap=None):
+    """A front end with no server behind it (its mirror is filled by
+    hand); ``mirror_cap`` shrinks the mirror for the eviction case."""
+    from antidote_tpu.proto import native_frontend as nfm
+
+    if mirror_cap is None:
+        nf = nfm.NativeFrontend.create("127.0.0.1", 0, 64, 32, 32)
+    else:
+        lib = nfm._load_lib()
+        h = lib and lib.frontend_create(b"127.0.0.1", 0, 64, 32, 32,
+                                        mirror_cap)
+        nf = nfm.NativeFrontend(lib, h) if h else None
+    if nf is None:
+        pytest.skip("native frontend unavailable (no g++/epoll)")
+    return nf
+
+
+def test_fill_many_is_n_fills():
+    """frontend_fill_many of N entries against N frontend_fill calls: the
+    mirror ends the same — every key a native hit whose reply is the
+    Python plane's, byte for byte — an entry that does not pack is
+    skipped alone, and the counters say one call, N keys."""
+    from antidote_tpu.proto.codec import encode, encode_value
+
+    epoch, vc = 7, [3, 0]
+    entries = [
+        ("fk0", "b", "counter_pn", 41),
+        ("fk1", "b", "set_aw", ["a", "b", "c"]),
+        (object(), "b", "counter_pn", 1),         # key does not pack
+        ("fk2", "b2", "register_lww", "v"),
+        ("fk3", "b", "counter_pn", object()),     # value does not pack
+        ("fk4", "b", "map_rr", {("f", "counter_pn"): 2}),
+        (("t", 1), "b", "counter_pn", -5),        # a tuple key packs
+    ]
+    good = [e for e in entries if e[0].__class__ is not object
+            and e[3].__class__ is not object]
+    assert len(good) == len(entries) - 2
+
+    def run(many: bool):
+        nf = _bare_frontend()
+        s = None
+        try:
+            nf.advance(epoch, vc, True)
+            st0 = nf.stats()
+            if many:
+                nf.fill_many(entries, epoch)
+            else:
+                for key, bucket, tn, v in entries:
+                    nf.fill(key, bucket, tn, v, epoch)
+            st1 = nf.stats()
+            s = socket.create_connection(("127.0.0.1", nf.port),
+                                         timeout=10)
+            s.settimeout(10)
+            got = []
+            for key, bucket, tn, _v in good:
+                s.sendall(_raw_frame(MessageCode.STATIC_READ_OBJECTS, {
+                    "objects": [[key, tn, bucket]], "clock": None}))
+                got.append(read_frame(s))
+            st2 = nf.stats()
+            assert st2["native_hits"] - st1["native_hits"] == len(good)
+            assert st2["forwarded"] == 0
+            return got, {k: st1[k] - st0[k] for k in st1}
+        finally:
+            if s is not None:
+                s.close()
+            nf.close()
+
+    got_m, d_m = run(True)
+    got_s, d_s = run(False)
+    assert got_m == got_s
+    for (key, bucket, tn, v), frame in zip(good, got_m):
+        assert frame == encode(MessageCode.READ_OBJECTS_RESP, {
+            "values": [encode_value(v)], "commit_clock": vc})[4:], key
+    n = len(good)
+    assert (d_m["fill_calls"], d_m["fill_keys"]) == (1, n)
+    assert (d_s["fill_calls"], d_s["fill_keys"]) == (n, n)
+    assert d_m["mirror_size"] == d_s["mirror_size"] == n
+
+
+def test_fill_many_nothing_packs_makes_no_call():
+    nf = _bare_frontend()
+    try:
+        nf.advance(1, [0, 0], True)
+        nf.fill_many([(object(), "b", "counter_pn", 1)], 1)
+        nf.fill_many([], 1)
+        st = nf.stats()
+        assert (st["fill_calls"], st["fill_keys"], st["mirror_size"]) \
+            == (0, 0, 0)
+    finally:
+        nf.close()
+
+
+def test_fill_many_is_one_step_under_hits_and_other_fillers():
+    """Stress, time-bounded: two threads fill the same 16 keys batch by
+    batch (a batch carries one value) while two connections read the
+    first and the last key in one request.  A batch goes in under one
+    take of the front end's mutex and a hit is built under it, so both
+    values of every reply come from one batch; the calls keep the GIL
+    across that mutex and nothing hangs."""
+    import sys
+    import threading
+
+    nf = _bare_frontend()
+    keys = [f"sk{i}" for i in range(16)]
+    stop = threading.Event()
+    errors = []
+    replies = [0, 0]
+
+    def filler(base):
+        n = base
+        while not stop.is_set():
+            nf.fill_many([(k, "b", "counter_pn", n) for k in keys], 3)
+            n += 2
+
+    def reader(slot):
+        try:
+            s = socket.create_connection(("127.0.0.1", nf.port), timeout=10)
+            s.settimeout(10)
+            req = _raw_frame(MessageCode.STATIC_READ_OBJECTS, {
+                "objects": [[keys[0], "counter_pn", "b"],
+                            [keys[-1], "counter_pn", "b"]], "clock": None})
+            try:
+                while not stop.is_set():
+                    s.sendall(req)
+                    a, b = decode(read_frame(s))[1]["values"]
+                    if a != b:
+                        errors.append((a, b))
+                    replies[slot] += 1
+            finally:
+                s.close()
+        except BaseException as e:  # noqa: BLE001 — the test reads it
+            errors.append(e)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        nf.advance(3, [1, 0], True)
+        nf.fill_many([(k, "b", "counter_pn", 0) for k in keys], 3)
+        threads = [threading.Thread(target=filler, args=(i,), daemon=True)
+                   for i in (1, 2)]
+        threads += [threading.Thread(target=reader, args=(i,), daemon=True)
+                    for i in (0, 1)]
+        for t in threads:
+            t.start()
+        time.sleep(1.5)
+        stop.set()
+        for t in threads:
+            t.join(10)
+            assert not t.is_alive()
+        st = nf.stats()
+    finally:
+        sys.setswitchinterval(interval)
+        nf.close()
+    assert not errors, errors[:3]
+    assert min(replies) > 50
+    assert st["forwarded"] == 0 and st["native_hits"] == sum(replies)
+    assert st["fill_keys"] == 16 * st["fill_calls"]
+    assert st["mirror_size"] == 16
+
+
+@pytest.mark.parametrize("many", [True, False], ids=["fill_many", "fill"])
+def test_mirror_cap_evicts_on_fill(many):
+    """Past the cap every new key evicts one entry (an arbitrary one);
+    a key the mirror already holds is rewritten in place."""
+    cap = 4
+    nf = _bare_frontend(mirror_cap=cap)
+    try:
+        nf.advance(2, [0, 0], True)
+        entries = [(f"ck{i}", "b", "counter_pn", i) for i in range(10)]
+        # a rewrite of a held key at the cap evicts nothing
+        tail = [("ck9", "b", "counter_pn", 99)]
+        if many:
+            nf.fill_many(entries, 2)
+            nf.fill_many(tail, 2)
+        else:
+            for key, bucket, tn, v in entries + tail:
+                nf.fill(key, bucket, tn, v, 2)
+        st = nf.stats()
+        assert st["mirror_size"] == cap
+        assert st["fill_keys"] == 11
+        assert st["fill_calls"] == (2 if many else 11)
+        s = socket.create_connection(("127.0.0.1", nf.port), timeout=10)
+        s.settimeout(10)
+        try:
+            # the last key filled is held, with its rewritten value
+            s.sendall(_raw_frame(MessageCode.STATIC_READ_OBJECTS, {
+                "objects": [["ck9", "counter_pn", "b"]], "clock": None}))
+            assert decode(read_frame(s))[1]["values"] == [99]
+        finally:
+            s.close()
+    finally:
+        nf.close()
 
 
 def test_direct_read_rerouted_to_the_locked_plane_is_answered():

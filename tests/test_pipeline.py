@@ -791,3 +791,101 @@ def test_close_many_folds_like_one_close_each():
     assert a["paths"]["gather"]["total"]["count"] == 2
     assert a["paths"]["gather"]["reply"]["sum_ms"] == pytest.approx(150.0)
     assert len(a["slow_requests"]) == len(recs)
+
+
+# ---------------------------------------------------------------------------
+# the writeback stage fills cache and mirror once a launch (ISSUE 30)
+# ---------------------------------------------------------------------------
+_FILL_OBJS = ([(f"k{i}", "counter_pn", "b") for i in range(8)]
+              + [(f"s{i}", "set_aw", "b") for i in range(3)])
+
+
+@pytest.mark.parametrize("mirror", [True, False],
+                         ids=["recording_mirror", "no_mirror"])
+def test_writeback_fills_cache_and_mirror_once_a_launch(mirror):
+    from conftest import check_writeback_fill
+
+    node = AntidoteNode(AntidoteConfig(
+        n_shards=4, max_dcs=2, keys_per_table=256))
+    for i in range(8):
+        node.update_objects(
+            [(f"k{i}", "counter_pn", "b", ("increment", i + 1))])
+    for i in range(3):
+        node.update_objects([(f"s{i}", "set_aw", "b", ("add", f"e{i}"))])
+    node.txm.publish_serving_epoch()
+    vals = check_writeback_fill(node.store, _FILL_OBJS, mirror)
+    assert vals[:8] == list(range(1, 9))
+    assert [sorted(v) for v in vals[8:]] == [["e0"], ["e1"], ["e2"]]
+    # every key is now a cache hit at that epoch: nothing left to gather
+    ep = node.store.pin_serving_epoch()
+    try:
+        pending, _fb = node.store.epoch_read_launch(_FILL_OBJS[-5:], ep)
+        assert not pending.launches
+    finally:
+        node.store.unpin_serving_epoch(ep)
+
+
+def test_served_batch_fills_before_its_first_reply():
+    """Through the server: a batch of K gathered reads makes one mirror
+    fill, inside ``epoch_read_finish`` (so before any reply of the batch
+    and before the epoch is unpinned)."""
+    from conftest import RecordingMirror
+
+    k = 6
+    p = _Pipeline(k)
+    try:
+        log = []
+        rec = RecordingMirror(p.store, log)
+        p.store.native_mirror = rec
+        finish = p.store.epoch_read_finish
+
+        def logged_finish(pending):
+            try:
+                return finish(pending)
+            finally:
+                log.append("finished")
+
+        p.store.epoch_read_finish = logged_finish
+        launches0 = p.status()["stages"]["launch"]["count"]
+        results = {}
+
+        def read(i):
+            results[i] = p.srv.static_read(
+                [(f"k{i}", "counter_pn", "b")], None)[0]
+            log.append("reply")
+
+        p.gate.clear()
+        threads = [threading.Thread(target=read, args=(i,), daemon=True)
+                   for i in range(k)]
+        threads[0].start()
+        p.wait(lambda: p.srv._wb_unfinished == 1)
+        for t in threads[1:]:
+            t.start()
+        p.wait(lambda: p.srv._wb_unfinished == DEPTH)
+        time.sleep(0.05)
+        p.gate.set()
+        for t in threads:
+            t.join(10)
+            assert not t.is_alive()
+        assert results == {i: [i + 1] for i in range(k)}
+        n_launch = p.status()["stages"]["launch"]["count"] - launches0
+        assert len(rec.many) == n_launch and not rec.single
+        assert sum(len(e) for e, _id, _p in rec.many) == k
+        assert all(pins >= 1 for _e, _id, pins in rec.many)
+        # a launch's fill, then its finish returns, then its replies: no
+        # read is answered before the fill that holds its key is done
+        assert [x for x in log if x != "reply"] \
+            == ["fill", "finished"] * n_launch
+        filled = replied = 0
+        batches = iter(rec.many)
+        for x in log:
+            if x == "finished":
+                filled += len(next(batches)[0])
+            elif x == "reply":
+                replied += 1
+                assert replied <= filled, log
+        assert replied == k
+        assert set(p.store.snapshot_cache) >= {
+            (f"k{i}", "b") for i in range(k)}
+    finally:
+        p.close()
