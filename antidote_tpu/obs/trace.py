@@ -24,7 +24,7 @@ from __future__ import annotations
 import functools
 import heapq
 import threading
-from typing import Dict, List, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 
@@ -194,10 +194,12 @@ class StageAccumulator:
 COMMIT_PHASES = ("certify", "wal_append", "scatter", "fsync_wait",
                  "listeners", "publish")
 #: reported beside them: ``freeze`` is the freeze_serving dispatch inside
-#: ``publish``; ``stage`` and ``ack`` are the wire server's merge point
-#: before the lock (a transaction started and its updates turned into
-#: effects, per member) and after it (results fanned back out)
-EXTRA_PHASES = ("freeze", "stage", "ack")
+#: ``publish``; ``mirror_invalidate`` the invalidation of the group's keys
+#: in the native front end's mirror, inside ``certify`` (counted only on a
+#: node that has the mirror); ``stage`` and ``ack`` are the wire server's
+#: merge point before the lock (a transaction started and its updates
+#: turned into effects, per member) and after it (results fanned back out)
+EXTRA_PHASES = ("freeze", "mirror_invalidate", "stage", "ack")
 
 
 class PhaseAccumulator:
@@ -208,10 +210,12 @@ class PhaseAccumulator:
         self._sums = {p: 0.0 for p in COMMIT_PHASES + EXTRA_PHASES}
         self._counts = {p: 0 for p in COMMIT_PHASES + EXTRA_PHASES}
 
-    def add_group(self, stamps: Sequence[float], freeze_s: float) -> None:
+    def add_group(self, stamps: Sequence[float], freeze_s: float,
+                  mirror_invalidate_s: Optional[float] = None) -> None:
         """``stamps``: lock taken, then the end of each of
         :data:`COMMIT_PHASES` (a phase the group skipped repeats the stamp
-        before it, so the phases always sum to last − first)."""
+        before it, so the phases always sum to last − first);
+        ``mirror_invalidate_s``: None when the group told no mirror."""
         with self._lock:
             prev = stamps[0]
             for p, t in zip(COMMIT_PHASES, stamps[1:]):
@@ -220,6 +224,9 @@ class PhaseAccumulator:
                 prev = t
             self._sums["freeze"] += freeze_s
             self._counts["freeze"] += 1
+            if mirror_invalidate_s is not None:
+                self._sums["mirror_invalidate"] += mirror_invalidate_s
+                self._counts["mirror_invalidate"] += 1
 
     def add(self, phase: str, seconds: float) -> None:
         with self._lock:

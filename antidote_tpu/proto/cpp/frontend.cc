@@ -8,10 +8,10 @@
 // connection's read buffer, 4-byte big-endian length framing, a minimal
 // msgpack scan of STATIC_READ_OBJECTS bodies, the admission gate
 // (global + per-peer-host in-flight caps, the overload.py semantics),
-// and a mirror of the hot-key snapshot cache (epoch-id-stamped entries
-// pushed down from Python at writeback/publish time).  A clockless read
-// whose every object resolves from the mirror at the current serving
-// epoch is answered entirely here — byte-identical to the Python
+// and a mirror of the hot-key snapshot cache (entries pushed down from
+// Python at writeback time, kept coherent by the one rule stated at
+// `struct Frontend`).  A clockless read whose every object resolves
+// from the mirror is answered entirely here — byte-identical to the Python
 // fast path (proto/server.py _try_cache_read) — and Python only ever
 // sees cache misses, writes, interactive txns and foreign-dialect
 // frames via one packed batch-drain crossing (frontend_take_batch, one
@@ -40,6 +40,7 @@
 #include <string>
 #include <thread>
 #include <unordered_map>
+#include <unordered_set>
 #include <vector>
 
 namespace {
@@ -303,7 +304,6 @@ struct ObjSpan {
 };
 
 struct Entry {
-  long stamp;
   std::string type_frag;  // packed type-name str fragment
   std::string val;        // packed encode_value(v) fragment
 };
@@ -332,8 +332,37 @@ struct Frontend {
   long shed_streak = 0;
   std::unordered_map<std::string, long> host_inflight;
 
+  // The mirror, and the ONE rule that keeps it coherent under writes.
+  //
+  // Invariant: the mirror never holds a value that was read before an
+  // invalidation of its key.  So every entry is the key's value in the
+  // epoch being served and in every later one until its key is next
+  // invalidated, and the io thread may answer from any entry it finds.
+  //
+  // What keeps it, all of it in three functions below:
+  //  * invalidate_locked erases the key's entry AND marks the key in
+  //    `dead`, entry or no entry (a bottom that Python is about to teach
+  //    has none);
+  //  * fill_locked, which every fill passes through, takes an entry only
+  //    if it is stamped with `cur_epoch` and its key is not marked.  A
+  //    value gathered (or a bottom, or a re-proved cache entry, read) at
+  //    epoch E and pushed after a commit invalidated its key is refused:
+  //    it arrives either while E is still served, and finds the mark, or
+  //    after the advance, with another stamp than the one served;
+  //  * frontend_advance moves `cur_epoch` and drops the marks: no fill
+  //    stamped with an earlier epoch is taken any more, and a value read
+  //    at the new epoch was read after everything applied before it.
+  // It rests on one thing the callers hold to (store/kv.py, txn/
+  // manager.py): invalidations and advances happen under the commit lock,
+  // the advance to E in the critical section that publishes E — so an
+  // invalidation always finds the mirror at the epoch Python serves.
+  // A read the mirror cannot answer is a miss: it crosses to Python.
   std::unordered_map<std::string, Entry> mirror;
   size_t mirror_cap = 1u << 18;
+  // keys invalidated while cur_epoch was served (bounded: dropped at every
+  // advance; past mirror_cap of them `dead_all` refuses every fill instead)
+  std::unordered_set<std::string> dead;
+  bool dead_all = false;
   long cur_epoch = -1;
   bool clockless_ok = false;
   bool fast_serve = true;
@@ -351,6 +380,10 @@ struct Frontend {
   // calls of frontend_fill / frontend_fill_many, and the entries they
   // carried (a writeback launch's gathered keys are one call)
   long st_fill_calls = 0, st_fill_keys = 0;
+  // of those entries, the ones the rule above turned away
+  long st_fill_refused = 0;
+  // calls of frontend_invalidate_many, and the keys they named
+  long st_inval_calls = 0, st_inval_keys = 0;
   std::atomic<long> st_drains{0};
 
   std::vector<ObjSpan> scratch_objs;
@@ -609,7 +642,7 @@ void on_frame(Frontend* f, std::unique_lock<std::mutex>& lk, long cid,
       k.assign(reinterpret_cast<const char*>(o.key_b), o.key_n);
       k.append(reinterpret_cast<const char*>(o.buck_b), o.buck_n);
       auto e = f->mirror.find(k);
-      if (e == f->mirror.end() || e->second.stamp != f->cur_epoch ||
+      if (e == f->mirror.end() ||
           e->second.type_frag.size() != o.type_n ||
           memcmp(e->second.type_frag.data(), o.type_b, o.type_n) != 0) {
         all = false;
@@ -855,20 +888,39 @@ bool send_locked(Frontend* f, long conn_id, const uint8_t* buf, long len,
   return work;
 }
 
-// one mirror entry, caller holds f->mu
+// one mirror entry, caller holds f->mu.  Every fill passes through here,
+// and here is the rule (see `struct Frontend`): only a value read at the
+// epoch being served, of a key not invalidated in it, is taken.
 void fill_locked(Frontend* f, const uint8_t* key, long key_len,
                  const uint8_t* type_frag, long type_len,
                  const uint8_t* val, long val_len, long epoch_id) {
+  ++f->st_fill_keys;
   std::string k(reinterpret_cast<const char*>(key), size_t(key_len));
+  if (epoch_id != f->cur_epoch || f->dead_all || f->dead.count(k)) {
+    ++f->st_fill_refused;
+    return;
+  }
   if (f->mirror.size() >= f->mirror_cap && !f->mirror.count(k)) {
     f->mirror.erase(f->mirror.begin());  // capacity cap, arbitrary victim
   }
   Entry& e = f->mirror[k];
-  e.stamp = epoch_id;
   e.type_frag.assign(reinterpret_cast<const char*>(type_frag),
                      size_t(type_len));
   e.val.assign(reinterpret_cast<const char*>(val), size_t(val_len));
-  ++f->st_fill_keys;
+}
+
+// one invalidated key, caller holds f->mu: the entry goes and the key is
+// marked, so that no value read before this call comes (back) in
+void invalidate_locked(Frontend* f, const uint8_t* key, long key_len) {
+  std::string k(reinterpret_cast<const char*>(key), size_t(key_len));
+  f->mirror.erase(k);
+  if (f->dead_all) return;
+  if (f->dead.size() >= f->mirror_cap) {
+    f->dead.clear();
+    f->dead_all = true;
+  } else {
+    f->dead.insert(std::move(k));
+  }
 }
 
 }  // namespace
@@ -1012,26 +1064,19 @@ void frontend_close_conn(void* h, long conn_id) {
 }
 
 // mirror protocol ------------------------------------------------------
-// advance to serving epoch `epoch_id`: entries stamped with the
-// PREVIOUS epoch survive (every mutation between the two invalidated
-// its keys eagerly under the commit lock), anything older drops.
+// advance to serving epoch `epoch_id`, called in the critical section
+// that publishes it: every entry survives (every mutation since it was
+// taken invalidated its key eagerly under the commit lock), the marks of
+// the epoch left behind drop (fills stamped with it are refused by their
+// stamp from here on).
 void frontend_advance(void* h, long epoch_id, const uint8_t* clock_frag,
                       long clock_len, int clockless_ok) {
   Frontend* f = static_cast<Frontend*>(h);
   std::lock_guard<std::mutex> lk(f->mu);
   if (epoch_id != f->cur_epoch) {
-    long prev = f->cur_epoch;
-    for (auto it = f->mirror.begin(); it != f->mirror.end();) {
-      if (it->second.stamp == prev) {
-        it->second.stamp = epoch_id;
-        ++it;
-      } else if (it->second.stamp == epoch_id) {
-        ++it;
-      } else {
-        it = f->mirror.erase(it);
-      }
-    }
     f->cur_epoch = epoch_id;
+    f->dead.clear();
+    f->dead_all = false;
   }
   f->clock_frag.assign(reinterpret_cast<const char*>(clock_frag),
                        size_t(clock_len));
@@ -1064,17 +1109,35 @@ void frontend_fill_many(void* h, long n, const long* descs,
   }
 }
 
-void frontend_invalidate(void* h, const uint8_t* key, long key_len) {
+// invalidate `n` keys in one crossing (a commit group's written keys):
+// the keys back-to-back in `buf`, their lengths in `lens`; one mu take
+// for all of them, so no hit is built between two keys of one group.
+void frontend_invalidate_many(void* h, long n, const long* lens,
+                              const uint8_t* buf) {
   Frontend* f = static_cast<Frontend*>(h);
   std::lock_guard<std::mutex> lk(f->mu);
-  f->mirror.erase(
-      std::string(reinterpret_cast<const char*>(key), size_t(key_len)));
+  ++f->st_inval_calls;
+  f->st_inval_keys += n;
+  for (long i = 0; i < n; ++i) {
+    invalidate_locked(f, buf, lens[i]);
+    buf += lens[i];
+  }
+}
+
+// whole-batch hits answered so far: reads the epoch plane served that
+// Python never saw (the txn manager's idle test counts them as reads)
+long frontend_native_hits(void* h) {
+  Frontend* f = static_cast<Frontend*>(h);
+  std::lock_guard<std::mutex> lk(f->mu);
+  return f->st_hits;
 }
 
 void frontend_mirror_reset(void* h) {
   Frontend* f = static_cast<Frontend*>(h);
   std::lock_guard<std::mutex> lk(f->mu);
   f->mirror.clear();
+  f->dead.clear();
+  f->dead_all = false;
   f->cur_epoch = -1;
   f->clockless_ok = false;
 }
@@ -1095,7 +1158,8 @@ void frontend_set_clockless_ok(void* h, int on) {
 //                  sheds, forwarded, drains, mirror_size, in_flight,
 //                  open_conns, bad_frames, cross_wait_us, cross_frames,
 //                  send_wait_us, send_frames, send_calls, fill_calls,
-//                  fill_keys]
+//                  fill_keys, fill_refused, invalidate_calls,
+//                  invalidate_keys, mirror_marks]
 void frontend_stats(void* h, long* out, int n) {
   Frontend* f = static_cast<Frontend*>(h);
   std::lock_guard<std::mutex> lk(f->mu);
@@ -1105,7 +1169,9 @@ void frontend_stats(void* h, long* out, int n) {
                    f->g_inflight, f->n_open, f->st_bad_frame,
                    f->st_cross_wait_us, f->st_cross_frames,
                    f->st_send_wait_us, f->st_send_frames,
-                   f->st_send_calls, f->st_fill_calls, f->st_fill_keys};
+                   f->st_send_calls, f->st_fill_calls, f->st_fill_keys,
+                   f->st_fill_refused, f->st_inval_calls, f->st_inval_keys,
+                   long(f->dead_all ? f->mirror_cap : f->dead.size())};
   const int have = int(sizeof(vals) / sizeof(vals[0]));
   for (int i = 0; i < n && i < have; ++i) out[i] = vals[i];
 }

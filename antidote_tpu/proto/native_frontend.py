@@ -8,20 +8,26 @@ only cache misses, writes, interactive txns and apb-dialect frames via
 one packed batch-drain crossing per wakeup (``take_batch`` — the
 ``pump_take_batch`` discipline).
 
-The mirror protocol (kv.py pushes, epoch-id-stamped entries):
+The mirror protocol (kv.py and the txn manager push; the rule that keeps
+the mirror coherent under writes is the mirror's own — ``struct
+Frontend`` in cpp/frontend.cc — and no caller checks anything):
 
 * ``fill(key, bucket, type_name, value, epoch_id)`` — pushed wherever
   Python itself fills/serves from the snapshot cache (the whole-batch
   bottom path, a re-proved entry); ``fill_many(entries, epoch_id)`` —
   a writeback launch's gathered keys in one call (kv.py
-  ``snapshot_cache_fill``);
-* ``invalidate(key, bucket)`` — pushed EAGERLY under the commit lock for
-  every applied effect (kv.py ``_apply_effect_groups_inner``) and from
+  ``snapshot_cache_fill``).  The mirror takes an entry only if
+  ``epoch_id`` is the epoch it serves and the key was not invalidated
+  in it; what it refuses is a miss later, nothing else;
+* ``invalidate_many(keys)`` — pushed EAGERLY under the commit lock, one
+  call for a commit group's written keys (kv.py
+  ``_apply_effect_groups_inner``); ``invalidate(key, bucket)`` from
   ``drop_cached_value`` / ``mark_epoch_fallback``;
-* ``advance(epoch_id, vc, clockless_ok)`` — the server's epoch ticker
-  after every publish: entries stamped with the previous epoch survive
-  (every mutation in between invalidated its keys before publish),
-  older ones drop;
+* ``advance(epoch_id, vc, clockless_ok)`` — under the commit lock, in
+  the critical section that published the epoch (txn/manager.py
+  ``_native_epoch_published``): every entry survives (every mutation
+  since it was taken invalidated its key), the invalidation marks of
+  the epoch left behind drop;
 * ``reset()`` — ``drop_serving_epoch``: native serving disabled until
   the next advance.
 
@@ -93,9 +99,10 @@ def _load_lib():
             ctypes.c_long, ctypes.c_long,
         ]
         # a second handle on the same library whose calls KEEP the GIL:
-        # send_many and fill_many never block (they take the front end's
-        # mutex for a few microseconds), and the stage that calls them
-        # has a batch to finish — giving the GIL up around the call
+        # send_many, fill_many, invalidate_many, advance and native_hits never block
+        # (they take the front end's mutex for a few microseconds), and
+        # the stage that calls them has a batch to finish (the last three
+        # hold the commit lock) — giving the GIL up around the call
         # would put it back in the queue for it.  Nothing that runs
         # under that mutex calls back into Python (the io thread never
         # needs the GIL), so holding the GIL across it cannot deadlock
@@ -114,22 +121,31 @@ def _load_lib():
             ctypes.c_char_p, ctypes.c_long,
         ]
         lib.fill_many_keeping_gil = fill_many
-        lib.frontend_close_conn.restype = None
-        lib.frontend_close_conn.argtypes = [ctypes.c_void_p, ctypes.c_long]
-        lib.frontend_advance.restype = None
-        lib.frontend_advance.argtypes = [
+        invalidate_many = keeping_gil.frontend_invalidate_many
+        invalidate_many.restype = None
+        invalidate_many.argtypes = [
+            ctypes.c_void_p, ctypes.c_long, ctypes.POINTER(ctypes.c_long),
+            ctypes.c_char_p,
+        ]
+        lib.invalidate_many_keeping_gil = invalidate_many
+        advance = keeping_gil.frontend_advance
+        advance.restype = None
+        advance.argtypes = [
             ctypes.c_void_p, ctypes.c_long, ctypes.c_char_p,
             ctypes.c_long, ctypes.c_int,
         ]
+        lib.advance_keeping_gil = advance
+        native_hits = keeping_gil.frontend_native_hits
+        native_hits.restype = ctypes.c_long
+        native_hits.argtypes = [ctypes.c_void_p]
+        lib.native_hits_keeping_gil = native_hits
+        lib.frontend_close_conn.restype = None
+        lib.frontend_close_conn.argtypes = [ctypes.c_void_p, ctypes.c_long]
         lib.frontend_fill.restype = None
         lib.frontend_fill.argtypes = [
             ctypes.c_void_p, ctypes.c_char_p, ctypes.c_long,
             ctypes.c_char_p, ctypes.c_long, ctypes.c_char_p,
             ctypes.c_long, ctypes.c_long,
-        ]
-        lib.frontend_invalidate.restype = None
-        lib.frontend_invalidate.argtypes = [
-            ctypes.c_void_p, ctypes.c_char_p, ctypes.c_long,
         ]
         lib.frontend_mirror_reset.restype = None
         lib.frontend_mirror_reset.argtypes = [ctypes.c_void_p]
@@ -177,12 +193,19 @@ class NativeFrontend:
     #: send_calls: ``send`` + ``send_many`` calls (a writeback batch's
     #: replies are one); fill_calls: ``fill`` + ``fill_many`` calls (a
     #: writeback launch's gathered keys are one), fill_keys: the entries
-    #: they carried
+    #: they carried, fill_refused: those of them the mirror's rule turned
+    #: away (stamped with another epoch than the one served, or their key
+    #: invalidated in it); invalidate_calls / invalidate_keys: the
+    #: store's ``invalidate_many`` calls (a commit group's written keys
+    #: are one) and the keys they named; mirror_marks: keys invalidated
+    #: in the epoch being served (dropped at every advance)
     STAT_FIELDS = ("accepted", "closed", "frames", "native_hits",
                    "hit_objects", "sheds", "forwarded", "drains",
                    "mirror_size", "in_flight", "open_conns", "bad_frames",
                    "cross_wait_us", "cross_frames", "send_wait_us",
-                   "send_frames", "send_calls", "fill_calls", "fill_keys")
+                   "send_frames", "send_calls", "fill_calls", "fill_keys",
+                   "fill_refused", "invalidate_calls", "invalidate_keys",
+                   "mirror_marks")
     #: longs per frame in the take_batch descriptor
     _DESC = 5
 
@@ -330,21 +353,40 @@ class NativeFrontend:
         self._lib.fill_many_keeping_gil(h, len(lens) // 3, descs,
                                         b"".join(frags), int(epoch_id))
 
-    def invalidate(self, key, bucket) -> None:
+    def invalidate_many(self, keys) -> None:
+        """Invalidate ``[(key, bucket)]`` in ONE native call: one lock
+        take for all of them, the GIL kept.  A key that does not pack was
+        never mirrored."""
         h = self._h
         if h is None:
             return
-        k = self._mirror_key(key, bucket)
-        if k is not None:
-            self._lib.frontend_invalidate(h, k, len(k))
+        packed = [k for k in (self._mirror_key(key, bucket)
+                              for key, bucket in keys) if k is not None]
+        if not packed:
+            return
+        lens = (ctypes.c_long * len(packed))(*map(len, packed))
+        self._lib.invalidate_many_keeping_gil(h, len(packed), lens,
+                                              b"".join(packed))
+
+    def invalidate(self, key, bucket) -> None:
+        self.invalidate_many([(key, bucket)])
 
     def advance(self, epoch_id: int, vc_list, clockless_ok: bool) -> None:
+        """Serve at ``epoch_id`` from here on.  The caller holds the
+        commit lock and has just published that epoch (the mirror's rule
+        rests on it); the GIL is kept."""
         h = self._h
         if h is None:
             return
         frag = _packb([int(x) for x in vc_list])
-        self._lib.frontend_advance(h, int(epoch_id), frag,
-                                   len(frag), 1 if clockless_ok else 0)
+        self._lib.advance_keeping_gil(h, int(epoch_id), frag, len(frag),
+                                      1 if clockless_ok else 0)
+
+    def native_hits(self) -> int:
+        """Reads answered from the mirror so far (``stats()["native_hits"]``
+        alone, the GIL kept): epoch-plane reads that Python never sees."""
+        h = self._h
+        return 0 if h is None else int(self._lib.native_hits_keeping_gil(h))
 
     def reset(self) -> None:
         h = self._h

@@ -964,23 +964,6 @@ class ProtocolServer:
             "retry_after_ms": int(hint_ms),
         })
 
-    def _native_advance(self) -> None:
-        """Push the freshly-published serving epoch to the C++ mirror —
-        called by the epoch ticker right after every publish.  The
-        mirror's re-stamping is sound because every effect applied since
-        the last advance invalidated its keys eagerly (under the commit
-        lock, BEFORE the publish made them visible)."""
-        nf = self.native
-        txm = self.node.txm
-        if nf is None or getattr(txm.store, "native_mirror", None) is not nf:
-            return
-        ep = txm.store.serving_epoch
-        if ep is None:
-            nf.set_clockless_ok(False)
-            return
-        nf.advance(int(ep.id), [int(x) for x in ep.vc],
-                   int(ep.vc[txm.my_dc]) >= txm.epoch_lag_counter)
-
     # ------------------------------------------------------------------
     # static batch gate
     # ------------------------------------------------------------------
@@ -1647,7 +1630,6 @@ class ProtocolServer:
                 with span("epoch.tick"):
                     if self._epoch_reads:
                         txm.publish_serving_epoch()
-                        self._native_advance()
                     self._publish_table_epochs_capped()
             except Exception:
                 log.exception("epoch ticker publish failed")

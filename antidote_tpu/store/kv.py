@@ -511,7 +511,8 @@ class KVStore:
         #: NativeFrontend mirror (ISSUE 16) — the C++ serving loop's
         #: epoch-stamped copy of the snapshot cache.  Wired by the
         #: protocol server when native whole-batch serving is on;
-        #: pushed from the fill/invalidate/drop paths below so the
+        #: pushed from the fill/invalidate/drop paths below; which
+        #: fill it takes is the mirror's own rule (frontend.cc), so the
         #: native plane can never serve a value Python would not.
         self.native_mirror = None
         #: (key, bucket) pairs written/born/promoted since the last
@@ -705,9 +706,10 @@ class KVStore:
         sub-group.  Returns ``(errors, ticket, wal)``: one ``None`` or
         ``Exception`` per sub-group; with ``defer_sync`` the group-fsync
         ticket acks must wait on (None when nothing was logged; the fsync
-        runs CONCURRENTLY with the device scatter); and ``time.monotonic()``
-        at the start and the end of the WAL phase (append + fsync
-        submitted), for the commit path's phase split."""
+        runs CONCURRENTLY with the device scatter); and, for the commit
+        path's phase split, ``time.monotonic()`` at the start and the end
+        of the WAL phase (append + fsync submitted) and the seconds the
+        native mirror's invalidation took (None without a mirror)."""
         self._mutating = True
         self.mutation_epoch += 1
         try:
@@ -720,15 +722,20 @@ class KVStore:
         effects = [e for g in groups for e in g[0]]
         self.locate_many([(e.key, e.type_name, e.bucket) for e in effects])
         nm = self.native_mirror
+        mirror_s = None
         if nm is not None:
             # EAGER native-mirror invalidation, under the commit lock,
-            # BEFORE any table observes the effects: the C++ loop can
-            # at worst keep serving the pre-commit value at the current
-            # epoch stamp (exactly what the Python cache serves until
-            # the next publish), never a torn or stale-at-epoch one —
-            # this ordering is what makes advance()'s re-stamping sound
-            for dk in {(e.key, e.bucket) for e in effects}:
-                nm.invalidate(dk[0], dk[1])
+            # BEFORE any table observes the effects, the group's keys in
+            # one native call: from here to the advance that follows the
+            # group's publish the C++ loop misses on these keys, and the
+            # mirror itself refuses whatever a read launched before this
+            # line would still push for them (frontend.cc, the mirror's
+            # rule) — it never serves a value this group made history
+            keys = {(e.key, e.bucket) for e in effects}
+            t_inv = time.monotonic()
+            with span("commit.mirror_invalidate", keys=len(keys)):
+                nm.invalidate_many(keys)
+            mirror_s = time.monotonic() - t_inv
         # ---- overflow escape hatch: promote BEFORE anything can drop.
         # Aggregate each key's worst-case fresh-slot demand (+ the minimum
         # tier its effect lanes require — a remote DC may ship wider
@@ -863,7 +870,7 @@ class KVStore:
             # holds the commit lock; eviction mutates tables)
             self.cold.note_writes(inval)
             self.cold.maybe_evict()
-        return errors, ticket, (t_wal, t_wal_end)
+        return errors, ticket, (t_wal, t_wal_end, mirror_s)
 
     # ------------------------------------------------------------------
     # serving epochs (lock-split wire reads — ISSUE 5)
@@ -1115,9 +1122,9 @@ class KVStore:
                         nm = self.native_mirror
                         if nm is not None:
                             # re-prove the entry to the native mirror
-                            # too (its advance() only carries entries
-                            # stamped at the previous epoch — Python's
-                            # touch-log walk can bridge longer gaps)
+                            # too: it lacks what it refused or evicted,
+                            # and this walk has just shown the value to
+                            # be the one at ep
                             nm.fill(dk[0], dk[1], split_tier(loc[0])[0],
                                     value, ep.id)
                 if ok:

@@ -1482,11 +1482,27 @@ class TypedTable:
         [P, M'] routing/unrouting); mesh-sharded tables keep the routed
         layout so gathers stay shard-local."""
         if self.sharding is None:
+            # padded to a batch bucket with copies of the last row (same
+            # freshness, so the dispatch ladder decides as for the batch
+            # itself): the flat programs compile once a bucket, not once
+            # a batch size — on the chip each compile is most of a second
+            # of the locked worker's time, and this is the path reads
+            # take while a publish is put off
+            shards = np.asarray(shards, np.int64)
+            rows = np.asarray(rows, np.int64)
+            read_vcs = np.asarray(read_vcs, np.int32)
+            m = len(rows)
+            pad = _bucket(m, self.cfg.batch_buckets) - m if m else 0
+            if pad:
+                shards = np.concatenate([shards, np.repeat(shards[-1:], pad)])
+                rows = np.concatenate([rows, np.repeat(rows[-1:], pad)])
+                read_vcs = np.concatenate(
+                    [read_vcs, np.repeat(read_vcs[-1:], pad, axis=0)])
             resolved, fresh, complete = self.read_resolved_flat(
                 shards, rows, read_vcs
             )
-            return ({f: np.asarray(x) for f, x in resolved.items()},
-                    np.asarray(fresh), np.asarray(complete))
+            return ({f: np.asarray(x)[:m] for f, x in resolved.items()},
+                    np.asarray(fresh)[:m], np.asarray(complete)[:m])
         resolved, fresh, complete, pos = self.read_resolved_raw(
             shards, rows, read_vcs
         )

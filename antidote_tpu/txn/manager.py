@@ -251,15 +251,33 @@ class TransactionManager:
             return self._publish_serving_epoch_locked()
 
     def _publish_serving_epoch_locked(self) -> str:
-        return self.store.publish_serving_epoch(self.serving_epoch_vc())
+        st = self.store.publish_serving_epoch(self.serving_epoch_vc())
+        self._native_epoch_published()
+        return st
+
+    def _native_epoch_published(self) -> None:
+        """Push the serving epoch to the C++ mirror, in the critical
+        section (commit lock held) of the publish that made it: every
+        invalidation — they run under this lock too — then finds the
+        mirror at the epoch Python serves, which is what the mirror's
+        rule for fills rests on (proto/cpp/frontend.cc), and a commit's
+        acknowledgement never leaves before the mirror has left the
+        epoch that lacks it.  Also after a publish that made no epoch
+        (noop, deferred): the id is then the mirror's own and only the
+        lag gate's verdict is refreshed."""
+        nm = getattr(self.store, "native_mirror", None)
+        ep = self.store.serving_epoch
+        if nm is not None and ep is not None:
+            nm.advance(int(ep.id), [int(x) for x in ep.vc],
+                       int(ep.vc[self.my_dc]) >= self.epoch_lag_counter)
 
     def _native_lag_raised(self) -> None:
         """The serving epoch just started lagging the commit counter:
         the native front-end must stop serving clockless reads from it
         (Python's ``_try_cache_read`` refuses via ``epoch_lag_counter``;
-        the C++ loop learns the same fact here).  The next successful
-        advance — server epoch ticker, after a publish that catches up —
-        re-enables it."""
+        the C++ loop learns the same fact here).  The next publish that
+        catches up — a commit group's or the epoch ticker's —
+        re-enables it (``_native_epoch_published``)."""
         nm = getattr(self.store, "native_mirror", None)
         if nm is not None:
             nm.set_clockless_ok(False)
@@ -791,9 +809,9 @@ class TransactionManager:
         leaves; a write-bearing round's phase stamps go to
         ``self.phases`` and its lock-held time to ``commit_seconds``."""
         t0 = time.monotonic()
-        stamps, freeze_s = None, 0.0
+        stamps, freeze_s, mirror_s = None, 0.0, None
         try:
-            out, inner = self._commit_group_locked(txns)
+            out, inner, mirror_s = self._commit_group_locked(txns)
             stamps = (t0, *inner, time.monotonic())
             if round_writes and self.serving_epochs:
                 # publish BEFORE the ack leaves: a clockless
@@ -820,6 +838,12 @@ class TransactionManager:
                     sr = self.metrics.serving_reads
                     reads_now = (sr.value(path="cache")
                                  + sr.value(path="gather"))
+                    nm = getattr(self.store, "native_mirror", None)
+                    if nm is not None:
+                        # the C++ mirror's hits are epoch reads too, the
+                        # only ones Python never counts: while they flow
+                        # the plane is not idle
+                        reads_now += nm.native_hits()
                 idle = (reads_now ==
                         self._reads_at_last_publish)
                 if (idle and now2 - self._last_inline_publish
@@ -841,7 +865,9 @@ class TransactionManager:
                     if st not in ("published", "noop"):
                         self.epoch_lag_counter = (
                             self.commit_counter)
-                        self._native_lag_raised()
+                    # before the ack: the mirror leaves the epoch that
+                    # lacks this group with the lock still held
+                    self._native_epoch_published()
         except OSError as e:
             if round_writes and e.errno in (errno.ENOSPC,
                                             errno.EIO,
@@ -868,7 +894,8 @@ class TransactionManager:
                     # publish.  A phase the round skipped is zero-long,
                     # so the six always sum to ``t_end - t0``, the
                     # round's ``antidote_commit_seconds`` observation.
-                    self.phases.add_group((*stamps, t_end), freeze_s)
+                    self.phases.add_group((*stamps, t_end), freeze_s,
+                                          mirror_s)
                 if self.metrics is not None:
                     self.metrics.commit_seconds.observe(t_end - t0)
                     self.metrics.commit_merge_width.observe(
@@ -895,20 +922,22 @@ class TransactionManager:
         covering group fsync (overlapped with the scatter; awaited
         BEFORE listeners run, so nothing non-durable ever reaches the
         serving epoch or the inter-DC stream), listeners per member.
-        Returns the per-txn results and four stamps: ``time.monotonic()``
+        Returns the per-txn results, four stamps — ``time.monotonic()``
         at the start and the end of the WAL append, the end of the scatter
         and the end of the fsync wait (all four the end of certification
-        when no member survived it)."""
+        when no member survived it) — and the seconds the store spent
+        invalidating the group's keys in the native mirror (None when
+        there is no mirror or nothing was applied)."""
         with span("commit.certify"):
             out, pend = self._certify_locked(txns)
         if pend:
-            stamps = self._apply_certified_locked(out, pend)
+            stamps, mirror_s = self._apply_certified_locked(out, pend)
         else:
-            stamps = (time.monotonic(),) * 4
+            stamps, mirror_s = (time.monotonic(),) * 4, None
         if self.commit_counter >= self._next_cert_gc:
             self._gc_committed_keys()
             self._next_cert_gc = self.commit_counter + self._cert_gc_every
-        return out, stamps
+        return out, stamps, mirror_s
 
     def _certify_locked(self, txns: Sequence[Transaction]):
         """The ``certify`` phase of a commit group: vectorised
@@ -1091,13 +1120,15 @@ class TransactionManager:
         scatter, the covering fsync's wait, listeners per member.  NACKed
         members' entries of ``out`` become their errors.  Returns
         ``time.monotonic()`` at the start and the end of the WAL append,
-        the end of the scatter and the end of the fsync wait."""
+        the end of the scatter and the end of the fsync wait, and beside
+        them the seconds of the native mirror's invalidation (None
+        without a mirror)."""
         groups = [
             (effs, [vc] * len(effs), [self.my_dc] * len(effs))
             for _i, _t, vc, effs, _s, _c in pend
         ]
         try:
-            errors, ticket, (t_wal0, t_wal1) = (
+            errors, ticket, (t_wal0, t_wal1, mirror_s) = (
                 self.store.apply_effect_groups(groups))
         except BaseException:
             # a non-WAL failure (device error): nothing scattered —
@@ -1179,7 +1210,7 @@ class TransactionManager:
                     )
         finally:
             self._publishing_group = False
-        return t_wal0, t_wal1, t_scat, t_fsync
+        return (t_wal0, t_wal1, t_scat, t_fsync), mirror_s
 
     def _gc_committed_keys(self) -> None:
         """Drop certification entries no open (or future) txn can conflict

@@ -67,6 +67,18 @@ class RecordingMirror:
     def invalidate(self, key, bucket):
         pass
 
+    def invalidate_many(self, keys):
+        pass
+
+    def advance(self, epoch_id, vc_list, clockless_ok):
+        pass
+
+    def set_clockless_ok(self, on):
+        pass
+
+    def native_hits(self):
+        return 0
+
     def reset(self):
         pass
 

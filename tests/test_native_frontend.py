@@ -167,8 +167,9 @@ def test_whole_batch_hit_bytes_match_python_path():
 
 
 # ---------------------------------------------------------------------------
-# write invalidation: clockless reads through the native mirror are
-# bounded-stale and converge after every write
+# write invalidation: a clockless read through the native mirror shows
+# every acknowledged write, the first one sent after the acknowledgement
+# too (the configuration counter_pn_10k's visibility guarantee)
 # ---------------------------------------------------------------------------
 def test_native_mirror_invalidation_converges_and_never_overshoots():
     node, srv = _boot(True, epoch_tick_ms=25)
@@ -179,21 +180,15 @@ def test_native_mirror_invalidation_converges_and_never_overshoots():
             total += 1
             c.update_objects(
                 [("wk", "counter_pn", "b", ("increment", 1))])
-            deadline = time.monotonic() + 20
-            while True:
+            # the increment is acknowledged: the very next read shows it,
+            # and no read ever shows more than the store published
+            for _ in range(5):
                 vals, _ = c.read_objects([("wk", "counter_pn", "b")],
                                          clock=None)
-                # staleness is bounded by the epoch cadence; a value
-                # BEYOND the committed total would mean the mirror
-                # served bytes the store never published
-                assert vals[0] <= total, (round_, vals[0], total)
-                if vals[0] == total:
-                    break
-                assert time.monotonic() < deadline, \
-                    f"clockless read stuck at {vals[0]} < {total}"
-                time.sleep(0.01)
-            # converged: the Python fill re-armed the mirror — repeat
-            # reads between writes are exactly what the fast path owns
+                assert vals == [total], (round_, vals, total)
+            # let an epoch pass: the Python fill re-arms the mirror —
+            # repeat reads between writes are what the fast path owns
+            time.sleep(0.06)
             for _ in range(4):
                 vals, _ = c.read_objects([("wk", "counter_pn", "b")],
                                          clock=None)
@@ -633,6 +628,85 @@ def test_mirror_fill_keys_metric_file_reads_the_counters():
         srv.close()
 
 
+#: the four metric files of cell counter_pn_10k.update_read (ISSUE 31):
+#: name -> (two status blocks of a window, what the file reads of them)
+_MIRROR_METRICS = {
+    "frontend.mirror_hit_share": (
+        {"pipeline": {"native": {"native_hits": 100},
+                      "direct": {"served": 50, "worker": 10}}},
+        {"pipeline": {"native": {"native_hits": 500},
+                      "direct": {"served": 550, "worker": 110}}},
+        40.0),
+    "frontend.mirror_refused_share": (
+        {"pipeline": {"native": {"fill_refused": 5, "fill_keys": 100}}},
+        {"pipeline": {"native": {"fill_refused": 305, "fill_keys": 1100}}},
+        30.0),
+    "txn.mirror_invalidate_ms": (
+        {"write_plane": {"phases": {"mirror_invalidate": {
+            "sum_ms": 1.0, "count": 10}}}},
+        {"write_plane": {"phases": {"mirror_invalidate": {
+            "sum_ms": 13.0, "count": 90}}}},
+        0.15),
+    "txn.mirror_invalidate_keys": (
+        {"pipeline": {"native": {"invalidate_keys": 30,
+                                 "invalidate_calls": 1}}},
+        {"pipeline": {"native": {"invalidate_keys": 3030,
+                                 "invalidate_calls": 101}}},
+        30.0),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_MIRROR_METRICS))
+def test_mirror_metric_file_reads_the_counters(name):
+    """Each of the new cell's metric files agrees with its BENCHMARK.json
+    entry, reads its ratio off two node statuses whose paths a served
+    node's status really has, and reads nothing — without raising — when
+    nothing advanced, off the parent's status (the program before the
+    counters), and off the Python plane's."""
+    from types import SimpleNamespace
+
+    from benchmarks.readers import status_delta
+
+    spec, _ = _metric_file_and_ctx(name)
+    pre, post, want = _MIRROR_METRICS[name]
+    assert spec["workloads"] == ["counter_pn_10k.update_read"]
+
+    def read(a, b):
+        return status_delta.read(
+            spec, SimpleNamespace(status={"window": (a, b)}))
+
+    assert read(pre, post) == pytest.approx(want)
+    assert read(post, post) is None
+    # the parent: a native block and phases without this PR's counters
+    parent = {"pipeline": {"native": {"fill_keys": 9, "fill_calls": 3},
+                           "direct": {"served": 4, "worker": 0}},
+              "write_plane": {"phases": {"certify": {"sum_ms": 1.0,
+                                                     "count": 2}}}}
+    if name != "frontend.mirror_hit_share":    # its counters predate it
+        later = {"pipeline": {"native": {"fill_keys": 99, "fill_calls": 30},
+                              "direct": {"served": 40, "worker": 0}},
+                 "write_plane": {"phases": {"certify": {"sum_ms": 9.0,
+                                                        "count": 20}}}}
+        assert read(parent, later) is None
+    # the Python plane: no native block, no mirror phase counted
+    assert read({"pipeline": {}, "write_plane": {"phases": {}}},
+                {"pipeline": {}, "write_plane": {"phases": {}}}) is None
+    node, srv = _boot(True)
+    c = AntidoteClient(port=srv.port)
+    try:
+        c.update_objects([("mm", "counter_pn", "b", ("increment", 1))])
+        st = c.node_status()
+        for term in spec["num"] + spec["den"]:
+            assert status_delta.lookup(st, term["path"]) is not None, term
+        ph = st["write_plane"]["phases"]["mirror_invalidate"]
+        assert ph["count"] == st["write_plane"]["phases"]["certify"]["count"]
+        assert 0 < ph["sum_ms"] <= st["write_plane"]["phases"]["certify"][
+            "sum_ms"]
+    finally:
+        c.close()
+        srv.close()
+
+
 def test_pipelined_connection_gets_replies_in_request_order():
     """read-miss, read-hit, update, read written without waiting, 200
     rounds: the first frame parks from the drain thread, the rest take
@@ -1035,6 +1109,94 @@ def test_mirror_cap_evicts_on_fill(many):
             assert decode(read_frame(s))[1]["values"] == [99]
         finally:
             s.close()
+    finally:
+        nf.close()
+
+
+def _native_read(port, key):
+    """One clockless read of ``key`` on a fresh connection: the value."""
+    s = socket.create_connection(("127.0.0.1", port), timeout=10)
+    s.settimeout(20)
+    try:
+        s.sendall(_read_frame_of(key))
+        code, body = decode(read_frame(s))
+        assert code == MessageCode.READ_OBJECTS_RESP
+        return body["values"][0]
+    finally:
+        s.close()
+
+
+@pytest.mark.parametrize("held", [True, False],
+                         ids=["entry", "taught_bottom"])
+def test_mirror_refuses_a_fill_read_before_an_invalidation(held):
+    """The mirror's rule (frontend.cc), step by step on a booted server's
+    mirror, its ticker far away: at epoch E a key is invalidated (a commit
+    under the lock), then a value read before that arrives — a gather
+    launched at E whose writeback calls ``fill_many``, or the bottom that
+    ``epoch_cache_read`` teaches for a key the mirror has no entry of.
+    The mirror turns it away: once the commit is acknowledged no clockless
+    read is a native hit carrying it, at E or after the advance to E+1;
+    a value read at E+1 is taken, one stamped E no longer."""
+    node, srv = _boot(True, epoch_tick_ms=3_600_000)
+    nf = srv.native
+    key, stale, fresh = "mk", 41, 42      # the store itself holds 0
+    E = 1000
+    try:
+        nf.advance(E, [5, 0], True)
+        if held:
+            nf.fill(key, "b", "counter_pn", stale, E)
+            assert _native_read(srv.port, key) == stale     # a hit
+        st0 = nf.stats()
+        nf.invalidate(key, "b")         # the commit, under its lock
+        if held:                        # the late writeback of the gather
+            nf.fill_many([(key, "b", "counter_pn", stale)], E)
+        else:                           # the late taught bottom
+            nf.fill(key, "b", "counter_pn", stale, E)
+        # ... the commit publishes E+1 and is acknowledged
+        assert _native_read(srv.port, key) != stale
+        assert nf.stats()["mirror_marks"] == 1
+        nf.advance(E + 1, [6, 0], True)
+        assert _native_read(srv.port, key) != stale
+        st1 = nf.stats()
+        assert st1["native_hits"] == st0["native_hits"]
+        assert st1["fill_refused"] - st0["fill_refused"] == 1
+        assert st1["fill_keys"] - st0["fill_keys"] == 1
+        assert (st1["invalidate_calls"] - st0["invalidate_calls"],
+                st1["invalidate_keys"] - st0["invalidate_keys"]) == (1, 1)
+        assert st1["mirror_marks"] == 0         # dropped at the advance
+        # a fill that arrives with the epoch left behind: refused by its
+        # stamp; one read at the epoch served: taken, and served
+        nf.fill_many([(key, "b", "counter_pn", stale)], E)
+        assert _native_read(srv.port, key) != stale
+        nf.fill_many([(key, "b", "counter_pn", fresh)], E + 1)
+        assert _native_read(srv.port, key) == fresh
+        st2 = nf.stats()
+        assert st2["fill_refused"] - st1["fill_refused"] == 1
+        assert st2["native_hits"] - st1["native_hits"] == 1
+        # and in the node status, where the benchmark reads them
+        native = srv._pipeline_status()["native"]
+        assert {"fill_refused", "invalidate_calls", "invalidate_keys",
+                "mirror_marks"} <= set(native)
+    finally:
+        srv.close()
+
+
+def test_mirror_marks_are_bounded_by_the_mirror_cap():
+    """More keys invalidated in one epoch than the mirror may hold: the
+    marks collapse into 'refuse every fill' until the next advance."""
+    nf = _bare_frontend(mirror_cap=4)
+    try:
+        nf.advance(1, [0, 0], True)
+        nf.invalidate_many([(f"bk{i}", "b") for i in range(6)])
+        assert nf.stats()["mirror_marks"] == 4
+        nf.fill("other", "b", "counter_pn", 1, 1)
+        st = nf.stats()
+        assert (st["fill_refused"], st["mirror_size"]) == (1, 0)
+        nf.advance(2, [0, 0], True)
+        nf.fill("other", "b", "counter_pn", 1, 2)
+        st = nf.stats()
+        assert (st["fill_refused"], st["mirror_size"],
+                st["mirror_marks"]) == (1, 1, 0)
     finally:
         nf.close()
 

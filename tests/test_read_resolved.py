@@ -381,3 +381,37 @@ def test_read_resolved_flat_matches_routed():
                 np.asarray(flat_fresh), routed_fresh, err_msg=(tyname, t))
             np.testing.assert_array_equal(
                 np.asarray(flat_comp), routed_comp, err_msg=(tyname, t))
+
+
+@pytest.mark.parametrize("at", ["latest", "historical"])
+def test_read_resolved_pads_the_flat_read_to_a_batch_bucket(at):
+    """Whatever the batch size, the single-device serving read hands the
+    flat programs a batch of a bucket's size (one compiled program a
+    bucket, not one a size) and gives the batch's own answers back."""
+    d = 3
+    cfg = _mk_cfg()
+    table = TypedTable(get_type("set_aw"), cfg)
+    _, mid = _populate_set(table, 10, d)
+    t = 10_000 if at == "latest" else mid
+    flat = table.read_resolved_flat
+    seen = []
+
+    def recording(shards, rows, read_vcs):
+        seen.append(len(rows))
+        return flat(shards, rows, read_vcs)
+
+    table.read_resolved_flat = recording
+    for m in (1, 3, 16, 17, 49):
+        keys = np.arange(m, dtype=np.int64) % 10
+        ss, rr = keys % table.n_shards, keys
+        vcs = np.zeros((m, d), np.int32)
+        vcs[:, 0] = t
+        out, fresh, complete = table.read_resolved(ss, rr, vcs)
+        want, want_fresh, want_complete = flat(ss, rr, vcs)
+        assert fresh.shape == complete.shape == (m,)
+        for f, x in out.items():
+            np.testing.assert_array_equal(x, np.asarray(want[f]), err_msg=f)
+        np.testing.assert_array_equal(fresh, np.asarray(want_fresh))
+        np.testing.assert_array_equal(complete, np.asarray(want_complete))
+    assert seen == [16, 16, 16, 64, 64]
+    assert set(seen) <= set(cfg.batch_buckets)

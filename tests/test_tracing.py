@@ -303,6 +303,113 @@ def test_commit_phases_sum_to_the_group_and_count_once_per_group(tmp_path):
     assert ph["freeze"]["sum_ms"] <= ph["publish"]["sum_ms"]
 
 
+@pytest.mark.parametrize("mirror", [True, False],
+                         ids=["mirror", "no_mirror"])
+def test_mirror_invalidate_is_one_call_one_span_one_phase_a_group(
+        mirror, monkeypatch):
+    """A commit group tells the native mirror its written keys in ONE
+    call, inside one ``commit.mirror_invalidate`` span whose interval is
+    the group's ``mirror_invalidate`` phase, and the mirror advances in
+    the same critical section as the publish, before the acknowledgement;
+    a node without the mirror opens no span and counts no phase."""
+    from conftest import RecordingMirror
+
+    from antidote_tpu.store import kv
+
+    calls, opened = [], []
+
+    class Mirror(RecordingMirror):
+        def invalidate_many(self, keys):
+            assert node.txm.commit_lock._is_owned()
+            calls.append(("invalidate", sorted(keys)))
+
+        def advance(self, epoch_id, vc_list, clockless_ok):
+            assert node.txm.commit_lock._is_owned()
+            assert epoch_id == node.store.serving_epoch.id
+            calls.append(("advance", epoch_id, clockless_ok))
+
+    inner = kv.span
+
+    def span(name, **ids):
+        opened.append((name, ids))
+        return inner(name, **ids)
+
+    monkeypatch.setattr(kv, "span", span)
+    node = AntidoteNode(mk_cfg())
+    node.txm.enable_serving_epochs()
+    # no read flows here: without this a group that follows another
+    # within the window would put its publish off (the write-storm rule)
+    node.txm.EPOCH_INLINE_PUBLISH_S = 0.0
+    if mirror:
+        node.store.native_mirror = Mirror(node.store)
+    groups = 3
+    for g in range(groups):
+        txns = []
+        for j in range(4):
+            t = node.start_transaction()
+            # two members write the same key: one name in the call
+            node.update_objects(
+                [(f"m{g}_{j // 2}", "counter_pn", "b", ("increment", 1))], t)
+            txns.append(t)
+        assert not any(isinstance(o, Exception)
+                       for o in node.txm.commit_transactions_group(txns))
+    ph = node.status()["write_plane"]["phases"]
+    spans = [ids for name, ids in opened
+             if name == "commit.mirror_invalidate"]
+    if not mirror:
+        assert not spans and ph["mirror_invalidate"] == {"sum_ms": 0.0,
+                                                         "count": 0}
+        return
+    assert [ids["keys"] for ids in spans] == [2] * groups
+    assert ph["mirror_invalidate"]["count"] == groups == ph["certify"]["count"]
+    assert 0 <= ph["mirror_invalidate"]["sum_ms"] <= ph["certify"]["sum_ms"]
+    # invalidate, then — once the group has published — advance; the ack
+    # (commit_transactions_group returning) comes after both
+    assert [c[0] for c in calls] == ["invalidate", "advance"] * groups
+    assert calls[0][1] == [("m0_0", "b"), ("m0_1", "b")]
+    ids = [c[1] for c in calls if c[0] == "advance"]
+    assert ids == sorted(set(ids)) and all(c[2] for c in calls
+                                           if c[0] == "advance")
+
+
+@pytest.mark.parametrize("hits_flow", [True, False],
+                         ids=["native_hits_flow", "idle"])
+def test_native_hits_keep_the_epoch_plane_from_looking_idle(hits_flow):
+    """The write-storm rule puts a commit group's publish off while no
+    epoch read came since the last one.  Reads the C++ mirror answers
+    never reach Python's counters: the mirror's hits count as reads, so a
+    node whose readers all hit natively still publishes before each
+    acknowledgement; with no hit and no read the second group defers."""
+    from conftest import RecordingMirror
+
+    class Mirror(RecordingMirror):
+        hits = 0
+
+        def native_hits(self):
+            if hits_flow:
+                Mirror.hits += 3
+            return Mirror.hits
+
+    node = AntidoteNode(mk_cfg())
+    node.txm.enable_serving_epochs()
+    node.txm.EPOCH_INLINE_PUBLISH_S = 60.0      # every group inside it
+    node.store.native_mirror = Mirror(node.store)
+    epochs = []
+    for g in range(3):
+        t = node.start_transaction()
+        node.update_objects([(f"h{g}", "counter_pn", "b", ("increment", 1))],
+                            t)
+        assert not isinstance(node.txm.commit_transactions_group([t])[0],
+                              Exception)
+        epochs.append(node.store.serving_epoch.id)
+    if hits_flow:
+        assert epochs[0] < epochs[1] < epochs[2]
+        assert node.txm.epoch_lag_counter == 0
+    else:
+        assert epochs[0] == epochs[1] == epochs[2]
+        assert node.txm.epoch_lag_counter == node.txm.commit_counter
+
+
 def test_server_reports_ack_phase_and_locked_idle():
     node, srv = _boot(False)
     c = AntidoteClient(srv.host, srv.port)
