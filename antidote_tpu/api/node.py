@@ -455,6 +455,9 @@ class AntidoteNode:
             # (`freeze` lies inside publish, `ack` follows the lock)
             "group": _hist_ms(self.metrics.commit_seconds),
             "phases": self.txm.phases.status(),
+            # what the scatter phase sent to the device: commit groups,
+            # host arrays transferred, device programs launched
+            "scatter": self.store.scatter_status(),
         }
         # checkpoint / fast-restart view (ISSUE 8): last published image
         # stamp, size, age, and how much tail a crash-now restart would

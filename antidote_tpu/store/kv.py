@@ -462,6 +462,9 @@ class KVStore:
         #: the commit it never saw
         self.mutation_epoch = 0
         self._mutating = False
+        #: commit groups whose effects went to the device (node status
+        #: ``write_plane.scatter``, beside the tables' own tallies)
+        self.scatter_groups = 0
         #: (src_tname, dst_tname) -> jitted one-launch row promotion —
         #: ~25 eager device ops per promotion otherwise, each a dispatch
         #: (and on first use a compile), which made every hot-key tier
@@ -849,16 +852,18 @@ class KVStore:
         with span("commit.scatter", effects=len(inval)):
             for tname_t, items in by_table.items():
                 t = self.table(tname_t)
-                aw = t.ty.eff_a_width(t.cfg)
-                bw = t.ty.eff_b_width(t.cfg)
-                t.append(
-                    np.asarray([x[0] for x in items], np.int64),
-                    np.asarray([x[1] for x in items], np.int64),
-                    np.stack([_pad_lane(x[2], aw, np.int64) for x in items]),
-                    np.stack([_pad_lane(x[3], bw, np.int32) for x in items]),
-                    np.stack([np.asarray(x[4], np.int32) for x in items]),
-                    np.asarray([x[5] for x in items], np.int32),
-                )
+                shards, rows, las, lbs, vcs, orgs = zip(*items)
+                # lanes narrower than the table's tier are zero-padded
+                eff_a = np.zeros((len(items), t.ty.eff_a_width(t.cfg)),
+                                 np.int64)
+                eff_b = np.zeros((len(items), t.ty.eff_b_width(t.cfg)),
+                                 np.int32)
+                for i, (la, lb) in enumerate(zip(las, lbs)):
+                    eff_a[i, :len(la)] = la
+                    eff_b[i, :len(lb)] = lb
+                t.append(shards, rows, eff_a, eff_b, np.asarray(vcs), orgs)
+            if by_table:
+                self.scatter_groups += 1
         # only after every append succeeded may the partition clocks claim
         # these commits (the stable snapshot must never dominate unapplied
         # ops — the causal gate trusts it)
@@ -871,6 +876,17 @@ class KVStore:
             self.cold.note_writes(inval)
             self.cold.maybe_evict()
         return errors, ticket, (t_wal, t_wal_end, mirror_s)
+
+    def scatter_status(self) -> Dict[str, int]:
+        """``write_plane.scatter`` of the node status: commit groups that
+        reached the device, and what their tables' ``append`` sent there
+        — host arrays transferred, device programs launched."""
+        tables = list(self.tables.values())
+        return {
+            "groups": self.scatter_groups,
+            "transfers": sum(t.scatter_transfers for t in tables),
+            "launches": sum(t.scatter_launches for t in tables),
+        }
 
     # ------------------------------------------------------------------
     # serving epochs (lock-split wire reads — ISSUE 5)

@@ -243,3 +243,78 @@ def test_mesh_gather_compiles_at_2m_rows(topo, no_persistent_cache,
           f"{mem.argument_size_in_bytes / 1e9:.3f} GB, temporaries "
           f"{mem.temp_size_in_bytes / 1e9:.3f} GB, output "
           f"{mem.output_size_in_bytes / 1e6:.3f} MB a device")
+
+
+@pytest.mark.parametrize("window", [1, 0])
+@pytest.mark.parametrize("placement", ["one_chip", "mesh4"])
+def test_commit_scatter_compiles_in_place_at_set_aw_shapes(
+        topo, no_persistent_cache, placement, window):
+    """`jit_antidote_commit_scatter_w<n>`, the one program of a commit
+    group, at the shapes of the two `set_aw` deployments: [16, 65536] on
+    one chip (`set_aw_1m`) and [16, 131072] over the host's four
+    (`set_aw_2m_mesh4`: [4, 131072] a device), batch bucket 64.  All six
+    donated tables alias their outputs, a mesh program needs no
+    collective, and fusing the ring scatter with the head update costs no
+    memory: the program's temporaries stay within 64 MB of what the head
+    update reserves as a program of its own on one device's block (it
+    copies whole head fields between layouts and splits the int64 ones:
+    4.3 GB on one chip, 2.15 GB a mesh device, beside 8.87 / 4.45 GB of
+    tables).  This is the test that keeps ring scatter and head update
+    ONE program: if it ever fails, they go back to two programs fed the
+    same staged operand."""
+    from antidote_tpu.store import typed_table
+
+    mesh = Mesh(np.array(topo.devices), ("shard",))
+    placed = NamedSharding(mesh, PartitionSpec("shard"))
+    one = SingleDeviceSharding(topo.devices[0])
+    if placement == "one_chip":
+        rows, devices, table_sh, staged_sh = 65_536, 1, one, one
+    else:
+        rows, devices = 131_072, 4
+        table_sh, staged_sh = placed, NamedSharding(mesh, PartitionSpec())
+    cfg = AntidoteConfig(n_shards=16, max_dcs=8, keys_per_table=rows,
+                         use_pallas=True)
+    # a few rows are allocated here; the shapes compiled are the real ones
+    table = TypedTable(get_type("set_aw"), cfg, n_rows=8)
+    if placement == "mesh4":
+        table.set_sharding(placed)  # placement only: nothing is put anywhere
+    p, mb = cfg.n_shards, 64
+
+    def tables(shards, sharding):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(
+                (shards, rows) + x.shape[2:], x.dtype, sharding=sharding),
+            ((table.ops_a, table.ops_b, table.ops_vc, table.ops_origin),
+             (table.head, table.head_vc)))
+
+    rings, head = tables(p, table_sh)
+    compiled = table._commit_scatter_for(window).lower(
+        *rings, *head, jax.ShapeDtypeStruct(
+            (mb, table._staged_cols), jnp.int32, sharding=staged_sh),
+    ).compile()
+    text = compiled.as_text()
+    assert f"jit_antidote_commit_scatter_w{window}" in text
+    for collective in ("all-gather", "all-reduce", "all-to-all",
+                       "collective-permute"):
+        assert collective not in text, collective
+    mem = compiled.memory_analysis()
+    held = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+               for x in jax.tree.leaves((rings, head))) // devices
+    assert mem.alias_size_in_bytes >= held, "a donated table is copied"
+    block_rings, block_head = tables(p // devices, one)
+    keys = [jax.ShapeDtypeStruct((mb,), jnp.int32, sharding=one)] * 4
+    head_alone = jax.jit(
+        typed_table._head_update_body(table.ty, cfg, window),
+        donate_argnums=(0, 1),
+    ).lower(*block_head, *block_rings, *keys).compile().memory_analysis()
+    assert (mem.temp_size_in_bytes
+            <= head_alone.temp_size_in_bytes + 64 * 2**20), (
+        f"fused {mem.temp_size_in_bytes / 1e9:.3f} GB of temporaries, the "
+        f"head update alone {head_alone.temp_size_in_bytes / 1e9:.3f} GB")
+    reserved = mem.temp_size_in_bytes + mem.generated_code_size_in_bytes
+    table_share = 8.87e9 if devices == 1 else 4.45e9   # PERF §4, in use
+    assert table_share + reserved < 16e9
+    print(f"commit_scatter_w{window} {placement}: tables "
+          f"{held / 1e9:.3f} GB aliased, temporaries "
+          f"{mem.temp_size_in_bytes / 1e9:.3f} GB (head update alone "
+          f"{head_alone.temp_size_in_bytes / 1e9:.3f} GB) a device")

@@ -546,9 +546,12 @@ def lowered_names(tmp_path_factory):
                 t_old = None
                 for i in range(6):       # ring of 4: GC; 2 slots: promotion
                     t = node.start_transaction()
+                    # the counter twice: the commit's whole-ring window
                     node.update_objects(
                         [("s", "set_aw", "b", ("add", f"e{i}")),
-                         ("c", "counter_pn", "b", ("increment", 1))], t)
+                         ("c", "counter_pn", "b", ("increment", 1))]
+                        + [("c", "counter_pn", "b", ("increment", 1))] * (i == 5),
+                        t)
                     node.commit_transaction(t)
                     if i == 3:
                         t_old = node.start_transaction()
@@ -573,7 +576,7 @@ def lowered_names(tmp_path_factory):
 
 
 PROGRAMS = [
-    "antidote_commit_scatter_ring", "antidote_commit_scatter_head_w1",
+    "antidote_commit_scatter_w1", "antidote_commit_scatter_w0",
     "antidote_gc", "antidote_tier_promote", "antidote_head_gather",
     "antidote_head_gather_routed", "antidote_read_latest",
     "antidote_read_resolved_", "antidote_freeze_serving_copy",

@@ -7,6 +7,7 @@ incomplete-read detection.
 """
 
 import numpy as np
+import pytest
 
 from antidote_tpu.crdt import get_type
 from antidote_tpu.crdt.blob import BlobStore
@@ -442,3 +443,193 @@ def test_epoch_lru_retention(cfg):
     caps = sorted(int(e["cap"][0]) for e in t.epochs)
     assert int(pin0[0]) in caps
     assert len(t.epochs) == 2
+
+
+# ---------------------------------------------------------------------------
+# append: one staged operand, one device program — against a plain fold
+# ---------------------------------------------------------------------------
+class PlainFold:
+    """What ``TypedTable.append`` must leave behind, kept in dicts: every
+    effect applied to its key's head one at a time, in commit order, and
+    each key's ring since its last fold (a ring that would overflow is
+    folded first; a batch holding more than a ring of one key goes in
+    ring-sized pieces)."""
+
+    def __init__(self, ty, cfg):
+        self.ty, self.cfg = ty, cfg
+        self.rings, self.heads, self.head_vcs = {}, {}, {}
+
+    def head(self, key):
+        if key not in self.heads:
+            self.heads[key] = {
+                f: np.zeros(shape, dtype)
+                for f, (shape, dtype) in self.ty.state_spec(self.cfg).items()
+            }
+            self.head_vcs[key] = np.zeros(self.cfg.max_dcs, np.int32)
+        return self.heads[key]
+
+    def apply(self, s, r, a, b, vc, o):
+        import jax.numpy as jnp
+
+        new = self.ty.apply(
+            self.cfg, {f: jnp.asarray(x) for f, x in self.head((s, r)).items()},
+            jnp.asarray(a), jnp.asarray(b), jnp.asarray(vc), o)
+        self.heads[s, r] = {f: np.asarray(x) for f, x in new.items()}
+        self.head_vcs[s, r] = np.maximum(self.head_vcs[s, r], vc)
+
+    def ring_batch(self, batch):
+        k = self.cfg.ops_per_key
+        by_key = {}
+        for s, r, *eff in batch:
+            by_key.setdefault((s, r), []).append(eff)
+        for key, effs in by_key.items():
+            ring = self.rings.setdefault(key, [])
+            for c in range(0, len(effs), k):
+                if len(ring) + len(effs[c:c + k]) > k:
+                    ring.clear()
+                ring.extend(effs[c:c + k])
+
+
+def _append_and_compare(tyname, cfg, batches, sharding=None):
+    """``batches``: lists of (shard, row, op) — or (shard, row, (eff_a,
+    eff_b)) for an effect given as its lanes — appended batch by batch,
+    one commit VC an effect.  After each batch the table's head, rings
+    and ``n_ops`` must equal the plain fold's, and a ``read_resolved`` at
+    the newest clock what the type resolves the folded heads to."""
+    ty = get_type(tyname)
+    table = TypedTable(ty, cfg, sharding=sharding)
+    fold, blobs = PlainFold(ty, cfg), BlobStore()
+    clock = np.zeros(cfg.max_dcs, np.int32)
+    for batch in batches:
+        effects = []
+        for s, r, op in batch:
+            lanes = ([e[:2] for e in ty.downstream(
+                op, fold.head((s, r)), blobs, cfg)]
+                if isinstance(op[0], str) else [op])
+            for a, b in lanes:
+                clock[0] += 1
+                eff = (s, r, np.asarray(a, np.int64), np.asarray(b, np.int32),
+                       clock.copy(), 0)
+                effects.append(eff)
+                fold.apply(*eff)  # a remove observes the batch's own adds
+        fold.ring_batch(effects)
+        cols = list(zip(*effects))
+        table.append(np.asarray(cols[0]), np.asarray(cols[1]),
+                     np.stack(cols[2]), np.stack(cols[3]), np.stack(cols[4]),
+                     np.asarray(cols[5], np.int32))
+        for (s, r), ring in fold.rings.items():
+            n = len(ring)
+            assert table.n_ops[s, r] == n, (s, r)
+            for i, dev in enumerate((table.ops_a, table.ops_b, table.ops_vc,
+                                     table.ops_origin)):
+                np.testing.assert_array_equal(
+                    np.asarray(dev)[s, r, :n],
+                    np.stack([e[i] for e in ring]), err_msg=f"ring {i} {s, r}")
+            for f, x in fold.heads[s, r].items():
+                np.testing.assert_array_equal(
+                    np.asarray(table.head[f])[s, r], x, err_msg=f"{f} {s, r}")
+            np.testing.assert_array_equal(
+                np.asarray(table.head_vc)[s, r], fold.head_vcs[s, r])
+        keys = sorted(fold.heads)
+        resolved, _, complete = table.read_resolved(
+            [s for s, _ in keys], [r for _, r in keys],
+            np.tile(clock, (len(keys), 1)))
+        assert np.asarray(complete).all()
+        want = {f: np.stack([fold.heads[key][f] for key in keys])
+                for f in fold.heads[keys[0]]}
+        if ty.resolve_spec(cfg) is not None:
+            want = ty.resolve(cfg, want)
+        for f, x in want.items():
+            np.testing.assert_array_equal(
+                np.asarray(resolved[f])[:len(keys)], np.asarray(x), err_msg=f)
+    return table
+
+
+def _spread(n, cfg):
+    """``n`` distinct (shard, row) pairs over every shard."""
+    return [(i % cfg.n_shards, i // cfg.n_shards) for i in range(n)]
+
+
+def _lww(handle, ts, cfg):
+    ty = get_type("register_lww")
+    return (np.asarray([handle, ts], np.int64),
+            np.zeros(ty.eff_b_width(cfg), np.int32))
+
+
+BIG = [2**31 + 5, 2**40 + 3]
+
+#: name -> (type, batches(cfg)); every case crosses the staged operand
+APPEND_CASES = {
+    # int64 lanes beyond 2^32, both signs: exact through the lo/hi halves
+    "counter_big_deltas": ("counter_pn", lambda cfg: [
+        [(0, 0, ("increment", BIG[0])), (1, 0, ("decrement", BIG[0])),
+         (2, 5, ("increment", BIG[1])), (3, 7, ("decrement", BIG[1]))],
+        [(0, 0, ("decrement", BIG[1])), (0, 0, ("increment", BIG[0])),
+         (3, 7, ("increment", 1))],
+    ]),
+    "set_aw_adds_removes": ("set_aw", lambda cfg: [
+        [(0, 1, ("add", "x")), (0, 1, ("add", "y")), (2, 3, ("add", "x"))],
+        [(0, 1, ("remove", "x")), (2, 3, ("add_all", ["p", "q"])),
+         (1, 1, ("add", "z"))],
+        [(0, 1, ("add", "x")), (2, 3, ("remove_all", ["x", "q"]))],
+    ]),
+    "lww_2pow45_timestamps": ("register_lww", lambda cfg: [
+        [(0, 0, _lww(7, 2**45 + 9, cfg)), (1, 2, _lww(8, 2**45, cfg))],
+        [(0, 0, _lww(9, 2**45 + 8, cfg)),     # older: loses
+         (1, 2, _lww(5, 2**45 + 2**33, cfg)),  # newer in the high half
+         (1, 2, _lww(6, 2**45 + 2**33, cfg))],  # tie: the handle decides
+    ]),
+    # window 0 (the whole-ring scan): one key more than once in a batch
+    "one_key_twice": ("counter_pn", lambda cfg: [
+        [(1, 4, ("increment", 3)), (2, 2, ("increment", 1)),
+         (1, 4, ("increment", 4))],
+    ]),
+    # more than two rings of one key in one batch: gc, then ring-sized
+    # pieces with a gc between them; a bystander key rides the first piece
+    "one_key_17_times": ("counter_pn", lambda cfg: [
+        [(1, 4, ("increment", 1))] * 3,
+        [(1, 4, ("increment", 10 ** i if i < 9 else -i))
+         for i in range(2 * cfg.ops_per_key + 1)] + [(0, 0, ("increment", 5))],
+    ]),
+    "ring_overflow_folds_first": ("counter_pn", lambda cfg: [
+        [(3, 1, ("increment", 2))] * (cfg.ops_per_key - 1),
+        [(3, 1, ("increment", 7)), (3, 1, ("decrement", 1)),
+         (3, 2, ("increment", 1))],
+    ]),
+    "batch_of_1": ("counter_pn", lambda cfg: [[(2, 9, ("increment", 1))]]),
+    # the last batch bucket exactly, and one past it (the next multiple)
+    "batch_of_64": ("counter_pn", lambda cfg: [
+        [(s, r, ("increment", s * 100 + r)) for s, r in _spread(64, cfg)]]),
+    "batch_of_65": ("counter_pn", lambda cfg: [
+        [(s, r, ("decrement", s * 100 + r + 1)) for s, r in _spread(65, cfg)],
+        [(s, r, ("increment", 2**33)) for s, r in _spread(3, cfg)]]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(APPEND_CASES))
+def test_append_equals_plain_fold(cfg, case):
+    tyname, batches = APPEND_CASES[case]
+    batches = batches(cfg)
+    table = _append_and_compare(tyname, cfg, batches)
+    # one transfer and one program an append call (a split batch makes
+    # more calls, never more than it has effects)
+    assert table.scatter_transfers == table.scatter_launches
+    assert len(batches) <= table.scatter_launches <= sum(map(len, batches))
+
+
+@pytest.mark.parametrize("case", ["counter_big_deltas", "one_key_17_times",
+                                  "set_aw_adds_removes"])
+def test_append_on_a_mesh_placed_table(cfg, case):
+    """The same cases on a table placed over four (virtual) devices, one
+    shard each: the program runs under the table's ``shard_map``, the
+    staged operand replicated, and every table stays in its placement."""
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    placed = NamedSharding(Mesh(np.array(jax.devices()[:4]), ("shard",)),
+                           PartitionSpec("shard"))
+    tyname, batches = APPEND_CASES[case]
+    table = _append_and_compare(tyname, cfg, batches(cfg), sharding=placed)
+    for x in jax.tree.leaves((table.ops_a, table.ops_b, table.ops_vc,
+                              table.ops_origin, table.head, table.head_vc)):
+        assert x.sharding.is_equivalent_to(placed, x.ndim)

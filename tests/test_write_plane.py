@@ -353,3 +353,53 @@ def test_write_plane_status_block(tmp_path, cfg):
     assert wp["merge_width"]["count"] >= 1
     assert wp["cert_bypass_total"] >= 1
     assert {"count", "mean", "p50", "p99"} <= set(wp["fsync_batch"])
+
+
+def test_a_merged_group_crosses_to_the_device_once(cfg):
+    """A merged group of N sub-groups on one table is one staged operand
+    and one device program: `write_plane.scatter` moves by exactly one
+    group, one transfer, one launch; `store.scatter_transfers`' file
+    reads it off two statuses, and reads nothing — without raising — off
+    a status that lacks the block (the parent's)."""
+    import json
+    import os
+    from types import SimpleNamespace
+
+    from benchmarks.readers import status_delta
+
+    node = AntidoteNode(cfg)
+    node.update_objects([("warm", "counter_pn", "b", ("increment", 1))])
+    pre = node.status()
+    txns = []
+    for j in range(5):
+        t = node.start_transaction()
+        node.update_objects([(f"k{j}", "counter_pn", "b", ("increment", j))], t)
+        txns.append(t)
+    outs = node.txm.commit_transactions_group(txns)
+    assert not any(isinstance(o, Exception) for o in outs)
+    t = node.start_transaction()      # a read-only commit: no group
+    node.txm.commit_transactions_group([t])
+    post = node.status()
+    moved = {k: post["write_plane"]["scatter"][k] - v
+             for k, v in pre["write_plane"]["scatter"].items()}
+    assert moved == {"groups": 1, "transfers": 1, "launches": 1}
+    vals, _ = node.read_objects([(f"k{j}", "counter_pn", "b")
+                                 for j in range(5)])
+    assert vals == list(range(5))
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "layer_metrics",
+                           "store.scatter_transfers.json")) as f:
+        spec = json.load(f)
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        entry, = [m for m in json.load(f)["per_layer"]
+                  if m["name"] == spec["name"]]
+    assert entry["source"] == "program_counter"
+    for key in ("unit", "better", "layer", "moves", "workloads"):
+        assert spec[key] == entry[key], key
+    ctx = lambda a, b: SimpleNamespace(status={"window": (a, b)})
+    assert status_delta.read(spec, ctx(pre, post)) == 1.0
+    assert status_delta.read(spec, ctx(post, post)) is None
+    for st in (pre, post):
+        del st["write_plane"]["scatter"]
+    assert status_delta.read(spec, ctx(pre, post)) is None
