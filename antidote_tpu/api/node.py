@@ -458,6 +458,11 @@ class AntidoteNode:
             # what the scatter phase sent to the device: commit groups,
             # host arrays transferred, device programs launched
             "scatter": self.store.scatter_status(),
+            # ring overflow and slot-tier promotion, both inside the
+            # scatter phase: `gc` (launches, rows, sum_ms) and `tiers`
+            # (promotions by destination tier, promote_sum_ms, tables
+            # built ahead of need, grows, rows a shard)
+            **self.store.tier_status(),
         }
         # checkpoint / fast-restart view (ISSUE 8): last published image
         # stamp, size, age, and how much tail a crash-now restart would

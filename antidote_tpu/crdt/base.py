@@ -274,9 +274,15 @@ def compact_top(elems, present, top: int):
     the full state for keys whose count exceeds ``top``."""
     import jax.numpy as jnp
 
-    order = jnp.argsort(~present, axis=-1, stable=True)[..., :top]
-    top_elems = jnp.take_along_axis(jnp.where(present, elems, 0), order, axis=-1)
-    return top_elems, present.sum(-1).astype(jnp.int32)
+    # the k-th present slot is the one whose running count of present
+    # slots reads k + 1: ``top`` masked sums, no sort (a stable argsort
+    # over a tier's 1,024 slots is a sorting network that takes the TPU's
+    # compiler 11 s, and over 4,096 slots 18)
+    rank = jnp.cumsum(present, axis=-1, dtype=jnp.int32)
+    top_elems = jnp.stack(
+        [jnp.sum(jnp.where(present & (rank == k + 1), elems, 0), axis=-1)
+         for k in range(top)], axis=-1)
+    return top_elems, rank[..., -1]
 
 
 def pack_a(*vals: int, width: int) -> np.ndarray:

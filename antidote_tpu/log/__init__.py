@@ -12,10 +12,12 @@ shard's log to rebuild tables, clocks and op-id counters
 
 from __future__ import annotations
 
+import collections
 import glob as _glob
 import json
 import os
 import re
+import threading
 import time
 from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
@@ -39,6 +41,20 @@ __all__ = ["LogManager", "SegmentedShardWAL", "ShardWAL", "FsyncTicket",
            "gen_segment_paths", "wholly_below"]
 
 _META_FILE = "antidote_meta.json"
+
+
+def _hashable(key):
+    """A record's key as a dict key (msgpack gives a tuple key back as a
+    list; the store's ``freeze_key`` does the same)."""
+    if isinstance(key, list):
+        return tuple(_hashable(k) for k in key)
+    return key
+
+
+def _history_entry(eff_a, eff_b, commit_vc, origin) -> tuple:
+    # copies: the list outlives the commit group's own arrays
+    return (np.array(eff_a, np.int64), np.array(eff_b, np.int32),
+            np.array(commit_vc, np.int32), int(origin))
 
 
 class LogDirMismatch(RuntimeError):
@@ -331,6 +347,14 @@ class LogManager:
         #: metrics hook — called with barriers-covered-per-fsync-pass
         #: (AntidoteNode points it at antidote_wal_fsync_batch.observe)
         self.on_fsync_batch = None
+        #: (key, bucket) -> every logged effect of the key above the
+        #: floor, in append order, as (eff_a, eff_b, commit_vc, origin):
+        #: an index into the log by key, started by the first
+        #: :meth:`key_history` of a key (one walk of its shard's files)
+        #: and kept by every append after it.  Least recently asked
+        #: keys go first; the lock orders a walk against an append
+        self._hist: "collections.OrderedDict" = collections.OrderedDict()
+        self._hist_lock = threading.Lock()
 
     def _fsync_batch(self, n: int) -> None:
         cb = self.on_fsync_batch
@@ -355,6 +379,10 @@ class LogManager:
         new_hashes = [h for h, _ in blobs]
         for h in new_hashes:
             self._blob_seen[shard].add(h)
+        if self._hist:
+            hist = self._hist.get((_hashable(key), bucket))
+            if hist is not None:
+                hist.append(_history_entry(eff_a, eff_b, commit_vc, origin))
         payload = msgpack.packb({
             "k": key,
             "b": bucket,
@@ -377,17 +405,20 @@ class LogManager:
         append sequence and blob-dedup memory back (the WAL itself heals
         its torn frame), so a refused write never leaves a permanent
         op-id GAP for egress to publish."""
-        opid, new_hashes, payload = self._mint_payload(
-            shard, key, type_name, bucket, eff_a, eff_b, commit_vc,
-            origin, blob_refs)
-        try:
-            self.wals[shard].current.append_packed(pack_frames([payload]))
-        except BaseException:
-            self.op_ids[shard, origin] -= 1
-            self.seqs[shard] -= 1
-            for h in new_hashes:
-                self._blob_seen[shard].discard(h)
-            raise
+        with self._hist_lock:
+            opid, new_hashes, payload = self._mint_payload(
+                shard, key, type_name, bucket, eff_a, eff_b, commit_vc,
+                origin, blob_refs)
+            try:
+                self.wals[shard].current.append_packed(
+                    pack_frames([payload]))
+            except BaseException:
+                self.op_ids[shard, origin] -= 1
+                self.seqs[shard] -= 1
+                for h in new_hashes:
+                    self._blob_seen[shard].discard(h)
+                self._hist.clear()  # (it may hold the refused effect)
+                raise
         return opid
 
     def log_effects(self, entries) -> None:
@@ -406,6 +437,10 @@ class LogManager:
         ``entries``: iterable of ``log_effect`` argument tuples
         ``(shard, key, type_name, bucket, eff_a, eff_b, commit_vc,
         origin, blob_refs)``."""
+        with self._hist_lock:
+            self._log_effects_locked(entries)
+
+    def _log_effects_locked(self, entries) -> None:
         op_snap = self.op_ids.copy()
         seq_snap = self.seqs.copy()
         added: List[Tuple[int, int]] = []  # (shard, blob hash) logged
@@ -436,6 +471,7 @@ class LogManager:
             self.seqs[:] = seq_snap
             for s, h in added:
                 self._blob_seen[s].discard(h)
+            self._hist.clear()  # (it may hold refused effects)
             raise
 
     def log_effect_groups(self, groups: Sequence) -> List[Optional[Exception]]:
@@ -535,6 +571,8 @@ class LogManager:
         image).  Caller holds the commit lock when the store is live."""
         self.floor_seqs = np.asarray(floors, np.int64).copy()
         self.chain_floor = np.asarray(chain_floor, np.int64).copy()
+        with self._hist_lock:
+            self._hist.clear()  # histories reach down to the old floor
         # fresh appends must mint sequences above everything the image
         # covers even before any tail record is replayed
         np.maximum(self.seqs, self.floor_seqs, out=self.seqs)
@@ -659,6 +697,8 @@ class LogManager:
         self.floor_seqs[shard] = 0
         self.chain_floor[shard] = 0
         self._blob_seen[shard].clear()
+        with self._hist_lock:
+            self._hist.clear()
         self.shard_resets[shard] = self.shard_resets.get(shard, 0) + 1
         _set_dir_meta_key(self.dir, "shard_resets",
                           {str(k): v for k, v in self.shard_resets.items()})
@@ -689,15 +729,45 @@ class LogManager:
                 continue
             yield rec
 
-    def replay_key(self, shard: int, key, bucket: str) -> List[dict]:
-        """Scan one shard's log for a key's ops (the reference's whole-log
-        scan + filter, /root/reference/src/logging_vnode.erl:663-702)."""
-        from antidote_tpu.store.kv import freeze_key
+    #: keys whose histories :meth:`key_history` keeps at a time
+    HISTORY_KEYS = 4096
 
-        return [
-            r for r in self.replay_shard(shard)
-            if freeze_key(r["k"]) == key and r["b"] == bucket
-        ]
+    def key_history(self, shard: int, key, bucket: str) -> list:
+        """Every logged effect of one key above the shard's floor, in
+        append order, as (eff_a i64[], eff_b i32[], commit_vc i32[D],
+        origin) — the reference scans its whole log for a key on every
+        such read (/root/reference/src/logging_vnode.erl:663-702); here
+        the first call for a key walks the shard's files once (headers
+        only but for the key's own records) and the appends that follow
+        keep the list, so a later call costs nothing.  The list is the
+        log's own: read it, do not change it."""
+        hk = (_hashable(key), bucket)
+        with self._hist_lock:
+            hist = self._hist.get(hk)
+            if hist is not None:
+                self._hist.move_to_end(hk)
+                return hist
+            for w in self.wals[shard].segs:
+                w.flush()
+            # a record's payload is a map that opens with "k" and "b"
+            # (_mint_payload): match their packed bytes, decode the rest
+            prefix = msgpack.packb(
+                {"k": key, "b": bucket}, use_bin_type=True)[1:]
+            floor = int(self.floor_seqs[shard])
+            hist = [
+                _history_entry(np.frombuffer(r["a"], np.int64),
+                               np.frombuffer(r["eb"], np.int32),
+                               r["vc"], r["o"])
+                for r in replay_segments(
+                    shard_segment_paths(self.dir, shard, self.n_segments),
+                    prefix)
+                if not floor or (r.get("q") is not None
+                                 and int(r["q"]) > floor)
+            ]
+            self._hist[hk] = hist
+            while len(self._hist) > self.HISTORY_KEYS:
+                self._hist.popitem(last=False)
+            return hist
 
     def close(self) -> None:
         self._fsync.close()

@@ -176,6 +176,12 @@ class ShardWAL:
         self._end = start + len(buf)
         self.pending_bytes += len(buf)
 
+    def flush(self) -> None:
+        """Hand buffered appends to the file, for a reader of it (the
+        native handle writes through; the fallback's file buffers)."""
+        if self._f is not None:
+            self._f.flush()
+
     def _tell_fs(self) -> int:
         """Real end-of-file offset from the filesystem (open-time seed
         for the host-tracked offset; includes any torn tail a crash
@@ -431,16 +437,17 @@ class GroupFsyncCoordinator:
             t.done(RuntimeError("fsync coordinator closed"))
 
 
-def replay_segments(paths: Sequence[str]) -> Iterator[dict]:
+def replay_segments(paths: Sequence[str],
+                    prefix: Optional[bytes] = None) -> Iterator[dict]:
     """Merge several WAL segment files of ONE shard back into commit
     order.  Records carry a per-shard append sequence ``"q"``; legacy
     records (pre-segmentation) have none, exist only in segment 0, and
     precede every sequenced record, so positional order within segment
     0 followed by a q-merge across all segments reconstructs the exact
-    append order."""
+    append order.  ``prefix``: as :func:`replay`'s."""
 
     def keyed(path):
-        for pos, rec in enumerate(replay(path)):
+        for pos, rec in enumerate(replay(path, prefix)):
             q = rec.get("q")
             yield ((0, pos) if q is None else (1, int(q))), rec
 
@@ -463,12 +470,31 @@ def wholly_below(path: str, floor: int) -> bool:
     return True
 
 
-def replay(path: str) -> Iterator[dict]:
+def replay(path: str, prefix: Optional[bytes] = None) -> Iterator[dict]:
     """Yield records from a WAL file; stops cleanly at a torn tail
-    (crash mid-append), like disk_log repair-on-open."""
+    (crash mid-append), like disk_log repair-on-open.  With ``prefix``
+    (the packed bytes a wanted record's payload has right after its map
+    header) only the records that carry it are checked and decoded: the
+    walk over the others reads their headers alone."""
     if not os.path.exists(path):
         return
     with open(path, "rb") as f:
+        data = f.read() if prefix is not None else None
+        if data is not None:
+            off, end, hdr_n = 0, len(data), _HDR.size
+            while off + hdr_n <= end:
+                magic, ln, crc = _HDR.unpack_from(data, off)
+                off += hdr_n
+                if magic != _MAGIC or off + ln > end:
+                    return
+                if data.startswith(prefix, off + 1):
+                    payload = data[off:off + ln]
+                    if (zlib.crc32(payload) & 0xFFFFFFFF) != crc:
+                        return
+                    yield msgpack.unpackb(payload, raw=False,
+                                          strict_map_key=False)
+                off += ln
+            return
         while True:
             hdr = f.read(_HDR.size)
             if len(hdr) < _HDR.size:

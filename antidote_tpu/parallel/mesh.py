@@ -139,16 +139,7 @@ class MeshServingPlane:
         if t.sharding is self.sharding:
             return
         t.set_sharding(self.sharding)
-        put = lambda x: jax.device_put(x, self.sharding)
-        t.snap = {f: put(x) for f, x in t.snap.items()}
-        t.head = {f: put(x) for f, x in t.head.items()}
-        t.snap_vc = put(t.snap_vc)
-        t.snap_seq = put(t.snap_seq)
-        t.ops_a = put(t.ops_a)
-        t.ops_b = put(t.ops_b)
-        t.ops_vc = put(t.ops_vc)
-        t.ops_origin = put(t.ops_origin)
-        t.head_vc = put(t.head_vc)
+        t._set_tree(jax.device_put(t._tree(), self.sharding))
         t.invalidate_epochs()
 
     # ------------------------------------------------------------------

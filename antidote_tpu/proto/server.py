@@ -264,6 +264,7 @@ class ProtocolServer:
         self.proxy: Optional[ProxyPlane] = None
         self._server_proxy = bool(server_proxy)
         self._lock = threading.Lock()
+        self._t_boot = time.monotonic()
         self._txns: Dict[int, Transaction] = {}
         #: metric sink for the overload planes: the node's own registry
         #: when it has one; a ClusterNode facade exposes its member's
@@ -2269,6 +2270,9 @@ class ProtocolServer:
             }
 
         out = {
+            # the server's own clock: the time base of a share of a span
+            # (a counter's sum_ms over it: status_delta)
+            "uptime_ms": round((time.monotonic() - self._t_boot) * 1e3, 3),
             "epoch_reads": self._epoch_reads,
             "stages": {
                 "decode": us(m.stage_decode_seconds),
@@ -2321,6 +2325,9 @@ class ProtocolServer:
             if txm.store.mesh is not None:
                 out["mesh"] = txm.store.mesh.status()
             out["materializer"] = txm.store.materializer_status()
+            # the versioned read of the locked plane: fold launches, rows
+            # folded, and reads the head answered against reads a fold did
+            out["fold"] = txm.store.fold_status()
         return out
 
     # ------------------------------------------------------------------

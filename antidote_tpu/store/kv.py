@@ -12,9 +12,14 @@ like Antidote's ``{Key, Type, Bucket}`` bound objects.
 
 from __future__ import annotations
 
+import atexit
+import collections
+import functools
+import threading
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from antidote_tpu.config import AntidoteConfig
@@ -22,7 +27,7 @@ from antidote_tpu.crdt import get_type, is_type
 from antidote_tpu.crdt.blob import BlobStore
 from antidote_tpu.obs.trace import span
 from antidote_tpu.store.router import shard_batch, shard_of
-from antidote_tpu.store.typed_table import TypedTable, _bucket
+from antidote_tpu.store.typed_table import TypedTable, _bucket, _cut
 
 BoundObject = Tuple[Any, str, str]  # (key, type_name, bucket)
 
@@ -39,6 +44,60 @@ BoundObject = Tuple[Any, str, str]  # (key, type_name, bucket)
 # ---------------------------------------------------------------------------
 _TIER_SCALE = 4
 _MAX_TIER = 8  # 4^8 = 65536x the base slot width
+#: rows a shard of a tier table starts with: sized by demand — under a
+#: Zipf mix a few dozen keys of a million outgrow the base slots, fewer
+#: each tier after — not by the base table's key count (a tier's row is
+#: 4x its predecessor's, so a tier as long as the base table would be as
+#: large as it).  A table that fills doubles (TypedTable._grow).
+_TIER_ROWS = (32, 16, 8)
+
+
+#: tier tables to build ahead of need (KVStore.prepare_tier), and the one
+#: thread of the process that builds them, one after the other: a compile
+#: uses every core, and two at once (two tiers, or two stores of a cluster
+#: in one process) would only slow the commit groups beside them.  The
+#: interpreter must not be torn down under a compile: at exit the thread
+#: is told to stop after the program it is at, and waited for.
+_BUILDS: "collections.deque" = collections.deque()
+_BUILD_LOCK = threading.Lock()
+_BUILDER: List[Any] = []          # the live builder thread, if any
+_EXITING = False
+
+
+def _build_tiers() -> None:
+    while True:
+        with _BUILD_LOCK:
+            if not _BUILDS or _EXITING:
+                _BUILDER.clear()
+                return
+            build = _BUILDS.popleft()
+        build()
+
+
+def _enqueue_build(build) -> None:
+    with _BUILD_LOCK:
+        _BUILDS.append(build)
+        if not _BUILDER:
+            _BUILDER.append(threading.Thread(
+                target=_build_tiers, daemon=True,
+                name="antidote-tier-prep"))
+            _BUILDER[0].start()
+
+
+def _join_tier_builder() -> None:
+    global _EXITING
+    _EXITING = True
+    for th in list(_BUILDER):
+        th.join(timeout=120.0)
+
+
+atexit.register(_join_tier_builder)
+
+
+def tier_rows(cfg: AntidoteConfig, tier: int) -> int:
+    """Rows a shard of tier ``tier``'s table is created with."""
+    return min(cfg.keys_per_table,
+               _TIER_ROWS[min(tier, len(_TIER_ROWS)) - 1])
 
 
 def split_tier(tname: str) -> Tuple[str, int]:
@@ -64,6 +123,11 @@ def scaled_cfg(cfg: AntidoteConfig, tier: int) -> AntidoteConfig:
         set_slots=cfg.set_slots * s,
         mv_slots=cfg.mv_slots * s,
         rga_slots=cfg.rga_slots * s,
+        # the Pallas kernels are tiled for the base table's widths, where
+        # the rows are: a tier table holds a few dozen rows, its kernels
+        # would take a minute to compile at 1,024 slots and fit no VMEM
+        # block at 4,096 — tiers fold and resolve in plain XLA
+        use_pallas=False,
     )
 
 
@@ -274,53 +338,6 @@ class Effect:
         self.blob_refs = list(blob_refs)
 
 
-def _make_promote_fn():
-    """One-launch tier promotion: move a key's whole device state (head,
-    snapshot versions, op ring) from its current table into a wider-slot
-    sibling, zero-padding the widened slot/lane axes (zeros are empty
-    slots in every slotted layout) and clearing the source row.  Version
-    seqs renumber above everything in the destination so the per-key
-    newest-version order survives the move.  Jitted per (src, dst) tier
-    pair — the previous eager form was ~25 separate device dispatches,
-    a visible serving-latency spike per hot-key tier crossing."""
-    import jax.numpy as jnp
-
-    from antidote_tpu.obs.trace import device_program
-
-    @device_program("tier_promote", donate_argnums=(0, 1))
-    def fn(src, dst, shard, row, new_row, seq_shift):
-        def emb(v, dshape):
-            out = jnp.zeros(dshape, v.dtype)
-            return out.at[tuple(slice(0, s) for s in v.shape)].set(v)
-
-        out_d = {"snap": {}, "head": {}}
-        out_s = {"snap": {}, "head": {}}
-        for grp in ("snap", "head"):
-            for f in src[grp]:
-                v = src[grp][f][shard, row]
-                out_d[grp][f] = dst[grp][f].at[shard, new_row].set(
-                    emb(v, dst[grp][f].shape[2:])
-                )
-                out_s[grp][f] = src[grp][f].at[shard, row].set(0)
-        seq = src["snap_seq"][shard, row]
-        seq = jnp.where(seq > 0, seq + seq_shift, 0)
-        out_d["snap_seq"] = dst["snap_seq"].at[shard, new_row].set(seq)
-        for name in ("snap_vc", "ops_vc", "ops_origin", "head_vc"):
-            out_d[name] = dst[name].at[shard, new_row].set(
-                src[name][shard, row]
-            )
-        for name in ("ops_a", "ops_b"):
-            out_d[name] = dst[name].at[shard, new_row].set(
-                emb(src[name][shard, row], dst[name].shape[2:])
-            )
-        for name in ("snap_vc", "snap_seq", "ops_a", "ops_b", "ops_vc",
-                     "ops_origin", "head_vc"):
-            out_s[name] = src[name].at[shard, row].set(0)
-        return out_s, out_d
-
-    return fn
-
-
 #: distinct miss marker (None is a legitimate cached value)
 _CACHE_MISS = object()
 
@@ -427,11 +444,39 @@ class KVStore:
         self.applied_vc = np.zeros((cfg.n_shards, cfg.max_dcs), np.int32)
         #: per-type cached bottom (never-written) resolved view
         self._bottom_cache: Dict[str, Dict[str, np.ndarray]] = {}
-        #: keys promoted to a wider slot tier (observability + tests)
+        #: keys promoted to a wider slot tier (observability + tests),
+        #: by destination tier, and the seconds the promotions took
         self.promotions = 0
+        self.promotions_by_tier: Dict[int, int] = {}
+        self.promote_seconds = 0.0
+        #: tier tables asked of the building thread (tname -> True), those
+        #: built and not yet asked for (the lock orders the thread's
+        #: hand-over against :meth:`table`'s), and how many were started
+        self._tier_prep: Dict[str, Any] = {}
+        self._tier_ready: Dict[str, TypedTable] = {}
+        self._tier_lock = threading.Lock()
+        self.tier_preps = 0
         #: per-strategy replay-path fold dispatch counts (the
         #: materializer status block; see _fold_over_ring)
         self.replay_fold_dispatches: Dict[str, int] = {}
+        #: (type, tier config) -> jitted serial fold of a replayed log
+        #: (compiled once a padded log length)
+        self._replay_fold_fns: Dict[tuple, Any] = {}
+        #: (key, bucket) -> [tiered name, state, vc, tail, pos]: a
+        #: below-coverage read's answer kept as the next one's base —
+        #: ``state`` holds exactly the effects among the key's first
+        #: ``pos`` logged ones (LogManager.key_history) that are <= ``vc``,
+        #: ``tail`` the others among them, in log order.  Least recently
+        #: read keys go first; the lock is the replay's own (a read
+        #: inside a transaction and a remote commit hold different ones)
+        self._replay_bases: "collections.OrderedDict" = (
+            collections.OrderedDict())
+        self._replay_lock = threading.Lock()
+        #: below-coverage reads answered from the log, the logged
+        #: effects they folded, and the host seconds they took
+        self.replays = 0
+        self.replay_records = 0
+        self.replay_seconds = 0.0
         #: type_name -> whether the type has slot accounting (cached so the
         #: apply_effects demand pre-pass skips unslotted effects cheaply)
         self._slotted: Dict[str, bool] = {}
@@ -465,11 +510,6 @@ class KVStore:
         #: commit groups whose effects went to the device (node status
         #: ``write_plane.scatter``, beside the tables' own tallies)
         self.scatter_groups = 0
-        #: (src_tname, dst_tname) -> jitted one-launch row promotion —
-        #: ~25 eager device ops per promotion otherwise, each a dispatch
-        #: (and on first use a compile), which made every hot-key tier
-        #: crossing a serving latency spike
-        self._promote_fns: Dict[Tuple[str, str], Any] = {}
         # --- serving epochs + hot-key snapshot cache (ISSUE 5) ---------
         #: NodeMetrics (attached by AntidoteNode) — snapshot-cache and
         #: epoch-publish counters land here when present
@@ -577,29 +617,92 @@ class KVStore:
         return hit
 
     # ------------------------------------------------------------------
+    def _new_table(self, tname: str) -> TypedTable:
+        base, tier = split_tier(tname)
+        return TypedTable(
+            get_type(base), scaled_cfg(self.cfg, tier),
+            n_rows=None if tier == 0 else tier_rows(self.cfg, tier),
+            sharding=self.sharding, metrics=self.metrics,
+        )
+
     def table(self, tname: str) -> TypedTable:
-        """Table for a (possibly tiered) name; tier tables are built with
-        x4-per-tier slot widths and start small (few keys ever promote)."""
+        """Table for a (possibly tiered) name.  A tier table has x4 slot
+        widths per tier and starts with :func:`tier_rows` rows a shard —
+        by demand, not by the base table's key count — and doubles when
+        a shard of it fills (``TypedTable._grow``).  It is taken from
+        :meth:`prepare_tier`'s hands when that has built it ahead of
+        need, and built here otherwise — also when that is still at it:
+        a commit group never waits for a compile it can do without (the
+        thread goes on, and the table built here takes the programs it
+        compiled when it is done: ``TypedTable.adopt_programs``)."""
         t = self.tables.get(tname)
         if t is None:
-            base, tier = split_tier(tname)
-            cfg = scaled_cfg(self.cfg, tier)
-            n_rows = None if tier == 0 else max(
-                self.cfg.keys_per_table // (_TIER_SCALE ** tier), 16
-            )
-            t = TypedTable(
-                get_type(base), cfg, n_rows=n_rows, sharding=self.sharding,
-                metrics=self.metrics,
-            )
+            with self._tier_lock:
+                t = self._tier_ready.pop(tname, None)
+            if t is None:
+                t = self._new_table(tname)
             # out-of-band mutations (grow/promote/handoff) invalidate the
             # table's frozen serving buffers; the store-wide epoch that
             # references them must die with them
             t.on_serving_invalidate = self.drop_serving_epoch
-            self.tables[tname] = t
+            with self._tier_lock:
+                # (either this sees the thread's table or the thread
+                # sees this one: the lock orders the two hand-overs)
+                self.tables[tname] = t
+                late = self._tier_ready.pop(tname, None)
+                if late is not None:
+                    t.adopt_programs(late)
         if t.metrics is None and self.metrics is not None:
             # metrics attach after store construction; adopt lazily
             t.metrics = self.metrics
         return t
+
+    def prepare_tier(self, tname: str) -> None:
+        """Have table ``tname`` built and its programs compiled on the
+        process's tier-building thread, off the commit path (caller
+        holds the commit lock): a synchronous build is 20 s of compile
+        inside a commit group on a cold cache, past what a forwarded
+        write waits.  The table stays out of ``tables``
+        until :meth:`table` is asked for it, so nothing else can reach
+        it meanwhile."""
+        if (tname in self.tables or tname in self._tier_prep
+                or tname in self._tier_ready or self.sharding is not None):
+            # (on a mesh every program runs on all devices, and two
+            # threads launching such programs can interleave their
+            # per-device queues: there the tier is built when needed,
+            # inside its commit group)
+            return
+        base, tier = split_tier(tname)
+        src = self.tables.get(tiered_name(base, tier - 1))
+        # the shapes of the tier below's row, taken here: its arrays are
+        # donated from under any other thread
+        src_row = None if src is None else jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape[2:], x.dtype),
+            src._tree())
+
+        def build():
+            try:
+                t = self._new_table(tname)
+                if not t.warm(src_row, stop=lambda: _EXITING):
+                    return
+                self._warm_replay(t.ty, t.cfg, with_base=tier == 1)
+                with self._tier_lock:
+                    live = self.tables.get(tname)
+                    if live is None:
+                        self._tier_ready[tname] = t
+                    else:
+                        # a promotion came first and built the table
+                        # itself: it takes the programs compiled here
+                        live.adopt_programs(t)
+            except Exception:  # noqa: BLE001 - table() then builds it
+                import logging
+
+                logging.getLogger(__name__).exception(
+                    "preparing tier table %s failed", tname)
+
+        self._tier_prep[tname] = True
+        self.tier_preps += 1
+        _enqueue_build(build)
 
     def locate(self, key, type_name: str, bucket: str, create: bool = True):
         """(tiered_name, shard, row) for a bound object; allocates on first
@@ -768,6 +871,11 @@ class KVStore:
                 cap is None or t.slots_ub[shard, row] + d <= cap
             ):
                 t.slots_ub[shard, row] += d
+                if (cap is not None and tier < _MAX_TIER
+                        and 2 * t.slots_ub[shard, row] > cap):
+                    # past half of its tier: have the next tier's table
+                    # and programs ready before the key needs them
+                    self.prepare_tier(tiered_name(base, tier + 1))
                 continue
             self._promote_key(dk, extra_demand=d, min_tier=need_t)
         # per-sub-group record build (blob intern rides along, as
@@ -886,6 +994,54 @@ class KVStore:
             "groups": self.scatter_groups,
             "transfers": sum(t.scatter_transfers for t in tables),
             "launches": sum(t.scatter_launches for t in tables),
+        }
+
+    def tier_status(self) -> Dict[str, Any]:
+        """``write_plane.gc`` and ``write_plane.tiers`` of the node
+        status: GC launches and the rows they folded into a snapshot
+        version; promotions by destination tier; tier tables built ahead
+        of need; rows a shard of each tier table has and how often a
+        table doubled."""
+        tables = dict(self.tables)
+        tiers = {t: v for t, v in tables.items() if split_tier(t)[1] > 0}
+        return {
+            "gc": {
+                "launches": sum(t.gc_launches for t in tables.values()),
+                "rows": sum(t.gc_rows for t in tables.values()),
+                "sum_ms": sum(t.gc_seconds for t in tables.values()) * 1e3,
+            },
+            "tiers": {
+                "promotions": self.promotions,
+                "promotions_by_tier": {
+                    str(k): v
+                    for k, v in sorted(self.promotions_by_tier.items())},
+                "promote_sum_ms": self.promote_seconds * 1e3,
+                "prepared": self.tier_preps,
+                "grows": sum(t.grows for t in tiers.values()),
+                "rows": {t: int(v.n_rows) for t, v in sorted(tiers.items())},
+                "rows_used": {t: int(v.used_rows.max())
+                              for t, v in sorted(tiers.items())},
+            },
+        }
+
+    def fold_status(self) -> Dict[str, Any]:
+        """``pipeline.fold`` of the node status: the versioned read's
+        launches, the rows it folded and the host seconds from launch to
+        answer; of the rows the locked read plane gathered, those the
+        head answered against those a fold did; and the reads below the
+        device's coverage that the log answered (``replays``), the
+        logged effects they folded and the host milliseconds they took
+        (:meth:`_replay_read_many`)."""
+        tables = list(self.tables.values())
+        return {
+            "launches": sum(t.fold_launches for t in tables),
+            "rows": sum(t.fold_rows for t in tables),
+            "sum_ms": sum(t.fold_seconds for t in tables) * 1e3,
+            "reads_by_head": sum(t.reads_by_head for t in tables),
+            "reads_by_fold": sum(t.reads_by_fold for t in tables),
+            "replays": self.replays,
+            "replay_records": self.replay_records,
+            "replay_sum_ms": self.replay_seconds * 1e3,
         }
 
     # ------------------------------------------------------------------
@@ -1312,6 +1468,7 @@ class KVStore:
             slot = ep.tables[tname_t]
             with span("serve.wb_host", table=tname_t, rows=len(items)):
                 filled = []
+                over = []
                 for j, (i, shard, row) in enumerate(items):
                     if pos is not None:
                         view = {f: x[pos[j, 0], pos[j, 1]]
@@ -1321,18 +1478,26 @@ class KVStore:
                     if has_resolve:
                         v = ty.value_from_resolved(view, self.blobs, t.cfg)
                         if v is RESOLVE_OVERFLOW:
-                            # truncated top-count view: re-gather the full
-                            # frozen state for this one key (rare)
-                            full = {
-                                f: np.asarray(x[shard, row])
-                                for f, x in slot["head"].items()
-                            }
-                            v = ty.value(full, self.blobs, t.cfg)
+                            over.append(len(filled))
                     else:
                         v = ty.value(view, self.blobs, t.cfg)
-                    vals[i] = v
                     key, _tn, bucket = pending.objects[i]
-                    filled.append(((key, bucket), shard, row, v))
+                    filled.append([(key, bucket), shard, row, v])
+                if over:
+                    # truncated top-count views (a set of more than
+                    # resolve_top elements): the launch's full frozen
+                    # states in one gather and one transfer
+                    full, _vc = t.gather_rows_dispatch(
+                        [filled[n][1] for n in over],
+                        [filled[n][2] for n in over],
+                        slot["head"], slot["head_vc"])
+                    full = {f: _cut(x, len(over)) for f, x in full.items()}
+                    for k, n in enumerate(over):
+                        filled[n][3] = ty.value(
+                            {f: x[k] for f, x in full.items()},
+                            self.blobs, t.cfg)
+                for (i, _s, _r), ent in zip(items, filled):
+                    vals[i] = ent[3]
                 # after the launch's last decode and before any reply of
                 # the batch: a client that has its answer finds the key
                 # in the mirror, at an epoch that is still pinned
@@ -1433,10 +1598,10 @@ class KVStore:
         base, tier = split_tier(tname_t)
         ty = get_type(base)
         t_old = self.table(tname_t)
-        head_state = {
-            f: np.asarray(x[shard, row]) for f, x in t_old.head.items()
-        }
-        used = ty.used_slots(head_state)
+        t0 = time.monotonic()
+        state = t_old.row_state(shard, row)
+        used = ty.used_slots(
+            {f: np.asarray(x) for f, x in state["head"].items()})
         cap_cur = ty.slot_capacity(t_old.cfg)
         if (min_tier <= tier and cap_cur is not None
                 and used + extra_demand <= cap_cur):
@@ -1456,39 +1621,15 @@ class KVStore:
             if cap is None or used + extra_demand <= cap:
                 break
             new_tier += 1
-        t_new = self.table(tiered_name(base, new_tier))
-        new_row = t_new.alloc_row(shard)
-        src_name, dst_name = tname_t, tiered_name(base, new_tier)
-        fn = self._promote_fns.get((src_name, dst_name))
-        if fn is None:
-            fn = _make_promote_fn()
-            self._promote_fns[(src_name, dst_name)] = fn
-        src_tree = {
-            "snap": t_old.snap, "head": t_old.head,
-            "snap_vc": t_old.snap_vc, "snap_seq": t_old.snap_seq,
-            "ops_a": t_old.ops_a, "ops_b": t_old.ops_b,
-            "ops_vc": t_old.ops_vc, "ops_origin": t_old.ops_origin,
-            "head_vc": t_old.head_vc,
-        }
-        dst_tree = {
-            "snap": t_new.snap, "head": t_new.head,
-            "snap_vc": t_new.snap_vc, "snap_seq": t_new.snap_seq,
-            "ops_a": t_new.ops_a, "ops_b": t_new.ops_b,
-            "ops_vc": t_new.ops_vc, "ops_origin": t_new.ops_origin,
-            "head_vc": t_new.head_vc,
-        }
-        src_tree, dst_tree = fn(
-            src_tree, dst_tree,
-            np.int64(shard), np.int64(row), np.int64(new_row),
-            np.int64(t_new.next_seq),
-        )
+        with span("commit.promote", tier=new_tier):
+            t_new = self.table(tiered_name(base, new_tier))
+            new_row = t_new.alloc_row(shard)
+            # the row's state goes from table to table on the device: a
+            # gather of one row, then row writes in both tables' own
+            # layouts (TypedTable.install_row / clear_rows)
+            t_new.install_row(shard, new_row, state, t_new.next_seq)
+            t_old.clear_rows([shard], [row])
         t_new.next_seq += int(t_old.next_seq)
-        for t, tree in ((t_old, src_tree), (t_new, dst_tree)):
-            t.snap, t.head = tree["snap"], tree["head"]
-            t.snap_vc, t.snap_seq = tree["snap_vc"], tree["snap_seq"]
-            t.ops_a, t.ops_b = tree["ops_a"], tree["ops_b"]
-            t.ops_vc, t.ops_origin = tree["ops_vc"], tree["ops_origin"]
-            t.head_vc = tree["head_vc"]
         t_new.n_ops[shard, new_row] = t_old.n_ops[shard, row]
         t_new.slots_ub[shard, new_row] = used + extra_demand
         t_new.max_abs_delta = max(t_new.max_abs_delta, t_old.max_abs_delta)
@@ -1516,13 +1657,23 @@ class KVStore:
         self.directory[dk] = (tiered_name(base, new_tier), shard, new_row)
         self.note_ckpt_dirty(dk)
         self.promotions += 1
+        self.promotions_by_tier[new_tier] = (
+            self.promotions_by_tier.get(new_tier, 0) + 1)
+        self.promote_seconds += time.monotonic() - t0
+        if new_tier < _MAX_TIER:
+            # a key that grows fast is through a tier's upper half before
+            # the next tier is compiled: start on it at the key's entry
+            self.prepare_tier(tiered_name(base, new_tier + 1))
 
     # ------------------------------------------------------------------
     def read_states(
-        self, objects: Sequence[BoundObject], read_vc: np.ndarray
+        self, objects: Sequence[BoundObject], read_vc: np.ndarray,
+        served: bool = False,
     ) -> List[Dict[str, np.ndarray]]:
         """Materialized per-key states for a batch of bound objects at one
-        read VC (grouped by type into batched device folds)."""
+        read VC (grouped by type into batched device folds).  ``served``:
+        the rows are a client's reads, counted as the locked read plane's
+        (``pipeline.fold.reads_by_head`` / ``reads_by_fold``)."""
         read_vc = np.asarray(read_vc, np.int32)
         by_type: Dict[str, list] = {}
         out: List[Dict[str, np.ndarray] | None] = [None] * len(objects)
@@ -1544,33 +1695,38 @@ class KVStore:
             shards = np.asarray([x[1] for x in items], np.int64)
             rows = np.asarray([x[2] for x in items], np.int64)
             vcs = np.broadcast_to(read_vc, (len(items), read_vc.shape[-1]))
-            # fast path: head gather; exact for rows whose head VC ≤ read VC
+            # head gather first (exact for rows whose head VC ≤ read VC:
+            # a remove's downstream reads the state it observes this way,
+            # a tenth of a fill), then the versioned snapshot + ring fold
+            # at the read VC for the stale rows
             state, fresh = t.read_latest(shards, rows, vcs)
-            if not fresh.all():
-                # stale rows: versioned snapshot + ring fold at the read VC
-                stale = ~fresh
-                s2, _, complete = t.read(shards[stale], rows[stale], vcs[stale])
-                idxs = np.nonzero(stale)[0]  # positions within this type batch
+            idxs = np.nonzero(~fresh)[0]  # positions within this batch
+            if served:
+                t.reads_by_head += len(rows) - len(idxs)
+                t.reads_by_fold += len(idxs)
+            complete = np.ones(0, bool)
+            if len(idxs):
+                s2, _, complete = t.read(shards[idxs], rows[idxs],
+                                         vcs[idxs])
                 for f in state:
                     state[f][idxs] = s2[f]
-                if not complete.all():
-                    # below retained device coverage: host log-replay
-                    # fallback (get_from_snapshot_log,
-                    # /root/reference/src/materializer_vnode.erl:415-419);
-                    # group by shard so each shard's WAL is scanned once
-                    incomplete = [int(idxs[j]) for j in np.nonzero(~complete)[0]]
-                    by_shard: Dict[int, list] = {}
-                    for j in incomplete:
-                        gi = items[j][0]  # global object index
-                        key, _, bucket = objects[gi]
-                        by_shard.setdefault(items[j][1], []).append(
-                            (j, key, tname_t, bucket)
-                        )
-                    for shard, wants in by_shard.items():
-                        reps = self._replay_read_many(shard, wants, read_vc)
-                        for j, rep in reps.items():
-                            for f in state:
-                                state[f][j] = rep[f]
+            if not complete.all():
+                # below retained device coverage: host log-replay
+                # fallback (get_from_snapshot_log,
+                # /root/reference/src/materializer_vnode.erl:415-419);
+                # group by shard so each shard's WAL is scanned once
+                by_shard: Dict[int, list] = {}
+                for j in (int(idxs[j]) for j in np.nonzero(~complete)[0]):
+                    gi = items[j][0]  # global object index
+                    key, _, bucket = objects[gi]
+                    by_shard.setdefault(items[j][1], []).append(
+                        (j, key, tname_t, bucket)
+                    )
+                for shard, wants in by_shard.items():
+                    reps = self._replay_read_many(shard, wants, read_vc)
+                    for j, rep in reps.items():
+                        for f in state:
+                            state[f][j] = rep[f]
             for j, (i, _, _) in enumerate(items):
                 out[i] = {f: x[j] for f, x in state.items()}
         if self.cold is not None:
@@ -1616,20 +1772,36 @@ class KVStore:
         device coverage fall back to the host log replay + host-side
         resolution.
 
-        When ``full_out`` is given, full states rebuilt by the replay
-        fallback are also recorded there keyed by object index — callers
-        that might need the full state anyway (e.g. a truncated resolved
-        view) must not pay a second WAL scan for it."""
+        When ``full_out`` is given, the caller can decode a full state
+        and wants it wherever the resolved view would not do: full
+        states are recorded there keyed by object index (and returned in
+        place of the view) for rows the replay fallback rebuilt (no
+        second log replay for them) and for rows that hold, by the
+        host's count of their slots, more than the view's
+        ``resolve_top`` — :meth:`read_states` answers those at once,
+        where the view's launch would only say "truncated"."""
         read_vc = np.asarray(read_vc, np.int32)
         out: List[Dict[str, np.ndarray] | None] = [None] * len(objects)
         by_type: Dict[str, list] = {}
+        wide: List[int] = []
         for i, (key, type_name, bucket) in enumerate(objects):
             ent = self.locate(key, type_name, bucket, create=False)
             if ent is None:
                 out[i] = self._bottom_resolved(type_name)
                 continue
             tname_t, shard, row = ent
+            if full_out is not None:
+                t = self.table(tname_t)
+                if (t.slots_ub[shard, row] > t.ty.resolve_top
+                        and t.ty.resolve_spec(self.cfg) is not None):
+                    wide.append(i)
+                    continue
             by_type.setdefault(tname_t, []).append((i, shard, row))
+        if wide:
+            states = self.read_states(
+                [objects[i] for i in wide], read_vc, served=True)
+            for i, st in zip(wide, states):
+                out[i] = full_out[i] = st
         for tname_t, items in by_type.items():
             t = self.table(tname_t)
             ty = t.ty
@@ -1683,11 +1855,19 @@ class KVStore:
 
     # ------------------------------------------------------------------
     def _replay_read_many(self, shard: int, wants, read_vc):
-        """Rebuild several keys' states at ``read_vc`` from one scan of the
-        shard's durable log.  ``wants`` = [(result_idx, key, tiered_name,
-        bucket)] — the state is rebuilt at the key's CURRENT tier width
+        """Several keys' states at ``read_vc``, rebuilt from the shard's
+        durable log: the answer to a read whose snapshot is older than
+        the device still holds history for (a row keeps what came after
+        its last GC).  ``wants`` = [(result_idx, key, tiered_name,
+        bucket)] — a state is rebuilt at the key's CURRENT tier width
         (wide enough for every logged effect, since the live store
-        promoted before any wide effect applied)."""
+        promoted before any wide effect applied).
+
+        A key's logged effects come from the log's index by key
+        (``LogManager.key_history``: no scan after the key's first), and
+        are folded onto the key's replay base when that is not newer
+        than ``read_vc`` — what such a read costs is the effects since
+        the base, not the log's length nor the key's."""
         if self.log is None:
             raise RuntimeError(
                 f"incomplete read for {[w[1] for w in wants]!r} and no log "
@@ -1710,70 +1890,110 @@ class KVStore:
                 "checkpoint-truncated and no longer holds history below "
                 "the checkpoint stamp"
             )
-        import time as _time
-
-        import jax
-        import jax.numpy as jnp
-
         read_vc = np.asarray(read_vc, np.int32)
-        index = {}
-        ops: Dict[int, list] = {}
-        for j, key, tname_t, bucket in wants:
-            base, tier = split_tier(tname_t)
-            ty = get_type(base)
-            cfg_t = scaled_cfg(self.cfg, tier)
-            index[(key, bucket)] = (j, ty, cfg_t)
-            ops[j] = []
-        # one host pass over the shard's log: collect each wanted key's
-        # visible effects in commit order (the sequence axis), then fold
-        # per key with the strategy the log's shape earns — this is where
-        # an over-ring celebrity key stops paying a length-L serial scan
-        for rec in self.log.replay_shard(shard):
-            hit = index.get((freeze_key(rec["k"]), rec["b"]))
-            if hit is None:
-                continue
-            j, ty, cfg_t = hit
-            vc = np.asarray(rec["vc"], np.int32)
-            if not (vc <= read_vc).all():
-                continue
-            ops[j].append((
-                _pad_lane(np.frombuffer(rec["a"], np.int64),
-                          ty.eff_a_width(cfg_t), np.int64),
-                _pad_lane(np.frombuffer(rec["eb"], np.int32),
-                          ty.eff_b_width(cfg_t), np.int32),
-                vc, np.int32(rec["o"]),
-            ))
-        out = {}
-        for (key, bucket), (j, ty, cfg_t) in index.items():
-            spec = ty.state_spec(cfg_t)
-            state0 = {
-                f: jnp.zeros(shape, dtype)
-                for f, (shape, dtype) in spec.items()
+        t0 = time.monotonic()
+        with span("serve.replay", shard=shard, keys=len(wants)), \
+                self._replay_lock:
+            out = {
+                j: self._replay_one(shard, key, bucket, tname_t, read_vc)
+                for j, key, tname_t, bucket in wants
             }
-            recs = ops[j]
-            l = len(recs)
-            if l == 0:
-                out[j] = jax.tree.map(np.asarray, state0)
-                continue
-            ops_a = np.stack([r[0] for r in recs])
-            ops_b = np.stack([r[1] for r in recs])
-            ops_vc = np.stack([r[2] for r in recs])
-            ops_origin = np.asarray([r[3] for r in recs], np.int32)
-            base_vc = np.zeros((self.cfg.max_dcs,), np.int32)
-            t0 = _time.monotonic()
-            state, strategy = self._fold_over_ring(
-                ty, cfg_t, state0, ops_a, ops_b, ops_vc, ops_origin,
-                l, base_vc, read_vc,
-            )
-            out[j] = jax.tree.map(np.asarray, state)  # sync-ok: replay
-            # fallback path materializes host states for the caller
-            self._observe_fold(strategy, ty.name, _time.monotonic() - t0)
+        self.replays += len(wants)
+        self.replay_seconds += time.monotonic() - t0
         return out
 
+    #: effects a replay base leaves unfolded at most; one that has more
+    #: moves up so that the newer half stay (a transaction's snapshot is
+    #: seldom older than they are), and keys that keep a base
+    _REPLAY_TAIL_MAX = 256
+    _REPLAY_BASES = 256
+
+    def _replay_one(self, shard, key, bucket, tname_t, read_vc):
+        base, tier = split_tier(tname_t)
+        ty = get_type(base)
+        cfg_t = scaled_cfg(self.cfg, tier)
+        hist = self.log.key_history(shard, key, bucket)
+        n = len(hist)  # (an append may follow: this read ends here)
+        dk = (key, bucket)
+        ent = self._replay_bases.get(dk)
+        if ent is not None and ent[0] != tname_t:
+            # promoted since: its state has the old tier's width
+            del self._replay_bases[dk]
+            ent = None
+        fold = functools.partial(self._fold_log, ty, cfg_t)
+        if ent is None or not (ent[2] <= read_vc).all():
+            # no base, or one that is newer than the snapshot (it stays
+            # for the reads that come after): from the bottom state
+            state0 = {f: np.zeros(shape, dtype) for f, (shape, dtype)
+                      in ty.state_spec(cfg_t).items()}
+            zero = np.zeros_like(read_vc)
+            self.replay_records += n
+            state = fold(state0, hist[:n], zero, read_vc, bottom=True)
+            if ent is None:
+                self._replay_bases[dk] = [
+                    tname_t, state, read_vc.copy(),
+                    [o for o in hist[:n] if not (o[2] <= read_vc).all()], n]
+                while len(self._replay_bases) > self._REPLAY_BASES:
+                    self._replay_bases.popitem(last=False)
+            return state
+        self._replay_bases.move_to_end(dk)
+        _, state0, base_vc, tail, pos = ent
+        tail.extend(hist[pos:n])
+        ent[4] = n
+        self.replay_records += len(tail)
+        state = fold(state0, tail, base_vc, read_vc)
+        if len(tail) > self._REPLAY_TAIL_MAX:
+            new_vc = np.maximum(base_vc, tail[-self._REPLAY_TAIL_MAX // 2][2])
+            ent[1] = fold(state0, tail, base_vc, new_vc)
+            ent[2] = new_vc
+            ent[3] = [o for o in tail if not (o[2] <= new_vc).all()]
+        return state
+
+    def _fold_log(self, ty, cfg_t, state0, ops, base_vc, read_vc,
+                  bottom: bool = False):
+        """``state0`` (which holds what is <= ``base_vc``) with those of
+        ``ops`` [(eff_a, eff_b, commit_vc, origin)] folded in that are
+        <= ``read_vc`` and not <= ``base_vc``, as host arrays."""
+        l = len(ops)
+        if l == 0:
+            return state0
+        t0 = time.monotonic()
+        wa, wb = ty.eff_a_width(cfg_t), ty.eff_b_width(cfg_t)
+        state, strategy = self._fold_over_ring(
+            ty, cfg_t, state0,
+            np.stack([_pad_lane(o[0], wa, np.int64) for o in ops]),
+            np.stack([_pad_lane(o[1], wb, np.int32) for o in ops]),
+            np.stack([o[2] for o in ops]),
+            np.asarray([o[3] for o in ops], np.int32),
+            l, base_vc, read_vc, bottom=bottom)
+        state = jax.tree.map(np.asarray, state)  # sync-ok: replay
+        # fallback path materializes host states for the caller
+        self._observe_fold(strategy, ty.name, time.monotonic() - t0)
+        return state
+
+    def _warm_replay(self, ty, cfg_t, with_base: bool) -> None:
+        """Compile the log replay's serial fold for a tier's widths (and
+        the base table's with the first tier) at its two shortest padded
+        lengths, on a log of nothing visible."""
+        for cfg in [cfg_t] + ([self.cfg] if with_base else []):
+            state0 = {f: np.zeros(shape, dtype) for f, (shape, dtype)
+                      in ty.state_spec(cfg).items()}
+            vc = np.zeros((self.cfg.max_dcs,), np.int32)
+            for l in (64, 256):
+                # kind 1 in lane 0 keeps an add-only type off its
+                # associative fold: the serial scan is what compiles
+                self._fold_over_ring(
+                    ty, cfg, state0,
+                    np.zeros((l, ty.eff_a_width(cfg)), np.int64),
+                    np.ones((l, ty.eff_b_width(cfg)), np.int32),
+                    np.ones((l, self.cfg.max_dcs), np.int32),
+                    np.zeros((l,), np.int32), l, vc, vc)
+
     def _fold_over_ring(self, ty, cfg_t, state0, ops_a, ops_b, ops_vc,
-                        ops_origin, l, base_vc, read_vc):
-        """Route one host-assembled op log (leading axis L, bottom base)
-        to a fold strategy; returns (device state pytree, strategy name).
+                        ops_origin, l, base_vc, read_vc, bottom=True):
+        """Route one host-assembled op log (leading axis L; ``bottom``:
+        onto the bottom state) to a fold strategy; returns (device state
+        pytree, strategy name).
 
         Strategy ladder (docs/performance.md "Sequence-axis parallel
         folds"):
@@ -1781,10 +2001,11 @@ class KVStore:
         * ``mesh_assoc`` — assoc-safe log of ≥ fold_chunk ops with a mesh
           attached: op axis sharded over devices, partial deltas merged
           in sequence order (``MeshServingPlane.fold_giant_key``).
-        * ``assoc`` — assoc-safe log: one O(log L)-depth delta window.
+        * ``assoc`` — assoc-safe log of ≥ fold_chunk ops: one
+          O(log L)-depth delta window.
           Assoc-safe = ``ty.supports_assoc``, plus (set_aw) an all-adds
-          log; the bottom base these replays start from satisfies
-          ``assoc_bottom_only`` by construction.
+          log, plus a bottom base where the type's delta is exact from
+          that alone (``assoc_bottom_only``).
         * ``long`` — order-sensitive log over fold_chunk ops: chunked
           scan, zero-padded to a chunk multiple (pad slots sit at index
           ≥ n_ops, so the inclusion mask drops them).
@@ -1798,7 +2019,7 @@ class KVStore:
         chunk = max(int(getattr(self.cfg, "fold_chunk", 4096)), 2)
         assoc_ok = ty.supports_assoc and (
             not ty.assoc_add_only or not (ops_b[:, 0] == 1).any()
-        )
+        ) and (bottom or not ty.assoc_bottom_only)
         n_ops = np.int32(l)
         if assoc_ok and self.mesh is not None and l >= chunk:
             state, _ = self.mesh.fold_giant_key(
@@ -1806,33 +2027,57 @@ class KVStore:
                 n_ops, base_vc, read_vc,
             )
             return state, "mesh_assoc"
-        if assoc_ok:
+        if assoc_ok and l >= chunk:
+            # (a short log takes the compiled serial scan below: the
+            # associative fold runs op by op, ~50 small programs that
+            # compile anew for every log length, under the commit lock —
+            # it pays from a chunk's length on, where depth matters)
             state, _ = longlog.assoc_fold(
                 ty, cfg_t, state0, jnp.asarray(ops_a), jnp.asarray(ops_b),
                 jnp.asarray(ops_vc), jnp.asarray(ops_origin), n_ops,
                 jnp.asarray(base_vc), jnp.asarray(read_vc),
             )
             return state, "assoc"
+        def padded(x, to):  # pad slots sit at index ≥ n_ops: masked out
+            return np.concatenate(
+                [x, np.zeros((to - l,) + x.shape[1:], x.dtype)]
+            ) if to > l else x
+
         if l > chunk:
-            pad = (-l) % chunk
-
-            def padl(x):
-                return np.concatenate(
-                    [x, np.zeros((pad,) + x.shape[1:], x.dtype)]
-                ) if pad else x
-
+            to = l + (-l) % chunk
             state, _ = longlog.fold_long(
-                ty, cfg_t, state0, jnp.asarray(padl(ops_a)),
-                jnp.asarray(padl(ops_b)), jnp.asarray(padl(ops_vc)),
-                jnp.asarray(padl(ops_origin)), n_ops,
+                ty, cfg_t, state0, jnp.asarray(padded(ops_a, to)),
+                jnp.asarray(padded(ops_b, to)),
+                jnp.asarray(padded(ops_vc, to)),
+                jnp.asarray(padded(ops_origin, to)), n_ops,
                 jnp.asarray(base_vc), jnp.asarray(read_vc), chunk=chunk,
             )
             return state, "long"
-        state, _ = fold_mod.fold_key(
-            ty, cfg_t, state0, jnp.asarray(ops_a), jnp.asarray(ops_b),
-            jnp.asarray(ops_vc), jnp.asarray(ops_origin), n_ops,
-            jnp.asarray(base_vc), jnp.asarray(read_vc),
-        )
+        # one compiled scan a (type, tier) at two padded lengths, 64 and
+        # 256, a longer log in pieces of 256: an eager scan compiles
+        # anew for every log length, under the commit lock, and these
+        # two a served mix has met before its first replay
+        # (_warm_replay); a padded slot costs a masked step
+        fn = self._replay_fold_fns.get((ty.name, cfg_t))
+        if fn is None:
+            from antidote_tpu.obs.trace import device_program
+
+            fn = self._replay_fold_fns[(ty.name, cfg_t)] = device_program(
+                "replay_fold_serial", functools.partial(
+                    fold_mod.fold_key, ty, cfg_t))
+        state = state0
+        for lo in range(0, l, 256):
+            n = min(l - lo, 256)
+            to = 64 if n <= 64 else 256
+
+            def piece(x):  # pad slots sit at index >= n: masked out
+                x = x[lo:lo + n]
+                return np.concatenate(
+                    [x, np.zeros((to - n,) + x.shape[1:], x.dtype)]
+                ) if to > n else x
+
+            state, _ = fn(state, piece(ops_a), piece(ops_b), piece(ops_vc),
+                          piece(ops_origin), np.int32(n), base_vc, read_vc)
         return state, "serial"
 
     def _observe_fold(self, strategy: str, tname: str, seconds: float):

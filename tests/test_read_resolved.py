@@ -396,9 +396,9 @@ def test_read_resolved_pads_the_flat_read_to_a_batch_bucket(at):
     flat = table.read_resolved_flat
     seen = []
 
-    def recording(shards, rows, read_vcs):
+    def recording(shards, rows, read_vcs, n_real=None):
         seen.append(len(rows))
-        return flat(shards, rows, read_vcs)
+        return flat(shards, rows, read_vcs, n_real=n_real)
 
     table.read_resolved_flat = recording
     for m in (1, 3, 16, 17, 49):

@@ -245,23 +245,28 @@ def test_mesh_gather_compiles_at_2m_rows(topo, no_persistent_cache,
           f"{mem.output_size_in_bytes / 1e6:.3f} MB a device")
 
 
+@pytest.mark.parametrize("mb", [64, 512])
 @pytest.mark.parametrize("window", [1, 0])
 @pytest.mark.parametrize("placement", ["one_chip", "mesh4"])
 def test_commit_scatter_compiles_in_place_at_set_aw_shapes(
-        topo, no_persistent_cache, placement, window):
+        topo, no_persistent_cache, placement, window, mb):
     """`jit_antidote_commit_scatter_w<n>`, the one program of a commit
     group, at the shapes of the two `set_aw` deployments: [16, 65536] on
-    one chip (`set_aw_1m`) and [16, 131072] over the host's four
-    (`set_aw_2m_mesh4`: [4, 131072] a device), batch bucket 64.  All six
-    donated tables alias their outputs, a mesh program needs no
-    collective, and fusing the ring scatter with the head update costs no
-    memory: the program's temporaries stay within 64 MB of what the head
-    update reserves as a program of its own on one device's block (it
-    copies whole head fields between layouts and splits the int64 ones:
-    4.3 GB on one chip, 2.15 GB a mesh device, beside 8.87 / 4.45 GB of
-    tables).  This is the test that keeps ring scatter and head update
-    ONE program: if it ever fails, they go back to two programs fed the
-    same staged operand."""
+    one chip (`set_aw_1m`, `set_aw_1m_rw`) and [16, 131072] over the
+    host's four (`set_aw_2m_mesh4`: [4, 131072] a device), at both batch
+    buckets a commit group of requests fills, 64 and 512 (a larger one
+    is a fill's and is scattered: `typed_table._ROW_WRITE_MAX_BATCH`).
+    All six donated tables alias their outputs, a mesh program
+    needs no collective, and fusing the ring writes with the head update
+    costs no memory: the program's temporaries stay within 64 MB of what
+    the head update reserves as a program of its own on one device's
+    block.  Since PR 33 both write their rows in the tables' own layout
+    (`_write_rows`) and that is 0.2 GB on one chip, 0.03 GB a mesh
+    device — the int64 fields' split — where a scatter re-laid whole
+    head fields out: 4.3 GB and 2.15 GB — at either bucket under
+    ROW_PROGRAM_TEMP_LIMIT.  This is the test that keeps
+    ring writes and head update ONE program: if it ever fails, they go
+    back to two programs fed the same staged operand."""
     from antidote_tpu.store import typed_table
 
     mesh = Mesh(np.array(topo.devices), ("shard",))
@@ -278,7 +283,7 @@ def test_commit_scatter_compiles_in_place_at_set_aw_shapes(
     table = TypedTable(get_type("set_aw"), cfg, n_rows=8)
     if placement == "mesh4":
         table.set_sharding(placed)  # placement only: nothing is put anywhere
-    p, mb = cfg.n_shards, 64
+    p = cfg.n_shards
 
     def tables(shards, sharding):
         return jax.tree.map(
@@ -311,10 +316,174 @@ def test_commit_scatter_compiles_in_place_at_set_aw_shapes(
             <= head_alone.temp_size_in_bytes + 64 * 2**20), (
         f"fused {mem.temp_size_in_bytes / 1e9:.3f} GB of temporaries, the "
         f"head update alone {head_alone.temp_size_in_bytes / 1e9:.3f} GB")
+    assert mem.temp_size_in_bytes < ROW_PROGRAM_TEMP_LIMIT
     reserved = mem.temp_size_in_bytes + mem.generated_code_size_in_bytes
     table_share = 8.87e9 if devices == 1 else 4.45e9   # PERF §4, in use
     assert table_share + reserved < 16e9
-    print(f"commit_scatter_w{window} {placement}: tables "
+    print(f"commit_scatter_w{window} {placement} bucket {mb}: tables "
           f"{held / 1e9:.3f} GB aliased, temporaries "
           f"{mem.temp_size_in_bytes / 1e9:.3f} GB (head update alone "
           f"{head_alone.temp_size_in_bytes / 1e9:.3f} GB) a device")
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 33: programs whose work is a bucket of rows reserve by the rows
+# ---------------------------------------------------------------------------
+#: what any one of them may reserve beside the tables at a deployment's
+#: shapes, whatever the rows: the row writes themselves need a few tiles;
+#: what is left is the compiler's 64-bit rewrite, which splits an int64
+#: field into halves and joins them again (0.27 GB for a GC's snap.elems
+#: at [16, 65536], 0.07 GB a mesh device).  The parent's GC reserved
+#: 8.00 GB here.
+ROW_PROGRAM_TEMP_LIMIT = 512 * 2**20
+
+
+def _parent_gc(snap, snap_vc, snap_seq, head, head_vc, rows, new_seqs):
+    """`_gc_fn` as it stood before ISSUE 33 (vmapped `.at[rows,
+    slot].set`), kept here as the case the limit has to refuse."""
+    from antidote_tpu.clock import orddict
+
+    def per_shard(snap, snap_vc, snap_seq, head, head_vc, rows, seqs):
+        slot = orddict.insert_slot(snap_seq[rows])
+        snap2 = {f: x.at[rows, slot].set(head[f][rows], mode="drop")
+                 for f, x in snap.items()}
+        return (snap2,
+                snap_vc.at[rows, slot].set(head_vc[rows], mode="drop"),
+                snap_seq.at[rows, slot].set(seqs, mode="drop"))
+
+    return jax.vmap(per_shard)(snap, snap_vc, snap_seq, head, head_vc,
+                               rows, new_seqs)
+
+
+@pytest.fixture(scope="module")
+def set_aw_programs(topo):
+    """The row programs of a `set_aw` base table and of its tier tables,
+    lowered at a placement's real shapes: `compile(what)` -> (compiled,
+    bytes of the donated tables on one device)."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from antidote_tpu.store.kv import scaled_cfg, tier_rows
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    mesh = Mesh(np.array(topo.devices), ("shard",))
+    placed = NamedSharding(mesh, PartitionSpec("shard"))
+    one = SingleDeviceSharding(topo.devices[0])
+    mb = 64
+
+    def build(placement):
+        if placement == "one_chip":
+            rows, devices, tsh, bsh = 65_536, 1, one, one
+        else:
+            rows, devices, tsh = 131_072, 4, placed
+            bsh = NamedSharding(mesh, PartitionSpec())
+        base = AntidoteConfig(n_shards=16, max_dcs=8, keys_per_table=rows,
+                              use_pallas=True)
+
+        def table(tier):
+            t = TypedTable(get_type("set_aw"), scaled_cfg(base, tier),
+                           n_rows=8)
+            if devices > 1:
+                t.set_sharding(placed)   # placement only
+            n = rows if tier == 0 else tier_rows(base, tier)
+            shapes = jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(
+                    (16, n) + x.shape[2:], x.dtype, sharding=tsh),
+                t._tree())
+            return t, shapes
+
+        def b(*dims, dtype=jnp.int32):
+            return jax.ShapeDtypeStruct(dims, dtype, sharding=bsh)
+
+        def held(*trees):
+            return sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                       for x in jax.tree.leaves(trees)) // devices
+
+        def compile_(what):
+            t0, s0 = table(0)
+            if what in ("gc", "parent_gc"):
+                donated = (s0["snap"], s0["snap_vc"], s0["snap_seq"])
+                if what == "gc":
+                    fn, batch = t0._gc_fn, (b(mb), b(mb),
+                                            b(mb, dtype=jnp.int64), b())
+                else:
+                    fn = jax.jit(_parent_gc, donate_argnums=(0, 1, 2))
+                    batch = (jax.ShapeDtypeStruct((16, mb), jnp.int64,
+                                                  sharding=tsh),) * 2
+                return fn.lower(*donated, s0["head"], s0["head_vc"],
+                                *batch).compile(), held(donated)
+            if what == "clear_rows":      # a promotion's source row
+                return t0._clear_rows_fn.lower(
+                    s0, b(mb), b(mb), b()).compile(), held(s0)
+            if what == "read":
+                args = (s0["head"], s0["head_vc"], s0["snap"], s0["snap_vc"],
+                        s0["snap_seq"], s0["ops_a"], s0["ops_b"],
+                        s0["ops_vc"], s0["ops_origin"])
+                if devices == 1:
+                    fn = t0._read_resolved_flat_fn("pallas_set_aw", 0)
+                    batch = (b(mb, dtype=jnp.int64), b(mb, dtype=jnp.int64),
+                             b(mb), b(mb, 8))
+                else:
+                    fn = t0._read_resolved_fn("pallas_set_aw", 0)
+                    mk = lambda *d, dtype=jnp.int32: jax.ShapeDtypeStruct(  # noqa: E731
+                        d, dtype, sharding=tsh)
+                    batch = (mk(16, mb, dtype=jnp.int64), mk(16, mb),
+                             mk(16, mb, 8))
+                return fn.lower(*args, *batch).compile(), 0
+            src, dst = {"promote_0_1": (0, 1), "promote_2_3": (2, 3),
+                        "grow_1": (1, 1)}[what]
+            td, sd = table(dst)
+            if what == "grow_1":
+                return td._grow_fn.lower(sd).compile(), 0
+            state = jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(x.shape[2:], x.dtype,
+                                               sharding=bsh),
+                table(src)[0]._tree())
+            return td._install_row_fn.lower(
+                sd, state, b(1), b(1), b(dtype=jnp.int64), b()
+            ).compile(), held(sd)
+
+        return compile_
+
+    yield {p: build(p) for p in ("one_chip", "mesh4")}
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.mark.parametrize("what", ["gc", "promote_0_1", "promote_2_3",
+                                  "clear_rows", "read", "grow_1"])
+@pytest.mark.parametrize("placement", ["one_chip", "mesh4"])
+def test_row_programs_reserve_by_the_rows(set_aw_programs, monkeypatch,
+                                          placement, what):
+    """GC, the two halves of a tier promotion (`tier_promote` writes the
+    destination tier's row, tier 0 -> 1 and 2 -> 3; `clear_rows` clears
+    the source's), the versioned read and a tier table's grow, at the
+    shapes of `set_aw_1m_rw` ([16, 65536] on one chip) and of the mesh
+    deployment ([4, 131072] a device): each reserves under
+    ROW_PROGRAM_TEMP_LIMIT beside the tables, a number that does not
+    hang on the table's rows; every donated table aliases its output;
+    a mesh program needs no collective."""
+    monkeypatch.setattr(pk, "_on_tpu", lambda: True)
+    compiled, held = set_aw_programs[placement](what)
+    mem = compiled.memory_analysis()
+    text = compiled.as_text()
+    assert mem.temp_size_in_bytes < ROW_PROGRAM_TEMP_LIMIT, (
+        f"{what} reserves {mem.temp_size_in_bytes / 1e9:.3f} GB")
+    assert mem.alias_size_in_bytes >= held, "a donated table is copied"
+    if what != "grow_1":
+        for collective in ("all-gather", "all-reduce", "all-to-all",
+                           "collective-permute"):
+            assert collective not in text, collective
+    print(f"{what} {placement}: temporaries "
+          f"{mem.temp_size_in_bytes / 1e6:.1f} MB, aliased "
+          f"{mem.alias_size_in_bytes / 1e9:.3f} GB a device")
+
+
+def test_parent_gc_fails_the_limit(set_aw_programs):
+    """The GC as it stood (vmapped scatter) at [16, 65536]: whole snapshot
+    fields re-laid out and back, 8 GB beside 8.87 GB of tables on a 16 GB
+    chip — the RESOURCE_EXHAUSTED of PERF §7 fault 2."""
+    compiled, _held = set_aw_programs["one_chip"]("parent_gc")
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp > 8 * ROW_PROGRAM_TEMP_LIMIT, f"{temp / 1e9:.2f} GB"
+    assert 8.87e9 + temp > 16e9

@@ -540,6 +540,9 @@ def lowered_names(tmp_path_factory):
             for mesh in (False, True):
                 node = AntidoteNode(cfg)
                 store, txm = node.store, node.txm
+                # tier tables built inside the commit group, on the
+                # thread whose compile log is listened to
+                store.prepare_tier = lambda tname: None
                 if mesh:
                     MeshServingPlane(cfg, n_devices=2).attach(store)
                 txm.enable_serving_epochs()
@@ -578,11 +581,10 @@ def lowered_names(tmp_path_factory):
 PROGRAMS = [
     "antidote_commit_scatter_w1", "antidote_commit_scatter_w0",
     "antidote_gc", "antidote_tier_promote", "antidote_head_gather",
-    "antidote_head_gather_routed", "antidote_read_latest",
+    "antidote_head_gather_routed", "antidote_row_state",
     "antidote_read_resolved_", "antidote_freeze_serving_copy",
-    "antidote_freeze_serving_scatter", "antidote_freeze_serving_scatter_routed",
+    "antidote_freeze_serving_scatter", "antidote_clear_rows",
     "antidote_mesh_gather", "antidote_mesh_pmin", "antidote_ckpt_gather",
-    "antidote_evict_clear",
 ]
 
 
