@@ -731,6 +731,10 @@ def handle_request(server, code: int, payload: bytes, conn_txns: set,
         # duplicate branch in _dispatch
         resp_name, resp = _dispatch_static(server, name, req)
         return encode_frame_body(resp_name, resp)
+    if name == "ApbReadObjects":
+        # a transaction's read parks at the locked plane's merge point
+        # (the server's one helper of both dialects), never under `lock`
+        return encode_frame_body(*_dispatch_txn_read(server, req))
     with (lock if lock is not None else contextlib.nullcontext()):
         resp_name, resp = _dispatch(server, name, req, conn_txns)
     return encode_frame_body(resp_name, resp)  # outside the lock
@@ -877,6 +881,27 @@ def _dispatch_static(server, name: str, req: Dict[str, Any]):
         return _error_resp(e, server=server)
 
 
+def _dispatch_txn_read(server, req: Dict[str, Any]):
+    # proto2 ApbReadObjects carries no deadline field: the server's
+    # configured default applies, as for the static ops
+    from antidote_tpu.overload import deadline_from_ms
+
+    try:
+        objs = [_bound_object(bo) for bo in req["boundobjects"]]
+        vals = server.txn_read(
+            int(req["transaction_descriptor"]), objs,
+            deadline=deadline_from_ms(None, server.default_deadline_ms))
+        return "ApbReadObjectsResp", {
+            "success": True,
+            "objects": [
+                value_to_read_resp(t, v)
+                for (_, t, _), v in zip(objs, vals)
+            ],
+        }
+    except Exception as e:
+        return _error_resp(e, server=server)
+
+
 def _dispatch(server, name: str, req: Dict[str, Any],
               conn_txns: set) -> Tuple[str, Dict[str, Any]]:
     node = server.node
@@ -891,19 +916,6 @@ def _dispatch(server, name: str, req: Dict[str, Any],
             return "ApbStartTransactionResp", {
                 "success": True,
                 "transaction_descriptor": str(txn.txid).encode(),
-            }
-        if name == "ApbReadObjects":
-            txn = server._txns.get(int(req["transaction_descriptor"]))
-            if txn is None:
-                raise KeyError("unknown transaction")
-            objs = [_bound_object(bo) for bo in req["boundobjects"]]
-            vals = node.read_objects(objs, txn)
-            return "ApbReadObjectsResp", {
-                "success": True,
-                "objects": [
-                    value_to_read_resp(t, v)
-                    for (_, t, _), v in zip(objs, vals)
-                ],
             }
         if name == "ApbUpdateObjects":
             txid = int(req["transaction_descriptor"])

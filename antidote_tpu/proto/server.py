@@ -65,8 +65,9 @@ _STOP = object()
 
 
 class _StaticWork:
-    """One client's static read/update — or an interactive COMMIT — parked
-    at the batch gate / locked-plane merge point."""
+    """One client's static read/update — or an interactive transaction's
+    COMMIT or read — parked at the batch gate / locked-plane merge
+    point."""
 
     __slots__ = ("kind", "objects", "updates", "clock", "event", "result",
                  "error", "deadline", "t_submit", "wants_bytes",
@@ -84,9 +85,10 @@ class _StaticWork:
         #: tenant lane this work rides (ISSUE 19): derived from the
         #: bucket namespace / request tag at decode; None = default
         self.tenant = tenant
-        #: interactive commit works (kind == "commit") carry the txid;
-        #: the locked worker resolves it to the registered Transaction
-        #: at the merge point
+        #: an interactive transaction's works (kind == "commit", and
+        #: "txn_read" with the ``objects`` to read) carry the txid; the
+        #: locked worker resolves it to the registered Transaction at
+        #: the merge point
         self.txid = txid
         #: how the work completes (``_complete``).  None: a thread waits
         #: on ``event`` (a Handler, a connection worker) and sends the
@@ -323,6 +325,13 @@ class ProtocolServer:
         self._direct = {"served": 0, "worker": 0}
         #: launched read chunks so far (written by the dispatcher only)
         self._launch_seq = 0
+        #: node status ``pipeline.txn_reads``: merged reads of the locked
+        #: worker's rounds (``groups``), the transaction reads and the
+        #: objects they answered (``reads``, ``rows``), and the reads
+        #: answered one by one (``inline``: a writeset to overlay, a
+        #: composite type, no local txm, the fallback of a merged read
+        #: that failed) — written under the dispatch lock
+        self._txn_reads = {"groups": 0, "reads": 0, "rows": 0, "inline": 0}
         #: (seconds, waits) the locked worker spent blocked on an empty
         #: merge-point queue — replaced whole by that worker only
         self._locked_idle = (0.0, 0)
@@ -933,7 +942,7 @@ class ProtocolServer:
             # while parked, or failed by a stage's error path
             path = "shed"
         elif w.kind != "read":
-            path = w.kind                   # "update" | "commit"
+            path = w.kind                   # "update" | "commit" | "txn_read"
         elif w.t_synced:
             path = "gather"                 # a device gather served it
         elif w.t_wb_start:
@@ -1032,10 +1041,31 @@ class ProtocolServer:
                                         deadline=deadline, tenant=tenant),
                             self._locked_q)
 
+    def txn_read(self, txid: int, objects, deadline=None, tenant=None):
+        """A read inside an interactive transaction, either dialect: the
+        values, in the objects' order.  It parks at the locked worker's
+        merge point, as the transaction's COMMIT does, and is answered
+        together with every other transaction read of the worker's round
+        in one batched read, each row at its own transaction's snapshot
+        (``_run_txn_reads``).  The connection's thread waits here, so a
+        connection's requests stay in order."""
+        if getattr(self.node, "txm", None) is None:
+            # cluster coordinator: no manager here to batch across
+            # transactions (the test COMMIT_TRANSACTION makes)
+            with self._lock:
+                self._check_dispatch_deadline(deadline)
+                self._txn_reads["inline"] += 1
+                return self.node.read_objects(objects, self._txn(txid))
+        tenant = self.tenants.resolve(tenant, (o[2] for o in objects))
+        return self._submit(_StaticWork("txn_read", objects=objects,
+                                        txid=txid, deadline=deadline,
+                                        tenant=tenant),
+                            self._locked_q)
+
     def _submit(self, work: _StaticWork, q: Optional[TenantLanes] = None):
         """Park a work on a pipeline queue (default: the batch gate;
-        interactive commits go straight to the locked-plane merge point
-        — one hop fewer) and wait for its stage to reply."""
+        interactive commits and reads go straight to the locked-plane
+        merge point — one hop fewer) and wait for its stage to reply."""
         tenant = self._park(work, self._static_q if q is None else q)
         try:
             if not work.event.wait(timeout=300):
@@ -1321,12 +1351,20 @@ class ProtocolServer:
         (ISSUE 6): static update groups and interactive COMMITs arriving
         on different connections drain into ONE merged batch that takes
         the commit lock once, certifies once, appends once and scatters
-        once, with per-source acks fanned back out.  Also serves the
-        reads the epoch path cannot (clocks ahead of the epoch,
+        once, with per-source acks fanned back out.  The reads of
+        interactive transactions merge here too: one batched read a
+        round, each row at its own transaction's snapshot.  Also serves
+        the reads the epoch path cannot (clocks ahead of the epoch,
         composite maps, promoted keys, no epoch yet).  Runs under
         ``self._lock`` — serialized against nothing but itself and the
         interactive-transaction dispatch; the epoch read plane never
-        waits for it."""
+        waits for it.
+
+        Order inside a round: transaction reads, commit merge, static
+        read group.  A transaction's snapshot is fixed, so its read is
+        right wherever it runs; first, it waits for no commit group (the
+        longest hold of a round) and finds the heads not yet moved on by
+        the round's own commits, so fewer of its rows need a fold."""
         q = self._locked_q
         while True:
             works, stop, waited = self._drain_batch(
@@ -1339,17 +1377,22 @@ class ProtocolServer:
             # those.  Write works park here directly (no dispatcher
             # hop), so this dequeue also owns their parked-stage clock;
             # rerouted reads were already observed at the batch gate.
-            writes = self._shed_expired(
+            # (transaction reads park here directly, like the writes)
+            direct = self._shed_expired(
                 [w for w in works if w.kind != "read"], "locked plane",
                 observe_parked=True)
             reads = self._shed_expired(
                 [w for w in works if w.kind == "read"], "locked plane")
             try:
-                ups = [w for w in writes if w.kind == "update"]
-                commits = [w for w in writes if w.kind == "commit"]
+                ups = [w for w in direct if w.kind == "update"]
+                commits = [w for w in direct if w.kind == "commit"]
+                txn_reads = [w for w in direct if w.kind == "txn_read"]
                 with self._lock:
-                    # writes first: the merged read then serves at a
-                    # snapshot covering them (fresh + cache friendly)
+                    if txn_reads:
+                        self._run_txn_reads(txn_reads)
+                    # writes before the static reads: the merged read
+                    # then serves at a snapshot covering them (fresh +
+                    # cache friendly)
                     if ups or commits:
                         self._run_commit_merge(ups, commits)
                     if reads:
@@ -1730,6 +1773,57 @@ class ProtocolServer:
             w.t_ready = time.monotonic()
             self._complete(w)
 
+    def _run_txn_reads(self, works: List[_StaticWork]) -> None:
+        """The round's reads inside interactive transactions, under the
+        dispatch lock: the txids resolved to their registered
+        transactions (an unknown or finished one is that work's
+        ``KeyError``), then every read that is the fused serving read
+        alone (``TransactionManager.read_merges``: no writeset to
+        overlay, no composite type) answered by ONE
+        ``read_objects_group`` — one device round trip a touched table
+        for all of them, each row at its own transaction's snapshot.
+        The others, and all of them if the merged call fails (one bad
+        request must fail alone), are read one by one."""
+        txm = self.node.txm  # txn_read parks works only with a local txm
+        merged, solo = [], []
+        for w in works:
+            txn = self._txns.get(w.txid)
+            if txn is None or not txn.active:
+                w.error = KeyError(
+                    f"unknown or finished transaction {w.txid}")
+                self._complete(w)
+            elif txm.read_merges(w.objects, txn):
+                merged.append((w, txn))
+            else:
+                solo.append((w, txn))
+        counts = self._txn_reads
+        if merged:
+            rows = sum(len(w.objects) for w, _ in merged)
+            try:
+                with span("serve.txn_read", reads=len(merged), rows=rows):
+                    vals = txm.read_objects_group(
+                        [(w.objects, txn) for w, txn in merged])
+            except Exception:
+                solo = merged + solo  # isolate the offender
+            else:
+                counts["groups"] += 1
+                counts["reads"] += len(merged)
+                counts["rows"] += rows
+                now = time.monotonic()
+                for (w, _), v in zip(merged, vals):
+                    w.result = v
+                    w.batch_id = counts["groups"]
+                    w.t_ready = now
+                    self._complete(w)
+        for w, txn in solo:
+            counts["inline"] += 1
+            try:
+                w.result = txm.read_objects(w.objects, txn)
+            except Exception as e:
+                w.error = e
+            w.t_ready = time.monotonic()
+            self._complete(w)
+
     def _covered_vc(self):
         """Freshest locally-covered clock (entry-wise), or None when the
         node doesn't expose one (then every clocked read runs solo)."""
@@ -2086,6 +2180,15 @@ class ProtocolServer:
             return MessageCode.COMMIT_RESP, {
                 "commit_clock": [int(x) for x in vc]
             }
+        if code == MessageCode.READ_OBJECTS:
+            # a transaction's read joins the merge point too (the one
+            # helper of both dialects)
+            vals = self.txn_read(
+                body["txid"], _decode_objects(body["objects"]),
+                deadline=deadline, tenant=body.get("tenant"))
+            return MessageCode.READ_OBJECTS_RESP, {
+                "values": [encode_value(v) for v in vals]
+            }
         if code == MessageCode.REPLICA_ADMIN:
             # replica registry op (console replica add/remove/status),
             # OUTSIDE the dispatch lock: pure registry bookkeeping on
@@ -2106,14 +2209,17 @@ class ProtocolServer:
                 "checkpoint": self.node.checkpoint_now()
             }
         with self._lock:
-            # deadline re-checked at dequeue (= after the lock convoy):
-            # a request that outlived its caller is not executed
-            try:
-                check_deadline(deadline, "dispatch")
-            except DeadlineExceeded:
-                self.metrics.shed.inc(plane="deadline")
-                raise
+            self._check_dispatch_deadline(deadline)
             return self._dispatch(code, body)
+
+    def _check_dispatch_deadline(self, deadline) -> None:
+        """Deadline re-checked at dequeue (= after the lock convoy): a
+        request that outlived its caller is not executed."""
+        try:
+            check_deadline(deadline, "dispatch")
+        except DeadlineExceeded:
+            self.metrics.shed.inc(plane="deadline")
+            raise
 
     def _dispatch(self, code: MessageCode, body: Any):
         node = self.node
@@ -2123,12 +2229,6 @@ class ProtocolServer:
             )
             self._txns[txn.txid] = txn
             return MessageCode.START_TRANSACTION_RESP, {"txid": txn.txid}
-        if code == MessageCode.READ_OBJECTS:
-            txn = self._txn(body["txid"])
-            vals = node.read_objects(_decode_objects(body["objects"]), txn)
-            return MessageCode.READ_OBJECTS_RESP, {
-                "values": [encode_value(v) for v in vals]
-            }
         if code == MessageCode.UPDATE_OBJECTS:
             txn = self._txn(body["txid"])
             try:
@@ -2310,6 +2410,10 @@ class ProtocolServer:
             # reply then leaving from the stage that answers) by the
             # drain thread itself / handed to a connection worker
             "direct": dict(self._direct),
+            # reads inside interactive transactions at the merge point:
+            # merged reads, the reads and objects they answered, and the
+            # reads answered one by one
+            "txn_reads": dict(self._txn_reads),
         }
         # per-path stage split of every request since boot, and the
         # slowest stage records since the last status read (ISSUE 24)

@@ -300,6 +300,13 @@ def key_to_shard(key: Any, bucket: str, n_shards: int) -> int:
     return shard_of(key, bucket, n_shards)
 
 
+def _row_vcs(read_vc, n: int) -> np.ndarray:
+    """A batch's read VCs, one a row (``[n, D]``): a single VC is the
+    batch whose rows all read at it."""
+    vc = np.asarray(read_vc, np.int32)
+    return np.broadcast_to(vc, (n, vc.shape[-1]))
+
+
 def _pad_lane(x, width: int, dtype) -> np.ndarray:
     """Zero-pad an effect lane to a (wider) tier's width."""
     x = np.asarray(x, dtype)
@@ -1491,7 +1498,7 @@ class KVStore:
                         [filled[n][1] for n in over],
                         [filled[n][2] for n in over],
                         slot["head"], slot["head_vc"])
-                    full = {f: _cut(x, len(over)) for f, x in full.items()}
+                    full = _cut(full, len(over))
                     for k, n in enumerate(over):
                         filled[n][3] = ty.value(
                             {f: x[k] for f, x in full.items()},
@@ -1670,11 +1677,12 @@ class KVStore:
         self, objects: Sequence[BoundObject], read_vc: np.ndarray,
         served: bool = False,
     ) -> List[Dict[str, np.ndarray]]:
-        """Materialized per-key states for a batch of bound objects at one
-        read VC (grouped by type into batched device folds).  ``served``:
-        the rows are a client's reads, counted as the locked read plane's
-        (``pipeline.fold.reads_by_head`` / ``reads_by_fold``)."""
-        read_vc = np.asarray(read_vc, np.int32)
+        """Materialized per-key states for a batch of bound objects, each
+        at its own read VC (``read_vc``: ``[n, D]``, or one VC that every
+        row reads at), grouped by table into batched device folds.
+        ``served``: the rows are a client's reads, counted as the locked
+        read plane's (``pipeline.fold.reads_by_head`` / ``reads_by_fold``)."""
+        read_vcs = _row_vcs(read_vc, len(objects))
         by_type: Dict[str, list] = {}
         out: List[Dict[str, np.ndarray] | None] = [None] * len(objects)
         for i, (key, type_name, bucket) in enumerate(objects):
@@ -1694,7 +1702,7 @@ class KVStore:
             t = self.table(tname_t)
             shards = np.asarray([x[1] for x in items], np.int64)
             rows = np.asarray([x[2] for x in items], np.int64)
-            vcs = np.broadcast_to(read_vc, (len(items), read_vc.shape[-1]))
+            vcs = read_vcs[[x[0] for x in items]]
             # head gather first (exact for rows whose head VC ≤ read VC:
             # a remove's downstream reads the state it observes this way,
             # a tenth of a fill), then the versioned snapshot + ring fold
@@ -1720,10 +1728,10 @@ class KVStore:
                     gi = items[j][0]  # global object index
                     key, _, bucket = objects[gi]
                     by_shard.setdefault(items[j][1], []).append(
-                        (j, key, tname_t, bucket)
+                        (j, key, tname_t, bucket, vcs[j])
                     )
                 for shard, wants in by_shard.items():
-                    reps = self._replay_read_many(shard, wants, read_vc)
+                    reps = self._replay_read_many(shard, wants)
                     for j, rep in reps.items():
                         for f in state:
                             state[f][j] = rep[f]
@@ -1779,8 +1787,12 @@ class KVStore:
         second log replay for them) and for rows that hold, by the
         host's count of their slots, more than the view's
         ``resolve_top`` — :meth:`read_states` answers those at once,
-        where the view's launch would only say "truncated"."""
-        read_vc = np.asarray(read_vc, np.int32)
+        where the view's launch would only say "truncated".
+
+        ``read_vc`` is ``[n, D]``, a row's own read VC (the reads of
+        several transactions in one batch), or one VC that every row
+        reads at."""
+        read_vcs = _row_vcs(read_vc, len(objects))
         out: List[Dict[str, np.ndarray] | None] = [None] * len(objects)
         by_type: Dict[str, list] = {}
         wide: List[int] = []
@@ -1799,7 +1811,7 @@ class KVStore:
             by_type.setdefault(tname_t, []).append((i, shard, row))
         if wide:
             states = self.read_states(
-                [objects[i] for i in wide], read_vc, served=True)
+                [objects[i] for i in wide], read_vcs[wide], served=True)
             for i, st in zip(wide, states):
                 out[i] = full_out[i] = st
         for tname_t, items in by_type.items():
@@ -1807,7 +1819,7 @@ class KVStore:
             ty = t.ty
             shards = np.asarray([x[1] for x in items], np.int64)
             rows = np.asarray([x[2] for x in items], np.int64)
-            vcs = np.broadcast_to(read_vc, (len(items), read_vc.shape[-1]))
+            vcs = read_vcs[[x[0] for x in items]]
             resolved, _, complete = t.read_resolved(shards, rows, vcs)
             for j, (i, _, _) in enumerate(items):
                 out[i] = {f: x[j] for f, x in resolved.items()}
@@ -1819,10 +1831,10 @@ class KVStore:
                     gi = items[j][0]
                     key, _, bucket = objects[gi]
                     by_shard.setdefault(items[j][1], []).append(
-                        (int(j), key, tname_t, bucket)
+                        (int(j), key, tname_t, bucket, vcs[j])
                     )
                 for shard, wants in by_shard.items():
-                    reps = self._replay_read_many(shard, wants, read_vc)
+                    reps = self._replay_read_many(shard, wants)
                     for j, rep in reps.items():
                         gi = items[j][0]
                         if full_out is not None:
@@ -1854,19 +1866,20 @@ class KVStore:
         ]
 
     # ------------------------------------------------------------------
-    def _replay_read_many(self, shard: int, wants, read_vc):
-        """Several keys' states at ``read_vc``, rebuilt from the shard's
-        durable log: the answer to a read whose snapshot is older than
-        the device still holds history for (a row keeps what came after
-        its last GC).  ``wants`` = [(result_idx, key, tiered_name,
-        bucket)] — a state is rebuilt at the key's CURRENT tier width
+    def _replay_read_many(self, shard: int, wants):
+        """Several keys' states, each at its own read VC, rebuilt from
+        the shard's durable log: the answer to a read whose snapshot is
+        older than the device still holds history for (a row keeps what
+        came after its last GC).  ``wants`` = [(result_idx, key,
+        tiered_name, bucket, read_vc)] — a state is rebuilt at the key's
+        CURRENT tier width
         (wide enough for every logged effect, since the live store
         promoted before any wide effect applied).
 
         A key's logged effects come from the log's index by key
         (``LogManager.key_history``: no scan after the key's first), and
         are folded onto the key's replay base when that is not newer
-        than ``read_vc`` — what such a read costs is the effects since
+        than the read VC — what such a read costs is the effects since
         the base, not the log's length nor the key's."""
         if self.log is None:
             raise RuntimeError(
@@ -1890,13 +1903,12 @@ class KVStore:
                 "checkpoint-truncated and no longer holds history below "
                 "the checkpoint stamp"
             )
-        read_vc = np.asarray(read_vc, np.int32)
         t0 = time.monotonic()
         with span("serve.replay", shard=shard, keys=len(wants)), \
                 self._replay_lock:
             out = {
                 j: self._replay_one(shard, key, bucket, tname_t, read_vc)
-                for j, key, tname_t, bucket in wants
+                for j, key, tname_t, bucket, read_vc in wants
             }
         self.replays += len(wants)
         self.replay_seconds += time.monotonic() - t0
@@ -1966,8 +1978,8 @@ class KVStore:
             np.stack([o[2] for o in ops]),
             np.asarray([o[3] for o in ops], np.int32),
             l, base_vc, read_vc, bottom=bottom)
-        state = jax.tree.map(np.asarray, state)  # sync-ok: replay
-        # fallback path materializes host states for the caller
+        state = jax.device_get(state)  # sync-ok: replay fallback path
+        # materializes host states for the caller (one wait for all)
         self._observe_fold(strategy, ty.name, time.monotonic() - t0)
         return state
 

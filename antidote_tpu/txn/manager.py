@@ -390,6 +390,43 @@ class TransactionManager:
                 out[i] = vals[j]
         return out
 
+    def read_merges(self, objects: Sequence[BoundObject],
+                    txn: Transaction) -> bool:
+        """Whether this read of ``txn`` is the fused serving read alone,
+        which :meth:`read_objects_group` batches across transactions: the
+        transaction has no write of its own to overlay on what it reads
+        and no object is a composite (a map reads level by level)."""
+        if txn.writeset:
+            return False
+        composite_names = _composite_names()
+        return not any(t in composite_names for _, t, _ in objects)
+
+    def read_objects_group(
+        self, reads: Sequence[Tuple[Sequence[BoundObject], Transaction]]
+    ) -> List[List[Any]]:
+        """:meth:`read_objects` for the reads of several transactions at
+        once — ``[(objects, txn), ...]``, each of which
+        :meth:`read_merges` — in one batched store read in which every
+        row carries its own transaction's ``snapshot_vc``: one device
+        round trip a touched table for the group, where one call a
+        transaction makes one each.  Per transaction it keeps what
+        ``read_objects`` does (the txn turns read-bearing, the operation
+        count, the decoded-value cache probed and back-filled at that
+        transaction's snapshot)."""
+        for objects, txn in reads:
+            assert txn.active
+            if not self.read_merges(objects, txn):
+                raise ValueError(
+                    f"transaction {txn.txid}'s read does not merge "
+                    "(a writeset to overlay, or a composite type)")
+        vals = self._cached_values_group(reads,
+                                         self._values_resolved_uncached)
+        for objects, txn in reads:
+            txn.did_read = True
+            if self.metrics is not None:
+                self.metrics.operations.inc(len(objects), type="read")
+        return vals
+
     def _read_values_resolved(self, objs, txn: Transaction) -> List[Any]:
         """Values via the fused serving read.  Types with device resolution
         decode the compact view host-side (``value_from_resolved``);
@@ -400,38 +437,52 @@ class TransactionManager:
         cache (the host-level snapshot_cache analogue): a hit skips the
         device gather AND the decode; misses fall through, and latest
         reads back-fill the cache."""
-        return self._cached_values(
-            objs, txn, lambda miss: self._values_resolved_uncached(miss, txn)
-        )
+        return self._cached_values_group(
+            [(objs, txn)], self._values_resolved_uncached)[0]
 
-    def _cached_values(self, objs, txn: Transaction, compute) -> List[Any]:
+    def _cached_values_group(self, reads, compute) -> List[List[Any]]:
         """The decoded-value-cache protocol shared by plain and composite
-        reads: bulk probe, compute the misses via ``compute``, back-fill
-        latest reads under the epoch guard (a commit between capture and
-        fill drops the fill)."""
-        read_tup = tuple(int(x) for x in txn.snapshot_vc)
-        allv, miss_idx = self.store.value_cache_bulk_get(objs, read_tup)
-        if not miss_idx:
-            return allv
-        fill_vc = self.store.applied_max_tuple()
-        fill_epoch = self.store.mutation_epoch
-        is_latest = all(r >= f for r, f in zip(read_tup, fill_vc))
-        miss_objs = [objs[j] for j in miss_idx]
-        vals = compute(miss_objs)
-        if is_latest:
-            for (key, _t, bucket), v in zip(miss_objs, vals):
+        reads, for ``[(objs, txn), ...]``: bulk probe, each transaction's
+        objects at its own snapshot (a hit never reaches the device);
+        the misses of all of them computed by ONE ``compute(miss_objs,
+        read_vcs)`` (``read_vcs`` ``[n, D]``: a miss's own transaction's
+        snapshot); latest reads back-filled under the epoch guard (a
+        commit between capture and fill drops the fill)."""
+        outs: List[List[Any]] = []
+        miss: List[Tuple[int, int, bool]] = []  # (read, position, latest)
+        fill_vc = fill_epoch = None
+        for r, (objs, txn) in enumerate(reads):
+            read_tup = tuple(int(x) for x in txn.snapshot_vc)
+            allv, miss_idx = self.store.value_cache_bulk_get(objs, read_tup)
+            outs.append(allv)
+            if not miss_idx:
+                continue
+            if fill_vc is None:
+                fill_vc = self.store.applied_max_tuple()
+                fill_epoch = self.store.mutation_epoch
+            is_latest = all(x >= f for x, f in zip(read_tup, fill_vc))
+            miss.extend((r, j, is_latest) for j in miss_idx)
+        if not miss:
+            return outs
+        miss_objs = [reads[r][0][j] for r, j, _ in miss]
+        vals = compute(miss_objs, np.stack(
+            [reads[r][1].snapshot_vc for r, _, _ in miss]))
+        for (r, j, is_latest), (key, _t, bucket), v in zip(
+                miss, miss_objs, vals):
+            if is_latest:
                 self.store.value_cache_fill(key, bucket, v, fill_vc,
                                             fill_epoch)
-        for j, gi in enumerate(miss_idx):
-            allv[gi] = vals[j]
-        return allv
+            outs[r][j] = v
+        return outs
 
-    def _values_resolved_uncached(self, objs, txn: Transaction) -> List[Any]:
+    def _values_resolved_uncached(self, objs, read_vcs) -> List[Any]:
+        """``objs`` decoded from the store, each at its row of
+        ``read_vcs`` (``[n, D]``)."""
         from antidote_tpu.crdt.base import RESOLVE_OVERFLOW
 
         replayed: Dict[int, Dict[str, Any]] = {}
         resolved = self.store.read_resolved(
-            objs, txn.snapshot_vc, full_out=replayed
+            objs, read_vcs, full_out=replayed
         )
         vals: List[Any] = [None] * len(objs)
         refetch = []
@@ -454,7 +505,7 @@ class TransactionManager:
                 vals[j] = v
         if refetch:
             states = self.store.read_states(
-                [objs[j] for j in refetch], txn.snapshot_vc
+                [objs[j] for j in refetch], read_vcs[refetch]
             )
             for j, st in zip(refetch, states):
                 _, t, _ = objs[j]
@@ -469,9 +520,9 @@ class TransactionManager:
         whole; any write to a field or the membership invalidates the
         parent entry (the derived-key walk in KVStore.apply_effects)."""
         if not txn.writeset:
-            return self._cached_values(
-                objects, txn, lambda miss: self._assemble_maps(miss, txn)
-            )
+            return self._cached_values_group(
+                [(objects, txn)],
+                lambda miss, _vcs: self._assemble_maps(miss, txn))[0]
         return self._assemble_maps(objects, txn)
 
     def _assemble_maps(self, objects, txn: Transaction) -> List[dict]:

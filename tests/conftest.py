@@ -31,6 +31,10 @@ from antidote_tpu.config import (  # noqa: E402
 # spams feature-mismatch warnings on every load
 enable_compilation_cache(XLA_CACHE_DIR + "_t8")
 
+import contextlib  # noqa: E402
+import threading  # noqa: E402
+import time  # noqa: E402
+
 import pytest  # noqa: E402
 
 
@@ -137,3 +141,89 @@ def check_writeback_fill(store, objs, mirror: bool, cap: int = 5):
         assert filled == {(o[0], o[2], o[1]): v
                           for o, v in zip(objs, vals)}
     return vals
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 34: transactions opened along a write stream, for the reads that
+# carry one read VC a row
+# ---------------------------------------------------------------------------
+#: small widths: a ring overflows every fourth op of a key (GC), sets
+#: outgrow 4 / 16 element slots (tiers 1 and 2)
+HISTORY_CFG = dict(n_shards=4, max_dcs=2, ops_per_key=4, snap_versions=2,
+                   set_slots=4, keys_per_table=64, batch_buckets=(8, 64))
+HISTORY_KEYS = ("head", "ring", "deep", "wide", "never")
+
+
+def history_scenario(node, type_name):
+    """Four transactions opened at different points of a write stream over
+    ``HISTORY_KEYS`` of ``type_name`` (``set_aw`` | ``counter_pn``), the
+    last one at the head.  For the older ones ``head`` is answered by the
+    head state, ``ring`` by a ring fold, ``deep`` (written past its ring
+    again and again since) only by the log replay, ``wide`` (a set past 4
+    and 16 elements) from a slot tier; ``never`` is never written.
+    Returns (objects, transactions, the value of every object at every
+    transaction's snapshot)."""
+    state, n = {}, [0]
+    is_set = type_name == "set_aw"
+
+    def write(key, times=1):
+        for _ in range(times):
+            n[0] += 1
+            if is_set:
+                op = ("add", f"{key}:{n[0]}")
+                state.setdefault(key, []).append(op[1])
+            else:
+                op = ("increment", n[0])
+                state[key] = state.get(key, 0) + n[0]
+            node.update_objects([(key, type_name, "b", op)])
+
+    objs = [(k, type_name, "b") for k in HISTORY_KEYS]
+    txns, expect = [], []
+
+    def snap():
+        txns.append(node.start_transaction())
+        expect.append([sorted(state.get(k, [])) if is_set
+                       else state.get(k, 0) for k in HISTORY_KEYS])
+
+    write("head", 2), write("ring"), write("deep", 2), write("wide", 6)
+    snap()
+    write("ring"), write("deep", 6), write("wide", 5)
+    snap()
+    write("ring"), write("deep", 6), write("wide", 6)
+    snap()
+    write("ring")
+    snap()
+    return objs, txns, expect
+
+
+def history_values(type_name, vals):
+    """Read values in the form ``history_scenario`` expects them."""
+    return [sorted(v) if type_name == "set_aw" else v for v in vals]
+
+
+@contextlib.contextmanager
+def locked_worker_held(srv, parked: int, timeout: float = 60.0):
+    """Hold a ``ProtocolServer``'s locked worker between two of its
+    rounds until ``parked`` works wait at its queue: when the block ends
+    they are ONE round's work (the block itself waits for them)."""
+    gate = threading.Event()
+    drain = srv._drain_batch
+
+    def gated(q, *a, **kw):
+        if q is srv._locked_q:
+            gate.wait(timeout)
+        return drain(q, *a, **kw)
+
+    srv._drain_batch = gated
+    # the worker sits in an ungated wait: one request takes it through
+    srv.static_update([("gate", "counter_pn", "gate", ("increment", 1))],
+                      None)
+    try:
+        yield
+        end = time.monotonic() + timeout
+        while srv._locked_q.qsize() < parked:
+            assert time.monotonic() < end, "the works never parked"
+            time.sleep(0.005)
+    finally:
+        del srv._drain_batch
+        gate.set()

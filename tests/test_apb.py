@@ -536,3 +536,125 @@ def test_apb_commit_busy_keeps_descriptor_retryable():
         c.close()
     finally:
         srv.close()
+
+
+# ---------------------------------------------------------------------------
+# reads inside transactions at the merge point (ISSUE 34), apb dialect
+# ---------------------------------------------------------------------------
+_CNT = {"key": b"hot", "type": 3, "bucket": b"b"}
+
+
+def _apb_read(conn, txd, out, name):
+    try:
+        out[name] = conn.call("ApbReadObjects", {
+            "transaction_descriptor": txd, "boundobjects": [_CNT]})
+    except Exception as e:  # pragma: no cover - failure detail
+        out[name] = e
+
+
+def test_apb_txn_reads_of_a_round_merge_each_at_its_own_snapshot():
+    """The apb dialect reaches the merged read through the server's one
+    helper: N connections in transactions opened along an increment
+    stream read at once, one batched read answers them, each at its own
+    snapshot."""
+    import threading
+
+    from conftest import locked_worker_held
+
+    node, srv = _mk_server()
+    n = 5
+    try:
+        conns, txds = [], []
+        for i in range(n):
+            node.update_objects([(b"hot", "counter_pn", b"b",
+                                  ("increment", 1))])
+            c = _ApbConn("127.0.0.1", srv.port)
+            _, resp = c.call("ApbStartTransaction", {})
+            conns.append(c), txds.append(resp["transaction_descriptor"])
+        node.update_objects([(b"hot", "counter_pn", b"b",
+                              ("increment", 9))])
+        out: dict = {}
+        ts = [threading.Thread(target=_apb_read,
+                               args=(conns[i], txds[i], out, i))
+              for i in range(n)]
+        with locked_worker_held(srv, parked=n):
+            for t in ts:
+                t.start()
+        for t in ts:
+            t.join(timeout=60)
+        for i in range(n):
+            name, resp = out[i]
+            assert name == "ApbReadObjectsResp" and resp["success"], out[i]
+            assert resp["objects"][0]["counter"]["value"] == i + 1
+        assert srv._pipeline_status()["txn_reads"] == {
+            "groups": 1, "reads": n, "rows": n, "inline": 0}
+        for c in conns:
+            c.close()
+    finally:
+        srv.close()
+
+
+def test_apb_bad_txn_read_fails_alone_and_a_writeset_reads_inline():
+    """apb: an unknown descriptor, a transaction aborted while its read
+    was parked and a read past the server's default deadline answer
+    ApbErrorResp for themselves alone; a transaction that has written
+    reads its own write, counted `inline`."""
+    import threading
+    import time
+
+    from conftest import locked_worker_held
+
+    cfg = AntidoteConfig(n_shards=2, max_dcs=2, keys_per_table=64,
+                         batch_buckets=(16, 64))
+    node = AntidoteNode(cfg)
+    srv = ProtocolServer(node, port=0, default_deadline_ms=2000)
+    try:
+        node.update_objects([(b"hot", "counter_pn", b"b",
+                              ("increment", 4))])
+        conns = [_ApbConn("127.0.0.1", srv.port) for _ in range(3)]
+        good, orphan = (
+            c.call("ApbStartTransaction", {})[1]["transaction_descriptor"]
+            for c in conns[:2])
+        out: dict = {}
+        ts = [threading.Thread(target=_apb_read, args=a + (out, a[1]))
+              for a in ((conns[0], good), (conns[1], orphan),
+                        (conns[2], b"999999999"))]
+        with locked_worker_held(srv, parked=3):
+            for t in ts:
+                t.start()
+            srv._abort_orphan(int(orphan))
+        for t in ts:
+            t.join(timeout=60)
+        name, resp = out[good]
+        assert name == "ApbReadObjectsResp", out
+        assert resp["objects"][0]["counter"]["value"] == 4
+        for txd in (orphan, b"999999999"):
+            name, resp = out[txd]
+            assert name == "ApbErrorResp", out
+            assert b"unknown or finished transaction" in resp["errmsg"]
+        # a writeset: read-your-writes, one by one
+        name, resp = conns[0].call("ApbUpdateObjects", {
+            "transaction_descriptor": good,
+            "updates": [{"boundobject": _CNT,
+                         "operation": {"counterop": {"inc": 3}}}]})
+        assert name == "ApbOperationResp", resp
+        _apb_read(conns[0], good, out, "own")
+        assert out["own"][1]["objects"][0]["counter"]["value"] == 7
+        assert srv._pipeline_status()["txn_reads"] == {
+            "groups": 1, "reads": 1, "rows": 1, "inline": 1}
+        # the server's default deadline runs out while the read is parked
+        late = threading.Thread(target=_apb_read,
+                                args=(conns[0], good, out, "late"))
+        with locked_worker_held(srv, parked=1):
+            late.start()
+            time.sleep(2.2)
+        late.join(timeout=60)
+        name, resp = out["late"]
+        assert name == "ApbErrorResp", out
+        assert apb.parse_error_text(resp["errmsg"])["kind"] == "deadline"
+        _apb_read(conns[0], good, out, "after")
+        assert out["after"][1]["objects"][0]["counter"]["value"] == 7
+        for c in conns:
+            c.close()
+    finally:
+        srv.close()

@@ -415,3 +415,51 @@ def test_read_resolved_pads_the_flat_read_to_a_batch_bucket(at):
         np.testing.assert_array_equal(complete, np.asarray(want_complete))
     assert seen == [16, 16, 16, 64, 64]
     assert set(seen) <= set(cfg.batch_buckets)
+
+
+# ---------------------------------------------------------------------------
+# one read VC a row (ISSUE 34): the reads of several transactions in one
+# batch of KVStore.read_resolved / read_states
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("placement", ["one_device", "mesh2"])
+@pytest.mark.parametrize("type_name", ["set_aw", "counter_pn"])
+@pytest.mark.parametrize("read", ["read_resolved", "read_states"])
+def test_kvstore_reads_with_a_vc_a_row_equal_one_vc_calls(
+        tmp_path, read, type_name, placement):
+    """A batch in which every row carries its own read VC — the same key
+    at several snapshots among them — answers each row as a call with
+    that one VC does: head rows, ring folds, rows only the log replay
+    answers, slot-tier rows, a never-written key; one device and a
+    2-device mesh."""
+    from antidote_tpu.api.node import AntidoteNode
+    from antidote_tpu.parallel import MeshServingPlane
+    from conftest import HISTORY_CFG, history_scenario
+
+    cfg = AntidoteConfig(**HISTORY_CFG)
+    plane = MeshServingPlane(cfg, 2) if placement == "mesh2" else None
+    node = AntidoteNode(cfg, log_dir=str(tmp_path),
+                        sharding=plane.sharding if plane else None)
+    if plane is not None:
+        plane.metrics = node.metrics
+        plane.attach(node.store)
+    store = node.store
+    objs, txns, _ = history_scenario(node, type_name)
+    batch = [(o, t.snapshot_vc) for t in txns for o in objs]
+    fn = getattr(store, read)
+    resolved = read == "read_resolved"
+    whole = {}  # read_resolved: rows it read whole (wide, replayed)
+    got = fn([o for o, _ in batch], np.stack([vc for _, vc in batch]),
+             **({"full_out": whole} if resolved else {}))
+    assert store.fold_status()["replays"] > 0
+    for i, (o, vc) in enumerate(batch):
+        whole_one = {}
+        want, = fn([o], vc, **({"full_out": whole_one} if resolved else {}))
+        assert (i in whole) == bool(whole_one), (i, o)
+        assert got[i].keys() == want.keys(), (i, o)
+        for f in want:
+            np.testing.assert_array_equal(got[i][f], want[f],
+                                          err_msg=str((i, o, f)))
+    assert not resolved or whole
+    with pytest.raises(ValueError):
+        fn([o for o, _ in batch], np.stack([vc for _, vc in batch[:3]]))
+    store.log.close()

@@ -2,6 +2,7 @@
 (clocksi_SUITE read-your-writes/isolation/concurrency, antidote_SUITE
 static+interactive API, commit_hooks_SUITE; SURVEY §4 tier-3)."""
 
+import numpy as np
 import pytest
 
 from antidote_tpu.api import AbortError, AntidoteNode
@@ -327,3 +328,99 @@ def test_rga_same_txn_inserts_have_distinct_uids(node):
     node.commit_transaction(txn)
     vals, _ = node.read_objects([("d2", "rga", "b")])
     assert vals[0] == ["q"]
+
+
+# ---------------------------------------------------------------------------
+# reads of several transactions in one batched store read (ISSUE 34)
+# ---------------------------------------------------------------------------
+@pytest.fixture
+def history(tmp_path, request):
+    from antidote_tpu.config import AntidoteConfig
+    from conftest import HISTORY_CFG, history_scenario
+
+    node = AntidoteNode(AntidoteConfig(**HISTORY_CFG),
+                        log_dir=str(tmp_path))
+    yield (node, request.param) + history_scenario(node, request.param)
+    node.store.log.close()
+
+
+both_types = pytest.mark.parametrize(
+    "history", ["set_aw", "counter_pn"], indirect=True)
+
+
+@both_types
+def test_read_objects_group_equals_one_read_a_transaction(history):
+    """`read_objects_group` over transactions with distinct snapshots
+    answers every object at its own transaction's snapshot, value for
+    value what `read_objects` answers one transaction at a time: rows the
+    head answers, rows a ring fold answers, a row below the device's
+    coverage (log replay at its own VC), a never-written key, and for
+    sets a wide key in a slot tier."""
+    from conftest import history_values
+
+    node, type_name, objs, txns, expect = history
+    txm, store = node.txm, node.store
+    ops = node.metrics.operations.value(type="read")
+    assert not any(t.did_read for t in txns)
+    got = txm.read_objects_group([(objs, t) for t in txns])
+    assert all(t.did_read for t in txns)
+    assert node.metrics.operations.value(type="read") - ops == \
+        len(objs) * len(txns)
+    fold = store.fold_status()
+    assert fold["replays"] > 0 and fold["reads_by_fold"] > 0 \
+        and fold["reads_by_head"] > 0
+    if type_name == "set_aw":
+        assert store.directory[("wide", "b")][0] == "set_aw#2"
+    with store._value_cache_lock:
+        store._value_cache.clear()
+    one = [txm.read_objects(objs, t) for t in txns]
+    for i in range(len(txns)):
+        assert history_values(type_name, got[i]) == expect[i], i
+        assert history_values(type_name, one[i]) == expect[i], i
+    assert len({repr(e) for e in expect}) == len(expect)
+
+
+@both_types
+def test_read_objects_group_serves_cache_hits_off_the_device(history):
+    """A transaction at the head whose objects the decoded-value cache
+    holds rides a group without reaching the store; the group's other
+    transaction is read at its own, older snapshot, alone."""
+    from conftest import history_values
+
+    node, type_name, objs, txns, expect = history
+    txm, store = node.txm, node.store
+    assert history_values(type_name, txm.read_objects(objs, txns[3])) \
+        == expect[3]            # a latest read: back-fills the cache
+    latest = node.start_transaction()
+    seen = []
+    read_resolved = store.read_resolved
+
+    def spy(objects, read_vc, full_out=None):
+        seen.append((list(objects), np.array(read_vc)))
+        return read_resolved(objects, read_vc, full_out=full_out)
+
+    store.read_resolved = spy
+    got = txm.read_objects_group([(objs, latest), (objs, txns[0])])
+    assert history_values(type_name, got[0]) == expect[3]
+    assert history_values(type_name, got[1]) == expect[0]
+    (objects, vcs), = seen
+    assert objects == objs and vcs.shape == (len(objs), 2)
+    assert (vcs == txns[0].snapshot_vc).all()
+    # every object of the latest transaction was a hit: a group of its
+    # own launches nothing
+    del seen[:]
+    assert history_values(
+        type_name, txm.read_objects_group([(objs, latest)])[0]) == expect[3]
+    assert seen == []
+
+
+def test_read_merges_is_decided_from_what_the_request_holds(node):
+    txn = node.start_transaction()
+    plain = [("k", "counter_pn", "b"), ("s", "set_aw", "b")]
+    assert node.txm.read_merges(plain, txn)
+    assert not node.txm.read_merges(plain + [("m", "map_rr", "b")], txn)
+    node.update_objects([("k", "counter_pn", "b", ("increment", 1))], txn)
+    assert not node.txm.read_merges(plain, txn)
+    with pytest.raises(ValueError):  # its read overlays its own write
+        node.txm.read_objects_group([(plain, txn)])
+    assert node.read_objects(plain, txn) == [1, []]

@@ -403,3 +403,172 @@ def test_a_merged_group_crosses_to_the_device_once(cfg):
     for st in (pre, post):
         del st["write_plane"]["scatter"]
     assert status_delta.read(spec, ctx(pre, post)) is None
+
+
+# ---------------------------------------------------------------------------
+# reads inside transactions at the merge point (ISSUE 34)
+# ---------------------------------------------------------------------------
+def _txn_read_paths(srv, n):
+    """``pipeline.paths.txn_read`` once ``n`` such requests closed (a
+    connection thread closes its record after the reply left)."""
+    import time
+
+    end = time.monotonic() + 30
+    while True:
+        blk = srv._pipeline_status()["paths"].get("txn_read", {})
+        if blk.get("total", {}).get("count", 0) >= n:
+            return blk
+        assert time.monotonic() < end, blk
+        time.sleep(0.01)
+
+
+@pytest.mark.parametrize("type_name", ["counter_pn", "set_aw"])
+def test_txn_reads_of_a_round_merge_each_at_its_own_snapshot(cfg,
+                                                             type_name):
+    """N connections, each in a transaction opened at a different point
+    of a write stream to one hot key, read at once: the locked worker
+    answers them with ONE batched read, every answer its own
+    snapshot's."""
+    from antidote_tpu.proto.client import AntidoteClient
+    from antidote_tpu.proto.server import ProtocolServer
+    from conftest import locked_worker_held
+
+    node = AntidoteNode(cfg)
+    srv = ProtocolServer(node, port=0)
+    n = 6
+    hot = ("hot", type_name, "b")
+
+    def write(i):
+        op = ("add", f"e{i}") if type_name == "set_aw" else ("increment", 1)
+        node.update_objects([hot + (op,)])
+
+    def expect(i):
+        return ([f"e{j}" for j in range(i)] if type_name == "set_aw"
+                else i)
+
+    try:
+        clients = [AntidoteClient(port=srv.port) for _ in range(n)]
+        txns = []
+        for i, c in enumerate(clients):
+            write(i)
+            txns.append(c.start_transaction())
+        write(n), write(n + 1)
+        answers: list = [None] * n
+
+        def read(i):
+            try:
+                answers[i] = txns[i].read_objects([hot])[0]
+            except Exception as e:  # pragma: no cover - failure detail
+                answers[i] = e
+
+        ts = [threading.Thread(target=read, args=(i,)) for i in range(n)]
+        with locked_worker_held(srv, parked=n):
+            for t in ts:
+                t.start()
+        for t in ts:
+            t.join(timeout=60)
+        for i in range(n):
+            got = answers[i]
+            assert (sorted(got) if type_name == "set_aw" else got) \
+                == expect(i + 1), (i, got)
+        assert srv._pipeline_status()["txn_reads"] == {
+            "groups": 1, "reads": n, "rows": n, "inline": 0}
+        blk = _txn_read_paths(srv, n)
+        assert blk["parked"]["count"] == blk["exec"]["count"] == n
+        for c in clients:
+            c.close()
+    finally:
+        srv.close()
+
+
+def test_txn_read_with_a_writeset_or_a_map_is_answered_alone(cfg):
+    """What the request holds decides: a transaction that has written
+    reads its own write, a read naming a composite type assembles its
+    map, both one by one (`pipeline.txn_reads.inline`); a plain read of
+    the same connection merges."""
+    from antidote_tpu.proto.client import AntidoteClient
+    from antidote_tpu.proto.server import ProtocolServer
+
+    node = AntidoteNode(cfg)
+    srv = ProtocolServer(node, port=0)
+    try:
+        c = AntidoteClient(port=srv.port)
+        c.update_objects([("k", "counter_pn", "b", ("increment", 5))])
+        node.update_objects([("m", "map_rr", "b", ("update", {
+            ("n", "counter_pn"): ("increment", 3)}))])
+        whole, _ = c.read_objects([("m", "map_rr", "b")])
+        assert list(whole[0].values()) == [3]
+        t = c.start_transaction()
+        assert t.read_objects([("k", "counter_pn", "b")]) == [5]
+        assert t.read_objects([("m", "map_rr", "b")]) == whole
+        t.update_objects([("k", "counter_pn", "b", ("increment", 2))])
+        assert t.read_objects([("k", "counter_pn", "b")]) == [7]
+        t.commit()
+        assert srv._pipeline_status()["txn_reads"] == {
+            "groups": 1, "reads": 1, "rows": 1, "inline": 2}
+        c.close()
+    finally:
+        srv.close()
+
+
+def test_a_bad_txn_read_fails_alone_in_its_round(cfg):
+    """An unknown txid, a transaction its dead connection's clean-up
+    aborted while the read was parked (`_abort_orphan`) and an expired
+    deadline each fail their own request; the round's sound read is
+    answered, and nothing is stranded."""
+    from antidote_tpu.proto.client import (
+        AntidoteClient,
+        RemoteDeadline,
+        RemoteError,
+    )
+    from antidote_tpu.proto.codec import MessageCode
+    from antidote_tpu.proto.server import ProtocolServer
+    from conftest import locked_worker_held
+
+    node = AntidoteNode(cfg)
+    srv = ProtocolServer(node, port=0)
+    obj = ("k", "counter_pn", "b")
+    try:
+        node.update_objects([obj + (("increment", 4),)])
+        clients = [AntidoteClient(port=srv.port) for _ in range(4)]
+        good, orphan = (clients[i].start_transaction() for i in (0, 1))
+        late = clients[3].start_transaction()
+        out: dict = {}
+
+        def call(name, c, body):
+            try:
+                out[name] = c._call(MessageCode.READ_OBJECTS, body)["values"]
+            except Exception as e:
+                out[name] = e
+
+        bodies = {
+            "good": (clients[0], {"txid": good.txid, "objects": [obj]}),
+            "orphan": (clients[1], {"txid": orphan.txid, "objects": [obj]}),
+            "unknown": (clients[2], {"txid": 10 ** 9, "objects": [obj]}),
+            "late": (clients[3], {"txid": late.txid, "objects": [obj],
+                                  "deadline_ms": 20}),
+        }
+        ts = [threading.Thread(target=call, args=(k,) + v)
+              for k, v in bodies.items()]
+        with locked_worker_held(srv, parked=4):
+            for t in ts:
+                t.start()
+            import time
+            time.sleep(0.1)             # past the late read's deadline
+            srv._abort_orphan(orphan.txid)
+        for t in ts:
+            t.join(timeout=60)
+        assert out["good"] == [4]
+        for name in ("orphan", "unknown"):
+            assert isinstance(out[name], RemoteError), out
+            assert "unknown or finished transaction" in str(out[name])
+        assert isinstance(out["late"], RemoteDeadline), out
+        assert srv._pipeline_status()["txn_reads"] == {
+            "groups": 1, "reads": 1, "rows": 1, "inline": 0}
+        # the connections are all alive, their transactions too
+        assert late.read_objects([obj]) == [4]
+        assert good.read_objects([obj]) == [4]
+        for c in clients:
+            c.close()
+    finally:
+        srv.close()
