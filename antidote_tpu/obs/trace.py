@@ -16,7 +16,13 @@ Three pieces, all always on (no switch, flag or environment variable):
   stamps by one call per group.
 * :func:`device_program` — ``jax.jit`` under a stable name, so the
   trace's "XLA Modules" line reads ``jit_antidote_<what>`` and the ops
-  inside carry a ``jax.named_scope`` of the same name.
+  inside carry a ``jax.named_scope`` of the same name.  Each program
+  counts its own launches and their host time; one ``jax.monitoring``
+  listener counts every compilation of the process by program name
+  (:func:`program_status`, ``node_status()["programs"]``).
+* :class:`RoundAccumulator` — the wire server's locked worker: one call
+  a round (its idle wait, its busy part, the time its thread spent off
+  the CPU, the programs it launched, its phases).
 """
 
 from __future__ import annotations
@@ -24,9 +30,12 @@ from __future__ import annotations
 import functools
 import heapq
 import threading
+import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
+from jax import monitoring
+from jax._src import core as _jax_core
 
 #: prefix of every device program's name (module ``jit_antidote_<what>``)
 PROGRAM_PREFIX = "antidote_"
@@ -42,7 +51,10 @@ def device_program(what: str, fn=None, **jit_kw):
     """``jax.jit(fn, **jit_kw)`` named ``antidote_<what>``: the lowered
     module is ``jit_antidote_<what>`` and every op inside sits under a
     ``jax.named_scope`` of that name.  The body, its shapes and its
-    donation are the caller's, untouched.  Usable as a decorator."""
+    donation are the caller's, untouched.  The jitted function comes back
+    wrapped (:class:`_Program`): a call counts a launch of ``<what>``;
+    ``.lower`` and every other jit attribute are the jitted function's.
+    Usable as a decorator."""
     name = PROGRAM_PREFIX + what
 
     def wrap(f):
@@ -54,9 +66,156 @@ def device_program(what: str, fn=None, **jit_kw):
                 return f(*args, **kwargs)
 
         program.__name__ = program.__qualname__ = name
-        return jax.jit(program, **jit_kw)
+        return _Program(what, jax.jit(program, **jit_kw))
 
     return wrap if fn is None else wrap(fn)
+
+
+# ---------------------------------------------------------------------------
+# per-program launch and compile counters
+# ---------------------------------------------------------------------------
+#: fields of one program's row in ``node_status()["programs"]``
+PROGRAM_FIELDS = ("launches", "call_ms", "offcpu_ms", "compiles",
+                  "compile_ms", "cache_loads")
+_COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+                   "/jax/core/compile/jaxpr_to_mlir_module_duration",
+                   "/jax/core/compile/backend_compile_duration")
+_TRACE_EVENT, _BACKEND_EVENT = _COMPILE_EVENTS[0], _COMPILE_EVENTS[2]
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+_counts_lock = threading.Lock()
+#: what -> [launches, call seconds, off-CPU seconds]
+_launches: Dict[str, list] = {}
+#: what (or an eager op's name) -> [compiles, seconds, cache loads]
+_compiles: Dict[str, list] = {}
+
+
+class _ThreadCounts(threading.local):
+    #: device-program launches made on this thread (read by the locked
+    #: worker around a round: :func:`thread_launches`)
+    launches = 0
+    #: nesting of jaxpr traces open on this thread (an inner jit traced
+    #: inside an outer one is part of the outer's time)
+    trace_depth = 0
+    #: the compile in progress on this thread was a persistent-cache load
+    cache_hit = False
+
+
+_thread = _ThreadCounts()
+
+
+def thread_launches() -> int:
+    """Device-program launches the calling thread has made so far."""
+    return _thread.launches
+
+
+class _Program:
+    """A jitted device program that counts its launches: per call, the
+    host wall time inside it (operands transferred, the program
+    dispatched; an asynchronous dispatch does not wait for the device)
+    and that time less the calling thread's CPU time over the same call
+    — the time the thread waited, for the interpreter lock or a
+    transfer (an estimate a call, right in the sum).  One lock take a
+    launch.  A call made while an outer program is being traced is no
+    launch and is not counted."""
+
+    def __init__(self, what: str, jitted):
+        self._jit = jitted
+        with _counts_lock:
+            self._row = _launches.setdefault(what, [0, 0.0, 0.0])
+        functools.update_wrapper(self, jitted)
+
+    def __call__(self, *args, **kwargs):
+        if not _jax_core.trace_state_clean():
+            return self._jit(*args, **kwargs)
+        t0, c0 = time.perf_counter(), time.thread_time()
+        out = self._jit(*args, **kwargs)
+        c1, t1 = time.thread_time(), time.perf_counter()
+        _thread.launches += 1
+        wall = t1 - t0
+        row = self._row
+        with _counts_lock:
+            row[0] += 1
+            row[1] += wall
+            # not clamped at 0: where the thread's CPU clock ticks
+            # coarsely (some hosts: 10 ms) one call reads a whole tick or
+            # none, and only the sum over many calls is right
+            row[2] += wall - (c1 - c0)
+        return out
+
+    def __getattr__(self, attr):
+        return getattr(self._jit, attr)
+
+
+def _program_key(fun_name: str) -> str:
+    """``jit(antidote_<what>)`` / ``antidote_<what>`` -> ``<what>``;
+    ``jit(dynamic_slice)`` -> ``dynamic_slice``."""
+    if fun_name.endswith(")") and "(" in fun_name:
+        fun_name = fun_name[fun_name.index("(") + 1:-1]
+    if fun_name.startswith(PROGRAM_PREFIX):
+        fun_name = fun_name[len(PROGRAM_PREFIX):]
+    return fun_name
+
+
+def _on_scalar(event: str, value, **kw) -> None:
+    # a compile stage's start (jax records it as a scalar on __enter__)
+    if event == _TRACE_EVENT:
+        _thread.trace_depth += 1
+
+
+def _on_event(event: str, **kw) -> None:
+    if event == _CACHE_HIT_EVENT:
+        _thread.cache_hit = True
+
+
+def _on_duration(event: str, seconds: float, **kw) -> None:
+    if event not in _COMPILE_EVENTS:
+        return
+    if event == _TRACE_EVENT:
+        _thread.trace_depth = depth = max(0, _thread.trace_depth - 1)
+        if depth:
+            return             # inside an outer trace: counted with it
+    key = _program_key(str(kw.get("fun_name", "?")))
+    loaded = False
+    if event == _BACKEND_EVENT:
+        loaded, _thread.cache_hit = _thread.cache_hit, False
+    with _counts_lock:
+        row = _compiles.get(key)
+        if row is None:
+            row = _compiles[key] = [0, 0.0, 0]
+        row[1] += seconds
+        if event == _BACKEND_EVENT:
+            row[2 if loaded else 0] += 1
+
+
+monitoring.register_scalar_listener(_on_scalar)
+monitoring.register_event_listener(_on_event)
+monitoring.register_event_duration_secs_listener(_on_duration)
+
+
+def program_status() -> dict:
+    """``{<what>: {launches, call_ms, offcpu_ms, compiles, compile_ms,
+    cache_loads}, "total": {...}}`` since the process started: every
+    device program's launches, and every compilation of the process
+    (eager operations under their own names) — a persistent-cache load
+    is counted under ``cache_loads``, its time under ``compile_ms``."""
+    with _counts_lock:
+        launches = {k: tuple(v) for k, v in _launches.items()}
+        compiles = {k: tuple(v) for k, v in _compiles.items()}
+    out = {}
+    total = dict.fromkeys(PROGRAM_FIELDS, 0)
+    for key in sorted(set(launches) | set(compiles)):
+        n, call_s, off_s = launches.get(key, (0, 0.0, 0.0))
+        nc, comp_s, loads = compiles.get(key, (0, 0.0, 0))
+        row = {"launches": n, "call_ms": call_s * 1e3,
+               "offcpu_ms": off_s * 1e3, "compiles": nc,
+               "compile_ms": comp_s * 1e3, "cache_loads": loads}
+        if any(row.values()):
+            out[key] = row
+            for f in PROGRAM_FIELDS:
+                total[f] += row[f]
+    out["total"] = total
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -196,10 +355,8 @@ COMMIT_PHASES = ("certify", "wal_append", "scatter", "fsync_wait",
 #: reported beside them: ``freeze`` is the freeze_serving dispatch inside
 #: ``publish``; ``mirror_invalidate`` the invalidation of the group's keys
 #: in the native front end's mirror, inside ``certify`` (counted only on a
-#: node that has the mirror); ``stage`` and ``ack`` are the wire server's
-#: merge point before the lock (a transaction started and its updates
-#: turned into effects, per member) and after it (results fanned back out)
-EXTRA_PHASES = ("freeze", "mirror_invalidate", "stage", "ack")
+#: node that has the mirror)
+EXTRA_PHASES = ("freeze", "mirror_invalidate")
 
 
 class PhaseAccumulator:
@@ -228,13 +385,56 @@ class PhaseAccumulator:
                 self._sums["mirror_invalidate"] += mirror_invalidate_s
                 self._counts["mirror_invalidate"] += 1
 
-    def add(self, phase: str, seconds: float) -> None:
-        with self._lock:
-            self._sums[phase] += seconds
-            self._counts[phase] += 1
-
     def status(self) -> dict:
         with self._lock:
             return {p: {"sum_ms": self._sums[p] * 1e3,
                         "count": self._counts[p]}
                     for p in self._sums}
+
+
+# ---------------------------------------------------------------------------
+# rounds of the wire server's locked worker
+# ---------------------------------------------------------------------------
+#: phases of one round, in order, summing to its busy part: the wait for
+#: the dispatch lock, the merged transaction read, the commit merge's
+#: staging passes, its ``commit_transactions_group`` calls, their results
+#: fanned out, the static reads handed back by the epoch plane
+ROUND_PHASES = ("lock", "txn_read", "stage", "group", "ack", "read")
+
+
+class RoundAccumulator:
+    """Sums of the locked worker's rounds; one ``add_round`` a round."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        #: rounds, idle s, busy s, off-CPU s, programs launched
+        self._totals = [0, 0.0, 0.0, 0.0, 0]
+        self._sums = [0.0] * len(ROUND_PHASES)
+        self._counts = [0] * len(ROUND_PHASES)
+
+    def add_round(self, idle_s: float, busy_s: float, offcpu_s: float,
+                  programs: int, phases: Sequence[Optional[float]]) -> None:
+        """``idle_s``: from the end of the previous round to this one's
+        dequeue; ``busy_s``: dequeue to the round's last answer;
+        ``phases``: seconds per :data:`ROUND_PHASES`, None where the round
+        did not meet the phase."""
+        with self._lock:
+            t = self._totals
+            t[0] += 1
+            t[1] += idle_s
+            t[2] += busy_s
+            t[3] += offcpu_s
+            t[4] += programs
+            for i, v in enumerate(phases):
+                if v is not None:
+                    self._sums[i] += v
+                    self._counts[i] += 1
+
+    def status(self) -> dict:
+        with self._lock:
+            n, idle, busy, off, programs = self._totals
+            sums, counts = [*self._sums], [*self._counts]
+        return {"rounds": n, "idle_ms": idle * 1e3, "busy_ms": busy * 1e3,
+                "offcpu_ms": off * 1e3, "programs": programs,
+                "phases": {p: {"sum_ms": sums[i] * 1e3, "count": counts[i]}
+                           for i, p in enumerate(ROUND_PHASES)}}

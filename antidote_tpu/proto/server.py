@@ -42,7 +42,14 @@ from antidote_tpu.overload import (
     check_deadline,
     deadline_from_ms,
 )
-from antidote_tpu.obs.trace import StageAccumulator, span
+from antidote_tpu.obs.trace import (
+    ROUND_PHASES,
+    RoundAccumulator,
+    StageAccumulator,
+    program_status,
+    span,
+    thread_launches,
+)
 from antidote_tpu.tenancy import TenantLanes, TenantRegistry
 from antidote_tpu.proto import apb
 from antidote_tpu.proto.proxy import ProxyExhausted, ProxyPlane
@@ -62,6 +69,13 @@ DEFAULT_PORT = 8087
 log = logging.getLogger(__name__)
 
 _STOP = object()
+
+#: indices of :data:`ROUND_PHASES` in a round's record
+_LOCK, _TXN_READ, _STAGE, _GROUP, _ACK, _READ = range(len(ROUND_PHASES))
+
+
+def _add_phase(ph: list, i: int, seconds: float) -> None:
+    ph[i] = seconds if ph[i] is None else ph[i] + seconds
 
 
 class _StaticWork:
@@ -332,9 +346,9 @@ class ProtocolServer:
         #: composite type, no local txm, the fallback of a merged read
         #: that failed) — written under the dispatch lock
         self._txn_reads = {"groups": 0, "reads": 0, "rows": 0, "inline": 0}
-        #: (seconds, waits) the locked worker spent blocked on an empty
-        #: merge-point queue — replaced whole by that worker only
-        self._locked_idle = (0.0, 0)
+        #: node status ``write_plane.locked``: the locked worker's rounds
+        #: (idle, busy, off-CPU, programs launched, phases)
+        self._rounds = RoundAccumulator()
         # --- staged serving pipeline (ISSUE 5) -------------------------
         #: serving-epoch publication cadence for the dedicated ticker
         self.epoch_tick_ms = epoch_tick_ms
@@ -1195,13 +1209,10 @@ class ProtocolServer:
         whatever else queued (up to ``_batch_max``); with ``window_s``
         keep gathering late arrivals up to that long (the
         --group-commit-window-us merge window).
-        Returns (works, stop_seen, seconds blocked for the first)."""
-        t0 = time.monotonic()
+        Returns (works, stop_seen)."""
         with span(wait_span):
             batch = [q.get()]
-        now = time.monotonic()
-        waited = now - t0
-        deadline = (now + window_s) if window_s > 0 else None
+        deadline = (time.monotonic() + window_s) if window_s > 0 else None
         while len(batch) < self._batch_max:
             try:
                 batch.append(q.get_nowait())
@@ -1216,7 +1227,7 @@ class ProtocolServer:
                 except queue.Empty:
                     break
         stop = any(w is _STOP for w in batch)
-        return [w for w in batch if w is not _STOP], stop, waited
+        return [w for w in batch if w is not _STOP], stop
 
     def _shed_expired(self, works, where: str, observe_parked=False):
         """Deadline discipline shared by both planes: work that outlived
@@ -1276,7 +1287,7 @@ class ProtocolServer:
         q = self._static_q
         m = self.metrics
         while True:
-            works, stop, _ = self._drain_batch(q, "serve.gate_wait")
+            works, stop = self._drain_batch(q, "serve.gate_wait")
             slot = False
             self._round_held_s = 0.0
             if (self._epoch_reads and not stop
@@ -1364,13 +1375,21 @@ class ProtocolServer:
         read group.  A transaction's snapshot is fixed, so its read is
         right wherever it runs; first, it waits for no commit group (the
         longest hold of a round) and finds the heads not yet moved on by
-        the round's own commits, so fewer of its rows need a fold."""
+        the round's own commits, so fewer of its rows need a fold.
+
+        Each round is one record (``write_plane.locked``, one accumulator
+        call): from dequeue to its last answer, the time its thread spent
+        off the CPU, the device programs it launched, and its phases
+        (:data:`~antidote_tpu.obs.trace.ROUND_PHASES`); host span
+        ``serve.round``."""
         q = self._locked_q
+        t_end = time.monotonic()
         while True:
-            works, stop, waited = self._drain_batch(
+            works, stop = self._drain_batch(
                 q, "serve.locked_wait", self._group_window_s)
-            idle_s, idle_n = self._locked_idle
-            self._locked_idle = (idle_s + waited, idle_n + 1)
+            t0 = time.monotonic()
+            c0, n0 = time.thread_time(), thread_launches()
+            ph: list = [None] * len(ROUND_PHASES)
             # re-checked at THIS dequeue too (the overload contract at
             # the merge point): a work can expire while parked behind a
             # slow commit group — this plane's whole job is absorbing
@@ -1387,21 +1406,38 @@ class ProtocolServer:
                 ups = [w for w in direct if w.kind == "update"]
                 commits = [w for w in direct if w.kind == "commit"]
                 txn_reads = [w for w in direct if w.kind == "txn_read"]
-                with self._lock:
-                    if txn_reads:
-                        self._run_txn_reads(txn_reads)
-                    # writes before the static reads: the merged read
-                    # then serves at a snapshot covering them (fresh +
-                    # cache friendly)
-                    if ups or commits:
-                        self._run_commit_merge(ups, commits)
-                    if reads:
-                        self._run_read_group(reads)
+                with span("serve.round", txn_reads=len(txn_reads),
+                          updates=len(ups), commits=len(commits),
+                          reads=len(reads)):
+                    t = time.monotonic()
+                    with self._lock:
+                        t1 = time.monotonic()
+                        ph[_LOCK] = t1 - t
+                        if txn_reads:
+                            self._run_txn_reads(txn_reads)
+                            t = time.monotonic()
+                            ph[_TXN_READ] = t - t1
+                            t1 = t
+                        # writes before the static reads: the merged read
+                        # then serves at a snapshot covering them (fresh +
+                        # cache friendly)
+                        if ups or commits:
+                            self._run_commit_merge(ups, commits, ph)
+                            t1 = time.monotonic()
+                        if reads:
+                            self._run_read_group(reads)
+                            ph[_READ] = time.monotonic() - t1
             except BaseException as e:  # never strand a parked connection
                 for w in works:
                     if not w.event.is_set():
                         w.error = e
                         self._complete(w)
+            now = time.monotonic()
+            busy = now - t0
+            self._rounds.add_round(
+                t0 - t_end, busy, busy - (time.thread_time() - c0),
+                thread_launches() - n0, ph)
+            t_end = now
             if stop:
                 self._fail_queue_remainder(q)
                 return
@@ -1839,13 +1875,15 @@ class ProtocolServer:
         return None
 
     def _run_commit_merge(self, ups: List[_StaticWork],
-                          commits: List[_StaticWork]) -> None:
+                          commits: List[_StaticWork], ph: list) -> None:
         """The write plane's merge point (ISSUE 6): static update groups
         AND interactive COMMITs from different connections fuse into ONE
         ``commit_transactions_group`` call — one commit-lock take, one
         certification pass, one WAL append, one device scatter — with
         per-source results fanned back out (a member's failure-atomic
-        rollback rolls back only its own sub-group)."""
+        rollback rolls back only its own sub-group).  Adds its ``stage``,
+        ``group`` and ``ack`` time to the round's phases ``ph``."""
+        t_stage = time.monotonic()
         txm = getattr(self.node, "txm", None)
         if txm is None:
             # cluster coordinator (2PC): sequential legacy path (commit
@@ -1860,6 +1898,7 @@ class ProtocolServer:
             for w in commits:
                 w.error = RuntimeError("commit merge requires a local txm")
                 w.event.set()
+            _add_phase(ph, _GROUP, time.monotonic() - t_stage)
             return
         # resolve interactive commit works to their registered txns
         # (self._lock is held by the locked worker)
@@ -1886,41 +1925,43 @@ class ProtocolServer:
         # Interactive commits ride the FIRST round only: their abort is
         # the client's to observe, never auto-retried.
         while pending or (first and inter):
-            t_stage = time.monotonic()
             staged = []
-            for w in pending:
-                # re-check per-work deadlines at every retry round: a
-                # conflict-retry loop under load must not keep executing
-                # work whose caller has already timed out
-                if (w.deadline is not None
-                        and time.monotonic() > w.deadline):
-                    self.metrics.shed.inc(plane="deadline")
-                    w.error = DeadlineExceeded(
-                        "request deadline passed before commit; "
-                        "not executed")
-                    w.event.set()
-                    continue
-                try:
-                    txn = txm.start_transaction(w.clock)
+            with span("serve.stage", updates=len(pending)):
+                for w in pending:
+                    # re-check per-work deadlines at every retry round: a
+                    # conflict-retry loop under load must not keep
+                    # executing work whose caller has already timed out
+                    if (w.deadline is not None
+                            and time.monotonic() > w.deadline):
+                        self.metrics.shed.inc(plane="deadline")
+                        w.error = DeadlineExceeded(
+                            "request deadline passed before commit; "
+                            "not executed")
+                        w.event.set()
+                        continue
                     try:
-                        txm.update_objects(w.updates, txn)
-                    except Exception:
-                        txm.abort_transaction(txn)
-                        raise
-                    staged.append((w, txn))
-                except Exception as e:
-                    w.error = e
-                    w.event.set()
+                        txn = txm.start_transaction(w.clock)
+                        try:
+                            txm.update_objects(w.updates, txn)
+                        except Exception:
+                            txm.abort_transaction(txn)
+                            raise
+                        staged.append((w, txn))
+                    except Exception as e:
+                        w.error = e
+                        w.event.set()
             batch = staged + (inter if first else [])
             first = False
+            t_staged = time.monotonic()
+            _add_phase(ph, _STAGE, t_staged - t_stage)
             if not batch:
                 return
-            seq0 = txm.group_seq
-            t_staged = time.monotonic()
             try:
                 outs = txm.commit_transactions_group(
                     [t for _, t in batch])
             except Exception as e:
+                t_ack = time.monotonic()
+                _add_phase(ph, _GROUP, t_ack - t_staged)
                 for w, txn in batch:
                     # a backlog-shed group comes back with its txns
                     # still OPEN — server-created static txns must be
@@ -1933,10 +1974,12 @@ class ProtocolServer:
                         txm.abort_transaction(txn)
                     w.error = e
                     w.event.set()
+                _add_phase(ph, _ACK, time.monotonic() - t_ack)
                 return
             # the `ack` phase: per-source results fanned back out, after
             # the commit lock was released
             t_ack = time.monotonic()
+            _add_phase(ph, _GROUP, t_ack - t_staged)
             group_id = txm.group_seq
             retry = []
             for (w, txn), r in zip(batch, outs):
@@ -1950,9 +1993,8 @@ class ProtocolServer:
                     w.result = r
                 w.t_ready = time.monotonic()
                 w.event.set()
-            if txm.group_seq != seq0:
-                txm.phases.add("stage", t_staged - t_stage)
-                txm.phases.add("ack", time.monotonic() - t_ack)
+            t_stage = time.monotonic()
+            _add_phase(ph, _ACK, t_stage - t_ack)
             pending = retry
 
     # ------------------------------------------------------------------
@@ -2282,11 +2324,9 @@ class ProtocolServer:
                 "batch_gate_max": self._static_q.maxsize,
             })
             status["pipeline"] = self._pipeline_status()
-            idle_s, idle_n = self._locked_idle
-            # time the locked worker sat blocked on an empty merge-point
-            # queue: a commit round is `group` + this
-            status.setdefault("write_plane", {})["locked_idle"] = {
-                "sum_ms": idle_s * 1e3, "count": idle_n}
+            status["programs"] = program_status()
+            status.setdefault("write_plane", {})["locked"] = (
+                self._rounds.status())
             status["tenants"] = self._tenant_status()
             if self.interdc is not None and hasattr(self.interdc,
                                                     "replica_status"):

@@ -1473,7 +1473,8 @@ class KVStore:
             del fresh  # provably all-fresh: frozen head_vc ≤ cap ≤ E
             has_resolve = ty.resolve_spec(t.cfg) is not None
             slot = ep.tables[tname_t]
-            with span("serve.wb_host", table=tname_t, rows=len(items)):
+            with span("serve.wb_host.decode", table=tname_t,
+                      rows=len(items)):
                 filled = []
                 over = []
                 for j, (i, shard, row) in enumerate(items):

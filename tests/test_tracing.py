@@ -1,6 +1,7 @@
 """ISSUE 24 tracing tests: per-request stage records on both front-end
 planes, commit-group phases, the native plane's crossing counters, and the
-stable names of the device programs.
+stable names of the device programs; ISSUE 35's: the locked worker's round
+record and the per-program launch and compile counters.
 
 CPU, counts and structure only: no time measured here is a device metric.
 """
@@ -410,21 +411,170 @@ def test_native_hits_keep_the_epoch_plane_from_looking_idle(hits_flow):
         assert node.txm.epoch_lag_counter == node.txm.commit_counter
 
 
+def _rounds_reach(srv, n, timeout=5.0):
+    """The locked worker records a round after its last answer: wait
+    until it has recorded ``n``."""
+    deadline = time.monotonic() + timeout
+    while srv._rounds.status()["rounds"] < n:
+        assert time.monotonic() < deadline, "the round was never recorded"
+        time.sleep(0.002)
+
+
 def test_server_reports_ack_phase_and_locked_idle():
     node, srv = _boot(False)
     c = AntidoteClient(srv.host, srv.port)
     try:
         for i in range(4):
             c.update_objects([(f"k{i}", "counter_pn", "b", ("increment", 1))])
+        _rounds_reach(srv, 4)
         st = c.node_status()
     finally:
         c.close()
         srv.close()
     wp = st["write_plane"]
-    assert wp["phases"]["ack"]["count"] == wp["phases"]["certify"]["count"]
-    assert wp["phases"]["ack"]["count"] == node.txm.group_seq >= 1
-    assert wp["locked_idle"]["count"] >= wp["group"]["count"]
+    lk = wp["locked"]
+    assert "locked_idle" not in wp and "ack" not in wp["phases"]
+    assert lk["phases"]["ack"]["count"] == wp["phases"]["certify"]["count"]
+    assert lk["phases"]["ack"]["count"] == node.txm.group_seq >= 1
+    assert lk["rounds"] >= wp["group"]["count"]
+    assert lk["idle_ms"] > 0 and lk["busy_ms"] > 0
     assert st["pipeline"]["paths"]["update"]["parked"]["count"] == 4
+
+
+# ---------------------------------------------------------------------------
+# (b') a round of the locked worker, and the metrics that read it
+# ---------------------------------------------------------------------------
+#: per-layer metrics of this PR, read from node_status() deltas
+ROUND_METRICS = ["txn.round_ms", "txn.round_programs",
+                 "txn.round_offcpu_share", "txn.round_stage_ms",
+                 "kernels.call_ms", "kernels.call_offcpu_ms",
+                 "kernels.compile_ms_per_s"]
+
+
+@pytest.fixture(scope="module")
+def round_traffic():
+    """node_status() before and after: two static updates, a read inside
+    an interactive transaction and its commit, and a static read the
+    epoch could not serve (no epoch to pin) — one round each."""
+    node, srv = _boot(False)
+    c = AntidoteClient(srv.host, srv.port)
+    try:
+        c.update_objects([("r0", "counter_pn", "b", ("increment", 1))])
+        _rounds_reach(srv, 1)
+        st0 = c.node_status()
+        groups0 = node.txm.group_seq
+        for i in range(2):
+            c.update_objects([(f"r{i}", "counter_pn", "b",
+                               ("increment", 1))])
+        t = c.start_transaction()
+        assert t.read_objects([("r1", "counter_pn", "b")]) == [1]
+        t.commit()
+        node.txm.store.pin_serving_epoch = lambda: None
+        vals, _ = c.read_objects([("r0", "counter_pn", "b")])
+        assert vals == [2]
+        _rounds_reach(srv, 6)
+        st1 = c.node_status()
+        groups = node.txm.group_seq - groups0
+    finally:
+        c.close()
+        srv.close()
+    return st0, st1, groups
+
+
+def test_a_round_record_counts_each_phase_it_met(round_traffic):
+    st0, st1, groups = round_traffic
+    lk0, lk1 = (s["write_plane"]["locked"] for s in (st0, st1))
+    d = {p: lk1["phases"][p]["count"] - lk0["phases"][p]["count"]
+         for p in trace.ROUND_PHASES}
+    rounds = lk1["rounds"] - lk0["rounds"]
+    # updates x2 and the commit: three rounds that met the merge; the
+    # transaction read and the static read one round each
+    assert d == {"lock": rounds, "txn_read": 1, "stage": 3, "group": 3,
+                 "ack": 3, "read": 1}
+    assert rounds == 5 and groups >= 2
+    assert lk1["programs"] > lk0["programs"]
+    busy = lk1["busy_ms"] - lk0["busy_ms"]
+    phases = sum(lk1["phases"][p]["sum_ms"] - lk0["phases"][p]["sum_ms"]
+                 for p in trace.ROUND_PHASES)
+    assert phases <= busy and phases == pytest.approx(busy, rel=0.05)
+    assert 0 <= lk1["offcpu_ms"] - lk0["offcpu_ms"] <= busy
+    assert lk1["idle_ms"] > lk0["idle_ms"]
+    # the round's launches are the process's too
+    tot0, tot1 = st0["programs"]["total"], st1["programs"]["total"]
+    assert (tot1["launches"] - tot0["launches"]
+            >= lk1["programs"] - lk0["programs"])
+
+
+@pytest.mark.parametrize("metric", ROUND_METRICS)
+def test_round_and_launch_metrics_read_a_number(round_traffic, metric):
+    """Each metric file's reader finds its counters in a live status (a
+    renamed counter would read nothing)."""
+    import json
+    import os
+    from types import SimpleNamespace
+
+    from benchmarks.readers import status_delta
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "layer_metrics",
+                           metric + ".json")) as f:
+        spec = json.load(f)
+    st0, st1, _ = round_traffic
+    v = status_delta.read(spec, SimpleNamespace(status={"window": [st0,
+                                                                    st1]}))
+    assert isinstance(v, float) and v >= 0, (metric, v)
+    # nothing on a program without the counters
+    bare = [{"pipeline": s["pipeline"]} for s in (st0, st1)]
+    if metric != "kernels.compile_ms_per_s":
+        assert status_delta.read(spec, SimpleNamespace(
+            status={"window": bare})) is None
+
+
+def test_device_program_counts_launches_and_compiles_by_name():
+    import jax.numpy as jnp
+    import numpy as np
+
+    @trace.device_program("unit_counted")
+    def fn(x):
+        return x + 1
+
+    zero = dict.fromkeys(trace.PROGRAM_FIELDS, 0)
+
+    def row(name="unit_counted"):
+        return trace.program_status().get(name, zero)
+
+    def built(r):
+        return r["compiles"] + r["cache_loads"]
+
+    r0 = row()
+    fn(jnp.ones((3,), jnp.float32))
+    r1 = row()
+    assert r1["launches"] == r0["launches"] + 1
+    assert built(r1) == built(r0) + 1 and r1["compile_ms"] > r0["compile_ms"]
+    fn(jnp.ones((3,), jnp.float32))                 # the same shape
+    r2 = row()
+    assert r2["launches"] == r1["launches"] + 1 and built(r2) == built(r1)
+    fn(jnp.ones((5,), jnp.float32))                 # a new shape
+    r3 = row()
+    assert r3["launches"] == r2["launches"] + 1
+    assert built(r3) == built(r2) + 1
+    # off the CPU: part of the call (up to the CPU clock's resolution)
+    assert -0.01 <= r3["offcpu_ms"] - r0["offcpu_ms"] <= (
+        r3["call_ms"] - r0["call_ms"])
+    # .lower is no launch, and the module keeps its name
+    text = fn.lower(jnp.ones((4,), jnp.float32)).as_text()
+    assert "module @jit_antidote_unit_counted " in text
+    # traced inside another program: part of its launch, not one of its own
+    jax.jit(lambda x: fn(x) * 2)(jnp.ones((6,), jnp.float32))
+    assert row()["launches"] == r3["launches"]
+    # an eager operation is counted under its own name
+    x = jnp.arange(7919, dtype=jnp.int16)
+    s0 = row("dynamic_slice")
+    assert np.asarray(x[:3]).tolist() == [0, 1, 2]
+    assert built(row("dynamic_slice")) == built(s0) + 1
+    total = trace.program_status()["total"]
+    assert total["launches"] >= r3["launches"]
+    assert total["compiles"] + total["cache_loads"] >= built(r3) + 1
 
 
 # ---------------------------------------------------------------------------
@@ -493,11 +643,25 @@ def test_stage_accumulator_keeps_only_the_slowest():
 def test_phase_accumulator_sums_to_last_minus_first():
     acc = trace.PhaseAccumulator()
     acc.add_group((10.0, 10.5, 10.5, 11.0, 11.25, 11.25, 12.0), 0.125)
-    acc.add("ack", 0.5)
     st = acc.status()
     assert sum(st[p]["sum_ms"] for p in trace.COMMIT_PHASES) == 2000.0
     assert st["wal_append"] == {"sum_ms": 0.0, "count": 1}
-    assert st["freeze"]["sum_ms"] == 125.0 and st["ack"]["count"] == 1
+    assert st["freeze"]["sum_ms"] == 125.0
+    assert set(st) == set(trace.COMMIT_PHASES + trace.EXTRA_PHASES)
+
+
+def test_round_accumulator_counts_only_the_phases_a_round_met():
+    acc = trace.RoundAccumulator()
+    acc.add_round(0.5, 0.25, 0.125, 3, (0.0, None, 0.05, 0.15, 0.05, None))
+    acc.add_round(1.0, 0.5, 0.25, 5, (0.0, 0.4, None, None, None, 0.1))
+    st = acc.status()
+    assert (st["rounds"], st["idle_ms"], st["busy_ms"], st["offcpu_ms"],
+            st["programs"]) == (2, 1500.0, 750.0, 375.0, 8)
+    assert {p: v["count"] for p, v in st["phases"].items()} == {
+        "lock": 2, "txn_read": 1, "stage": 1, "group": 1, "ack": 1,
+        "read": 1}
+    assert sum(v["sum_ms"] for v in st["phases"].values()) == \
+        pytest.approx(st["busy_ms"])
 
 
 def test_span_is_a_trace_annotation_and_inert_without_a_session():
