@@ -17,7 +17,8 @@ Three pieces, all always on (no switch, flag or environment variable):
 * :func:`device_program` — ``jax.jit`` under a stable name, so the
   trace's "XLA Modules" line reads ``jit_antidote_<what>`` and the ops
   inside carry a ``jax.named_scope`` of the same name.  Each program
-  counts its own launches and their host time; one ``jax.monitoring``
+  counts its own launches, their host time and their host operands; one
+  ``jax.monitoring``
   listener counts every compilation of the process by program name
   (:func:`program_status`, ``node_status()["programs"]``).
 * :class:`RoundAccumulator` — the wire server's locked worker: one call
@@ -29,6 +30,7 @@ from __future__ import annotations
 
 import functools
 import heapq
+import inspect
 import threading
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -66,7 +68,11 @@ def device_program(what: str, fn=None, **jit_kw):
                 return f(*args, **kwargs)
 
         program.__name__ = program.__qualname__ = name
-        return _Program(what, jax.jit(program, **jit_kw))
+        # static arguments are no operands, by name or by position
+        static = frozenset(jit_kw.get("static_argnames", ()))
+        at = frozenset(i for i, p in enumerate(
+            inspect.signature(f).parameters) if p in static)
+        return _Program(what, jax.jit(program, **jit_kw), static, at)
 
     return wrap if fn is None else wrap(fn)
 
@@ -75,8 +81,8 @@ def device_program(what: str, fn=None, **jit_kw):
 # per-program launch and compile counters
 # ---------------------------------------------------------------------------
 #: fields of one program's row in ``node_status()["programs"]``
-PROGRAM_FIELDS = ("launches", "call_ms", "offcpu_ms", "compiles",
-                  "compile_ms", "cache_loads")
+PROGRAM_FIELDS = ("launches", "call_ms", "offcpu_ms", "host_operands",
+                  "compiles", "compile_ms", "cache_loads")
 _COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
                    "/jax/core/compile/jaxpr_to_mlir_module_duration",
                    "/jax/core/compile/backend_compile_duration")
@@ -84,7 +90,7 @@ _TRACE_EVENT, _BACKEND_EVENT = _COMPILE_EVENTS[0], _COMPILE_EVENTS[2]
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
 _counts_lock = threading.Lock()
-#: what -> [launches, call seconds, off-CPU seconds]
+#: what -> [launches, call seconds, off-CPU seconds, host operands]
 _launches: Dict[str, list] = {}
 #: what (or an eager op's name) -> [compiles, seconds, cache loads]
 _compiles: Dict[str, list] = {}
@@ -115,19 +121,35 @@ class _Program:
     dispatched; an asynchronous dispatch does not wait for the device)
     and that time less the calling thread's CPU time over the same call
     — the time the thread waited, for the interpreter lock or a
-    transfer (an estimate a call, right in the sum).  One lock take a
-    launch.  A call made while an outer program is being traced is no
-    launch and is not counted."""
+    transfer (an estimate a call, right in the sum) — and its host
+    operands: the leaves of its arguments, static ones aside, that are
+    not device arrays, each a transfer of its own (one walk of the
+    arguments, before the call's time starts).  One lock take a launch.
+    A call made while an outer program is being traced is no launch and
+    is not counted."""
 
-    def __init__(self, what: str, jitted):
+    def __init__(self, what: str, jitted, static=frozenset(),
+                 static_at=frozenset()):
         self._jit = jitted
+        #: names of the static arguments, and their positions
+        self._static, self._static_at = static, static_at
         with _counts_lock:
-            self._row = _launches.setdefault(what, [0, 0.0, 0.0])
+            self._row = _launches.setdefault(what, [0, 0.0, 0.0, 0])
         functools.update_wrapper(self, jitted)
+
+    def _host_operands(self, args, kwargs) -> int:
+        if self._static:
+            args = [a for i, a in enumerate(args)
+                    if i not in self._static_at]
+            kwargs = {k: v for k, v in kwargs.items()
+                      if k not in self._static}
+        return sum(not isinstance(x, jax.Array)
+                   for x in jax.tree.leaves((args, kwargs)))
 
     def __call__(self, *args, **kwargs):
         if not _jax_core.trace_state_clean():
             return self._jit(*args, **kwargs)
+        host = self._host_operands(args, kwargs)
         t0, c0 = time.perf_counter(), time.thread_time()
         out = self._jit(*args, **kwargs)
         c1, t1 = time.thread_time(), time.perf_counter()
@@ -141,6 +163,7 @@ class _Program:
             # coarsely (some hosts: 10 ms) one call reads a whole tick or
             # none, and only the sum over many calls is right
             row[2] += wall - (c1 - c0)
+            row[3] += host
         return out
 
     def __getattr__(self, attr):
@@ -194,22 +217,25 @@ monitoring.register_event_duration_secs_listener(_on_duration)
 
 
 def program_status() -> dict:
-    """``{<what>: {launches, call_ms, offcpu_ms, compiles, compile_ms,
-    cache_loads}, "total": {...}}`` since the process started: every
-    device program's launches, and every compilation of the process
-    (eager operations under their own names) — a persistent-cache load
-    is counted under ``cache_loads``, its time under ``compile_ms``."""
+    """``{<what>: {launches, call_ms, offcpu_ms, host_operands, compiles,
+    compile_ms, cache_loads}, "total": {...}}`` since the process
+    started: every device program's launches (``host_operands``: the
+    host arrays and scalars they were handed, summed), and every
+    compilation of the process (eager operations under their own names)
+    — a persistent-cache load is counted under ``cache_loads``, its time
+    under ``compile_ms``."""
     with _counts_lock:
         launches = {k: tuple(v) for k, v in _launches.items()}
         compiles = {k: tuple(v) for k, v in _compiles.items()}
     out = {}
     total = dict.fromkeys(PROGRAM_FIELDS, 0)
     for key in sorted(set(launches) | set(compiles)):
-        n, call_s, off_s = launches.get(key, (0, 0.0, 0.0))
+        n, call_s, off_s, host = launches.get(key, (0, 0.0, 0.0, 0))
         nc, comp_s, loads = compiles.get(key, (0, 0.0, 0))
         row = {"launches": n, "call_ms": call_s * 1e3,
-               "offcpu_ms": off_s * 1e3, "compiles": nc,
-               "compile_ms": comp_s * 1e3, "cache_loads": loads}
+               "offcpu_ms": off_s * 1e3, "host_operands": host,
+               "compiles": nc, "compile_ms": comp_s * 1e3,
+               "cache_loads": loads}
         if any(row.values()):
             out[key] = row
             for f in PROGRAM_FIELDS:

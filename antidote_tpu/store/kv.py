@@ -25,9 +25,10 @@ import numpy as np
 from antidote_tpu.config import AntidoteConfig
 from antidote_tpu.crdt import get_type, is_type
 from antidote_tpu.crdt.blob import BlobStore
-from antidote_tpu.obs.trace import span
+from antidote_tpu.obs.trace import device_program, span
 from antidote_tpu.store.router import shard_batch, shard_of
-from antidote_tpu.store.typed_table import TypedTable, _bucket, _cut
+from antidote_tpu.store.typed_table import (TypedTable, _bucket, _cut,
+                                            _join_i64)
 
 BoundObject = Tuple[Any, str, str]  # (key, type_name, bucket)
 
@@ -318,6 +319,90 @@ def _pad_lane(x, width: int, dtype) -> np.ndarray:
     return out
 
 
+class _ReplayFold:
+    """The log replay's serial fold for one (type, widths): one device
+    program, ``replay_fold_serial``, launched once a piece of a log (its
+    ops padded to 64 or 256), whose one host operand is a staged int32
+    array [to + 1 + ``state_rows``, ``cols``] (:meth:`stage`):
+
+    * rows [0, to): the piece's ops, zeros past its ``n`` — each int64
+      lane of ``eff_a`` as its (lo, hi) halves, then ``eff_b``, the
+      commit VC, the origin;
+    * row ``to``: ``n``, whether the rows below hold the state to fold
+      onto, the base VC, the read VC;
+    * the rows after: a host state's fields as int32 words, in
+      ``state_spec`` order.
+
+    The state a previous piece left on the device is an operand of its
+    own; a device zero state takes its place beside a host one, so one
+    signature a padded length serves every piece."""
+
+    def __init__(self, ty, cfg_t, d: int):
+        import jax.numpy as jnp
+
+        from antidote_tpu.materializer import fold as fold_mod
+
+        wa, wb = ty.eff_a_width(cfg_t), ty.eff_b_width(cfg_t)
+        self.spec = {f: (tuple(s), np.dtype(dt))
+                     for f, (s, dt) in ty.state_spec(cfg_t).items()}
+        assert all(dt in (np.int32, np.int64)
+                   for _, dt in self.spec.values()), self.spec
+        self.d = d
+        self.b0, self.v0, self.o0 = 2 * wa, 2 * wa + wb, 2 * wa + wb + d
+        self.cols = max(self.o0 + 1, 2 + 2 * d)
+        words = sum(int(np.prod(s)) * dt.itemsize // 4
+                    for s, dt in self.spec.values())
+        self.state_rows = -(-words // self.cols)
+        self.zero = jax.device_put(
+            {f: np.zeros(s, dt) for f, (s, dt) in self.spec.items()})
+        b0, v0, o0 = self.b0, self.v0, self.o0
+
+        def fold(staged, carried):
+            to = staged.shape[0] - 1 - self.state_rows
+            ops, hdr = staged[:to], staged[to]
+            words = staged[to + 1:].reshape(-1)
+            state, off = {}, 0
+            for f, (s, dt) in self.spec.items():
+                k = int(np.prod(s)) * dt.itemsize // 4
+                w = words[off:off + k]
+                off += k
+                x = (_join_i64(w.reshape(s + (2,))) if dt == np.int64
+                     else w.reshape(s))
+                state[f] = jnp.where(hdr[1] != 0, x, carried[f])
+            return fold_mod.fold_key(
+                ty, cfg_t, state, _join_i64(ops[:, :b0].reshape(to, wa, 2)),
+                ops[:, b0:v0], ops[:, v0:o0], ops[:, o0], hdr[0],
+                hdr[2:2 + d], hdr[2 + d:2 + 2 * d])
+
+        self.fn = device_program("replay_fold_serial", fold)
+
+    def stage(self, ops_a, ops_b, ops_vc, ops_origin, to: int, base_vc,
+              read_vc, state=None) -> np.ndarray:
+        """The operand of one piece: its ``n`` ops (host arrays, leading
+        axis ``n`` <= ``to``) and ``state``, the host state to fold onto
+        (None: the device state handed in beside it).  A buffer of its
+        own for every launch (see TypedTable.append)."""
+        n, d = len(ops_origin), self.d
+        staged = np.zeros((to + 1 + self.state_rows, self.cols), np.int32)
+        staged[:n, :self.b0] = np.ascontiguousarray(
+            ops_a, np.int64).view(np.int32)
+        staged[:n, self.b0:self.v0] = ops_b
+        staged[:n, self.v0:self.o0] = ops_vc
+        staged[:n, self.o0] = ops_origin
+        hdr = staged[to]
+        hdr[0] = n
+        hdr[2:2 + d] = base_vc
+        hdr[2 + d:2 + 2 * d] = read_vc
+        if state is not None:
+            hdr[1] = 1
+            words, off = staged[to + 1:].reshape(-1), 0
+            for f, (_s, dt) in self.spec.items():
+                w = np.ascontiguousarray(state[f], dt).view(np.int32).ravel()
+                words[off:off + w.size] = w
+                off += w.size
+        return staged
+
+
 def effect_from_rec(rec: dict) -> "Effect":
     """Decode one WAL record (LogManager.log_effect's wire dict) back into
     an Effect — the single place that knows the record's lane encoding."""
@@ -466,8 +551,8 @@ class KVStore:
         #: per-strategy replay-path fold dispatch counts (the
         #: materializer status block; see _fold_over_ring)
         self.replay_fold_dispatches: Dict[str, int] = {}
-        #: (type, tier config) -> jitted serial fold of a replayed log
-        #: (compiled once a padded log length)
+        #: (type, tier config) -> serial fold of a replayed log
+        #: (:class:`_ReplayFold`, compiled once a padded log length)
         self._replay_fold_fns: Dict[tuple, Any] = {}
         #: (key, bucket) -> [tiered name, state, vc, tail, pos]: a
         #: below-coverage read's answer kept as the next one's base —
@@ -1435,15 +1520,10 @@ class KVStore:
                 )
                 launches.append((tname_t, items, resolved, fresh, pos))
             else:
-                mb = _bucket(mcount, t.cfg.batch_buckets)
-                ss = np.zeros(mb, np.int64)
-                rr = np.zeros(mb, np.int64)
-                ss[:mcount] = [x[1] for x in items]
-                rr[:mcount] = [x[2] for x in items]
-                vcs = np.zeros((mb, ep.vc.shape[-1]), np.int32)
-                vcs[:mcount] = ep.vc
                 resolved, fresh = t._latest_resolved_flat_fn(
-                    slot["head"], slot["head_vc"], ss, rr, vcs
+                    slot["head"], slot["head_vc"], t._stage_reads(
+                        [x[1] for x in items], [x[2] for x in items], ep.vc,
+                        mb=_bucket(mcount, t.cfg.batch_buckets))
                 )
                 launches.append((tname_t, items, resolved, fresh, None))
             if m is not None:
@@ -2024,7 +2104,6 @@ class KVStore:
           ≥ n_ops, so the inclusion mask drops them).
         * ``serial`` — short order-sensitive log: plain masked scan.
         """
-        from antidote_tpu.materializer import fold as fold_mod
         from antidote_tpu.materializer import longlog
 
         import jax.numpy as jnp
@@ -2071,26 +2150,22 @@ class KVStore:
         # anew for every log length, under the commit lock, and these
         # two a served mix has met before its first replay
         # (_warm_replay); a padded slot costs a masked step
-        fn = self._replay_fold_fns.get((ty.name, cfg_t))
-        if fn is None:
-            from antidote_tpu.obs.trace import device_program
-
-            fn = self._replay_fold_fns[(ty.name, cfg_t)] = device_program(
-                "replay_fold_serial", functools.partial(
-                    fold_mod.fold_key, ty, cfg_t))
-        state = state0
+        prog = self._replay_fold_fns.get((ty.name, cfg_t))
+        if prog is None:
+            prog = self._replay_fold_fns[(ty.name, cfg_t)] = _ReplayFold(
+                ty, cfg_t, cfg_t.max_dcs)
+        # a host state crosses inside the first piece's operand; a
+        # device one (and every piece's result) stays where it is
+        host = not any(isinstance(x, jax.Array)
+                       for x in jax.tree.leaves(state0))
+        state = prog.zero if host else state0
         for lo in range(0, l, 256):
             n = min(l - lo, 256)
-            to = 64 if n <= 64 else 256
-
-            def piece(x):  # pad slots sit at index >= n: masked out
-                x = x[lo:lo + n]
-                return np.concatenate(
-                    [x, np.zeros((to - n,) + x.shape[1:], x.dtype)]
-                ) if to > n else x
-
-            state, _ = fn(state, piece(ops_a), piece(ops_b), piece(ops_vc),
-                          piece(ops_origin), np.int32(n), base_vc, read_vc)
+            sl = slice(lo, lo + n)
+            state, _ = prog.fn(prog.stage(
+                ops_a[sl], ops_b[sl], ops_vc[sl], ops_origin[sl],
+                64 if n <= 64 else 256, base_vc, read_vc,
+                state0 if host and lo == 0 else None), state)
         return state, "serial"
 
     def _observe_fold(self, strategy: str, tname: str, seconds: float):

@@ -195,6 +195,22 @@ def _cut(tree, m: int):
     return jax.tree.map(lambda x: np.array(x[:m]), jax.device_get(tree))
 
 
+def _join_i64(halves):
+    """int64 values from the int32 halves NumPy's ``.view(np.int32)``
+    splits them into (lo, hi on the last axis): (hi << 32) | lo,
+    exactly."""
+    return ((halves[..., 1].astype(jnp.int64) << 32)
+            | halves[..., 0].astype(jnp.uint32).astype(jnp.int64))
+
+
+def _unstage_reads(staged, lanes: int):
+    """A staged read operand (:meth:`TypedTable._stage_reads`) taken
+    apart inside its program: its first ``lanes`` columns as vectors
+    (shard, row, and for a fold the used ring prefix), then the read VCs
+    [M, D]."""
+    return (*(staged[:, i] for i in range(lanes)), staged[:, lanes:])
+
+
 def _head_update_body(ty, cfg, window: int = 0):
     """Write-time fold: apply ring slots [start, end) of each touched key
     onto its *head* state (the eagerly-materialized snapshot at the key's
@@ -756,7 +772,7 @@ class TypedTable:
             out = self._read_resolved_flat_fn(strategy, kmax)(
                 self.head, self.head_vc, self.snap, self.snap_vc,
                 self.snap_seq, self.ops_a, self.ops_b, self.ops_vc,
-                self.ops_origin, z, z, np.zeros(mb, np.int32), vcs)[0]
+                self.ops_origin, self._stage_reads(z, z, vcs, z))[0]
             self._merge_scatter_fn(out, np.full(mb, mb, np.int64), out)
 
         steps = [
@@ -768,7 +784,7 @@ class TypedTable:
             lambda: [self.freeze_serving(True) for _ in range(3)],
             lambda: self.row_state(0, 0),
             lambda: self.clear_rows(none, none),
-            lambda: self._gather_rows_fn(self.head, self.head_vc, z, z),
+            lambda: self.gather_rows_dispatch(none, none),
         ]
         if self.sharding is not None:
             steps += [lambda: self.read_latest(z[:1], z[:1], vcs[:1]),
@@ -776,9 +792,9 @@ class TypedTable:
         else:
             steps += [
                 lambda: self._latest_resolved_flat_fn(
-                    self.head, self.head_vc, z, z, vcs),
+                    self.head, self.head_vc, self._stage_reads(z, z, vcs)),
                 lambda: self._head_state_flat_fn(
-                    self.head, self.head_vc, z, z, vcs),
+                    self.head, self.head_vc, self._stage_reads(z, z, vcs)),
             ] + [functools.partial(fold, k)
                  for k in sorted({self._kmax_bucket(1), 0})]
         for step in steps:
@@ -914,7 +930,8 @@ class TypedTable:
         checkpoint's capture primitive: launched under the commit-lock
         barrier, materialized outside it."""
         @device_program("ckpt_gather")
-        def fn(head, head_vc, ss, rr):
+        def fn(head, head_vc, staged):
+            ss, rr = staged[:, 0], staged[:, 1]
             return ({f: x[ss, rr] for f, x in head.items()},
                     head_vc[ss, rr])
 
@@ -926,17 +943,15 @@ class TypedTable:
         DEVICE handles padded to a batch bucket (the caller slices to
         the true length after materializing off the lock — padding
         keeps each delta stamp from minting a fresh XLA trace for its
-        particular dirty-row count)."""
-        m = len(rows)
-        mb = _bucket(max(m, 1), self.cfg.batch_buckets)
-        ss = np.zeros(mb, np.int64)
-        rr = np.zeros(mb, np.int64)
-        ss[:m] = np.minimum(np.asarray(shards, np.int64),
-                            self.n_shards - 1)
-        rr[:m] = np.minimum(np.asarray(rows, np.int64), self.n_rows - 1)
+        particular dirty-row count).  The rows cross as one staged
+        operand (:meth:`_stage_reads`, no read VC)."""
+        staged = self._stage_reads(
+            np.minimum(np.asarray(shards, np.int64), self.n_shards - 1),
+            np.minimum(np.asarray(rows, np.int64), self.n_rows - 1),
+            mb=_bucket(max(len(rows), 1), self.cfg.batch_buckets))
         if head is None:
             head, head_vc = self.head, self.head_vc
-        return self._gather_rows_fn(head, head_vc, ss, rr)
+        return self._gather_rows_fn(head, head_vc, staged)
 
     @functools.cached_property
     def _grow_fn(self):
@@ -1154,10 +1169,8 @@ class TypedTable:
                     shards = jnp.where(mine, shards - s0, pl)
                 mine = shards < pl
                 sl = jnp.minimum(shards, pl - 1)
-                # int64 lanes travel as exact halves: (hi << 32) | lo
-                a32 = staged[:, 5:b0].reshape(-1, aw, 2)
-                a = ((a32[..., 1].astype(jnp.int64) << 32)
-                     | a32[..., 0].astype(jnp.uint32).astype(jnp.int64))
+                # int64 lanes travel as exact halves
+                a = _join_i64(staged[:, 5:b0].reshape(-1, aw, 2))
                 ops_a, ops_b, ops_vc, ops_origin = _write_rows(
                     (ops_a, ops_b, ops_vc, ops_origin), (sl, slots), rows,
                     (a, staged[:, b0: b0 + bw], staged[:, b0 + bw:],
@@ -1345,11 +1358,13 @@ class TypedTable:
         no [P, M'] routing: index the tables by (shard, row) pairs in one
         advanced-indexing gather.  Serving hot path on a single device;
         mesh-sharded tables keep the routed layout (a flat gather across
-        the sharded axis would induce collectives)."""
+        the sharded axis would induce collectives).  The batch is one
+        staged operand (:meth:`_stage_reads`)."""
         ty, cfg = self.ty, self.cfg
 
         @device_program("head_gather")
-        def fn(head, head_vc, ss, rr, read_vcs):
+        def fn(head, head_vc, staged):
+            ss, rr, read_vcs = _unstage_reads(staged, 2)
             hvc = head_vc[ss, rr]
             state = {f: x[ss, rr] for f, x in head.items()}
             fresh = jnp.all(hvc <= read_vcs, axis=-1)
@@ -1365,9 +1380,11 @@ class TypedTable:
     @functools.cached_property
     def _head_state_flat_fn(self):
         """Flat gather of whole head states and their freshness
-        (:meth:`read_latest` of a one-device table)."""
+        (:meth:`read_latest` of a one-device table), the batch one staged
+        operand (:meth:`_stage_reads`)."""
         @device_program("head_state")
-        def fn(head, head_vc, ss, rr, read_vcs):
+        def fn(head, head_vc, staged):
+            ss, rr, read_vcs = _unstage_reads(staged, 2)
             fresh = jnp.all(head_vc[ss, rr] <= read_vcs, axis=-1)
             return {f: x[ss, rr] for f, x in head.items()}, fresh
 
@@ -1385,6 +1402,28 @@ class TypedTable:
                 [read_vcs, np.repeat(read_vcs[-1:], pad, axis=0)])
         return shards, rows, read_vcs
 
+    def _stage_reads(self, shards, rows, read_vcs=None, n_ops=None,
+                     mb=None):
+        """The one host operand of a flat read program of a one-device
+        table (and of the checkpoint's gather), so that the batch crosses
+        to the device once: int32 [``mb``, cols], one row a read — its
+        shard, its row, for a program that folds the row's used ring
+        prefix (``n_ops``), then the read VC's lanes (``read_vcs`` [M,
+        D], or one [D] for every read) — and zeros after the first
+        ``len(rows)`` rows (``mb`` None: no more rows).  int32 holds each
+        (shards and rows < 2**31).  A buffer of its own for every launch,
+        as for :meth:`append`."""
+        m = len(rows)
+        lanes = (shards, rows) if n_ops is None else (shards, rows, n_ops)
+        d = 0 if read_vcs is None else np.shape(read_vcs)[-1]
+        staged = np.zeros((m if mb is None else mb, len(lanes) + d),
+                          np.int32)
+        for i, x in enumerate(lanes):
+            staged[:m, i] = x
+        if d:
+            staged[:m, len(lanes):] = read_vcs
+        return staged
+
     def _read_resolved_flat_fn(self, strategy: str, kmax: int = 0):
         """Flat single-gather variant of :meth:`_read_resolved_fn`: the
         same fused serving read (freshness + version select + ring fold +
@@ -1395,7 +1434,8 @@ class TypedTable:
         state and the count of ops applied ride along on the device for
         :meth:`read`, which would otherwise need a program of its own
         (25 s of compile at the served widths, on first use in a
-        transaction's read of a set over ``resolve_top``)."""
+        transaction's read of a set over ``resolve_top``).  The batch is
+        one staged operand (:meth:`_stage_reads` with ``n_ops``)."""
         cached = self._resolved_flat_fns.get((strategy, kmax))
         if cached is not None:
             return cached
@@ -1404,8 +1444,8 @@ class TypedTable:
 
         @device_program(f"read_resolved_{strategy}_k{kmax}_flat")
         def fn(head, head_vc, snap, snap_vc, snap_seq,
-               ops_a, ops_b, ops_vc, ops_origin, ss, rr, n_ops_flat,
-               read_vcs):
+               ops_a, ops_b, ops_vc, ops_origin, staged):
+            ss, rr, n_ops_flat, read_vcs = _unstage_reads(staged, 3)
             m = ss.shape[0]
             idx = jnp.arange(m)
             hvc = head_vc[ss, rr]
@@ -1519,9 +1559,11 @@ class TypedTable:
         shards = np.asarray(shards, np.int64)
         rows = np.asarray(rows, np.int64)
         read_vcs = np.asarray(read_vcs, np.int32)
+        # each way down the ladder launches one head gather of the batch
+        staged = self._stage_reads(shards, rows, read_vcs)
         if (read_vcs >= self.max_commit_vc).all():
             resolved, fresh = self._latest_resolved_flat_fn(
-                self.head, self.head_vc, shards, rows, read_vcs
+                self.head, self.head_vc, staged
             )
             return resolved, fresh, fresh
         epoch = self._epoch_for(read_vcs)
@@ -1529,7 +1571,7 @@ class TypedTable:
             # pinned exactly at the epoch cap: every row frozen-fresh
             # (head_vc ≤ cap = R row-wise) — pure gather, no host sync
             resolved, fresh = self._latest_resolved_flat_fn(
-                epoch["head"], epoch["head_vc"], shards, rows, read_vcs
+                epoch["head"], epoch["head_vc"], staged
             )
             return resolved, fresh, fresh
         self.slow_serves += 1
@@ -1538,7 +1580,7 @@ class TypedTable:
         else:
             src_head, src_vc = self.head, self.head_vc
         resolved_h, fresh_d = self._latest_resolved_flat_fn(
-            src_head, src_vc, shards, rows, read_vcs
+            src_head, src_vc, staged
         )
         fresh = np.asarray(fresh_d)
         stale = np.nonzero(~fresh)[0]
@@ -1551,23 +1593,20 @@ class TypedTable:
             return resolved_h, fresh, fresh
         mb = _bucket(ns, self.cfg.batch_buckets)
         pad = mb - ns
-        sss = np.concatenate([shards[stale], np.zeros(pad, np.int64)])
-        rrs = np.concatenate([rows[stale], np.zeros(pad, np.int64)])
-        vcss = np.concatenate(
-            [read_vcs[stale], np.zeros((pad, read_vcs.shape[-1]), np.int32)]
-        )
+        sss, rrs = shards[stale], rows[stale]
         n_ops_flat = self.n_ops[sss, rrs]
-        n_ops_flat[ns:] = 0
         kmax = self._kmax_bucket(int(n_ops_flat.max()))
         strategy = self._fold_strategy()
         self._count_dispatch(strategy)
         fn = self._read_resolved_flat_fn(strategy, kmax)
         t0 = time.monotonic()
         with span("serve.fold", rows=ns, bucket=mb):
+            # padding: row (0, 0) at VC 0 with an empty ring
             resolved_s, _, complete_s, _, _ = fn(
                 self.head, self.head_vc, self.snap, self.snap_vc,
                 self.snap_seq, self.ops_a, self.ops_b, self.ops_vc,
-                self.ops_origin, sss, rrs, n_ops_flat, vcss,
+                self.ops_origin,
+                self._stage_reads(sss, rrs, read_vcs[stale], n_ops_flat, mb),
             )
             # scatter the folded rows back over the gathered batch on
             # device (padding scatters at index M → dropped)
@@ -1740,7 +1779,7 @@ class TypedTable:
             # Copies: callers patch stale rows into them
             state, fresh = self._head_state_flat_fn(
                 self.head, self.head_vc,
-                *self._pad_reads(shards, rows, read_vcs))
+                self._stage_reads(*self._pad_reads(shards, rows, read_vcs)))
             return _cut((state, fresh), m)
         row_mat, pos = self._route(shards, rows)
         p, mm = row_mat.shape
@@ -1905,7 +1944,8 @@ class TypedTable:
                     strategy, self._kmax_bucket(int(n_ops_flat.max())))(
                     self.head, self.head_vc, self.snap, self.snap_vc,
                     self.snap_seq, self.ops_a, self.ops_b, self.ops_vc,
-                    self.ops_origin, ss, rr, n_ops_flat, vcs)
+                    self.ops_origin,
+                    self._stage_reads(ss, rr, vcs, n_ops_flat))
                 out = _cut((state, applied, complete), m)
             self.fold_launches += 1
             self.fold_rows += m

@@ -431,3 +431,69 @@ def test_replay_mesh_assoc_over_ring(tmp_path):
     assert node.store.replay_fold_dispatches.get("mesh_assoc", 0) >= 1
     assert node.store.mesh.giant_folds >= 1
     assert node.store.materializer_status()["giant_folds"] >= 1
+
+
+# ---------------------------------------------------------------------------
+# the log replay's serial fold: one staged operand a piece
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def replay_store():
+    from antidote_tpu.store.kv import KVStore
+
+    return KVStore(_mk_cfg())
+
+
+def _replay_log(ty, cfg, l, rng):
+    d = cfg.max_dcs
+    if ty.name == "set_aw":
+        return _rand_set_ops(rng, l, d, n_handles=6, add_only=False)
+    # int64 lanes past 2^31 and negative: the lo/hi halves round trip
+    ops_a = rng.integers(-2**40, 2**40, size=(l, ty.eff_a_width(cfg)))
+    ops_a[::3] = -(2**31) - rng.integers(1, 9, size=ops_a[::3].shape)
+    ops_vc = rng.integers(0, 8, size=(l, d)).astype(np.int32)
+    return (ops_a.astype(np.int64),
+            np.zeros((l, ty.eff_b_width(cfg)), np.int32), ops_vc,
+            rng.integers(0, d, size=(l,)).astype(np.int32),
+            np.asarray([2, 0, 1], np.int32), np.asarray([6, 7, 5], np.int32))
+
+
+@pytest.mark.parametrize("l", [1, 64, 65, 256, 300])
+@pytest.mark.parametrize("tyname", ["counter_pn", "set_aw"])
+def test_replay_fold_staged_equals_fold_key(replay_store, tyname, l):
+    """The replay's serial fold — ops, clocks and a host base state packed
+    into one int32 operand a piece of 64 or 256, a longer log carrying its
+    state on the device from piece to piece — folds exactly what
+    ``fold_key`` folds over the whole log; a base state already on the
+    device stays its own operand and folds the same."""
+    from antidote_tpu.obs import trace
+
+    ty = get_type(tyname)
+    cfg = replay_store.cfg
+    rng = np.random.default_rng(l)
+    ops_a, ops_b, ops_vc, ops_origin, base_vc, read_vc = _replay_log(
+        ty, cfg, l, rng)
+    base = {f: np.asarray(x) for f, x in jax.jit(functools.partial(
+        fold_mod.fold_key, ty, cfg))(
+        _bottom(ty, cfg), ops_a[:l // 2], ops_b[:l // 2], ops_vc[:l // 2],
+        ops_origin[:l // 2], np.int32(l // 2), np.zeros_like(base_vc),
+        base_vc)[0].items()}
+    if tyname == "counter_pn":
+        base["cnt"] = np.int64(-(2**35) - 3)
+    ref, _ = jax.jit(functools.partial(fold_mod.fold_key, ty, cfg))(
+        base, ops_a, ops_b, ops_vc, ops_origin, np.int32(l), base_vc,
+        read_vc)
+    for state0 in (base, jax.device_put(base)):
+        r0 = trace.program_status().get("replay_fold_serial")
+        got, strategy = replay_store._fold_over_ring(
+            ty, cfg, state0, ops_a, ops_b, ops_vc, ops_origin, l, base_vc,
+            read_vc, bottom=False)
+        r1 = trace.program_status()["replay_fold_serial"]
+        assert strategy == "serial"
+        _assert_states_equal(ref, got, f"{tyname} l={l}")
+        # one launch a piece, one host operand a launch (a tier-building
+        # thread of an earlier test's node may launch the program too)
+        launches = r1["launches"] - (r0["launches"] if r0 else 0)
+        assert launches >= -(-l // 256)
+        assert r1["host_operands"] - (r0["host_operands"] if r0 else 0) \
+            == launches

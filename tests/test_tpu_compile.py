@@ -144,8 +144,7 @@ def test_fused_serving_read_compiles(shape, monkeypatch, tyname, strategy):
             table.snap_seq, table.ops_a, table.ops_b, table.ops_vc,
             table.ops_origin,
         )),
-        shape((m,), jnp.int64), shape((m,), jnp.int64), shape((m,)),
-        shape((m, cfg.max_dcs)),
+        shape((m, 3 + cfg.max_dcs)),   # the staged operand
     )
     # set_aw: the fold kernel and the presence kernel
     assert text.count("tpu_custom_call") >= (2 if tyname == "set_aw" else 1)
@@ -421,8 +420,7 @@ def set_aw_programs(topo):
                         s0["ops_vc"], s0["ops_origin"])
                 if devices == 1:
                     fn = t0._read_resolved_flat_fn("pallas_set_aw", 0)
-                    batch = (b(mb, dtype=jnp.int64), b(mb, dtype=jnp.int64),
-                             b(mb), b(mb, 8))
+                    batch = (b(mb, 3 + 8),)   # the staged operand
                 else:
                     fn = t0._read_resolved_fn("pallas_set_aw", 0)
                     mk = lambda *d, dtype=jnp.int32: jax.ShapeDtypeStruct(  # noqa: E731

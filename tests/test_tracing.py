@@ -448,7 +448,7 @@ def test_server_reports_ack_phase_and_locked_idle():
 ROUND_METRICS = ["txn.round_ms", "txn.round_programs",
                  "txn.round_offcpu_share", "txn.round_stage_ms",
                  "kernels.call_ms", "kernels.call_offcpu_ms",
-                 "kernels.compile_ms_per_s"]
+                 "kernels.compile_ms_per_s", "kernels.call_host_operands"]
 
 
 @pytest.fixture(scope="module")
@@ -550,6 +550,7 @@ def test_device_program_counts_launches_and_compiles_by_name():
     fn(jnp.ones((3,), jnp.float32))
     r1 = row()
     assert r1["launches"] == r0["launches"] + 1
+    assert r1["host_operands"] == r0["host_operands"]    # a device array
     assert built(r1) == built(r0) + 1 and r1["compile_ms"] > r0["compile_ms"]
     fn(jnp.ones((3,), jnp.float32))                 # the same shape
     r2 = row()
@@ -575,6 +576,115 @@ def test_device_program_counts_launches_and_compiles_by_name():
     total = trace.program_status()["total"]
     assert total["launches"] >= r3["launches"]
     assert total["compiles"] + total["cache_loads"] >= built(r3) + 1
+    assert set(total) == set(trace.PROGRAM_FIELDS)
+    fn(np.ones((3,), np.float32))                   # a host array
+    assert row()["host_operands"] == r3["host_operands"] + 1
+
+
+def test_host_operands_count_only_the_host_leaves_of_a_launch():
+    """A launch's host operands are the leaves of its arguments that are
+    not device arrays: a table pytree and a carried state on the device
+    count nothing, a static argument is no operand."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    @trace.device_program("unit_operands", static_argnames=("k",))
+    def fn(table, staged, carried, k=1):
+        return table["a"] + table["b"] + staged.sum() * k + carried
+
+    def host():
+        return trace.program_status()["unit_operands"]["host_operands"]
+
+    table = {"a": jnp.ones((3,)), "b": jnp.ones((3,))}
+    fn(table, np.ones((3,), np.float32), jnp.zeros((3,)), k=2)
+    h0 = host()
+    fn(table, np.ones((3,), np.float32), jnp.zeros((3,)), k=3)
+    assert host() == h0 + 1                      # the staged operand alone
+    fn(table, np.ones((3,), np.float32), jnp.zeros((3,)), 3)
+    assert host() == h0 + 2                      # static by position too
+    fn({"a": np.ones((3,)), "b": table["b"]}, np.ones((3,), np.float32),
+       np.zeros((3,)), k=3)
+    assert host() == h0 + 5                      # + a host field and state
+    assert trace.program_status()["unit_operands"]["launches"] == 4
+
+
+#: the programs that take a one-device table's read batch, or a replayed
+#: log's piece, as one staged operand
+ONE_OPERAND = ("head_gather", "head_state", "ckpt_gather",
+               "replay_fold_serial", "read_resolved_")
+
+
+@pytest.fixture(scope="module")
+def warm_reads(tmp_path_factory):
+    """program_status() around the reads a served mix makes of a table the
+    warm walks have compiled for (``TypedTable.warm``,
+    ``KVStore._warm_replay``): a versioned read on the locked plane, a read
+    below the device's coverage, an epoch read, and the table's own
+    head_state, versioned and checkpoint gathers."""
+    import numpy as np
+
+    node = AntidoteNode(mk_cfg(), log_dir=str(
+        tmp_path_factory.mktemp("warm") / "log"))
+    store, txm = node.store, node.txm
+    txm.enable_serving_epochs()
+
+    def inc(key):
+        return node.update_objects([(key, "counter_pn", "b",
+                                     ("increment", 1))])
+
+    for _ in range(2):
+        inc("d")
+    cut_d = inc("d")
+    inc("d")                                  # stale at cut_d, in the ring
+    cut_c = [inc("c") for _ in range(30)][2]  # 30: GC'd past the versions
+    t = store.table("counter_pn")
+    assert t.warm()
+    store._warm_replay(t.ty, t.cfg, with_base=False)
+    txm.publish_serving_epoch()
+    st0 = trace.program_status()
+    vals = []
+    for key, cut in (("d", cut_d), ("c", cut_c)):
+        txn = node.start_transaction()
+        txn.snapshot_vc = np.asarray(cut, np.int32)
+        vals += node.read_objects([(key, "counter_pn", "b")], txn)
+        node.commit_transaction(txn)
+    ep = store.pin_serving_epoch()
+    pending, fallback = store.epoch_read_launch(
+        [("c", "counter_pn", "b"), ("d", "counter_pn", "b")], ep)
+    vals += store.epoch_read_finish(pending)
+    store.unpin_serving_epoch(ep)
+    _, shard, row = store.directory[("d", "b")]
+    vc = np.asarray([cut_d], np.int32)
+    t.read_latest([shard], [row], vc)
+    t.read([shard], [row], vc)
+    t.gather_rows_dispatch([shard], [row])
+    st1 = trace.program_status()
+    assert fallback == [] and vals == [3, 3, 30, 4]
+    assert store.replays == 1 and t.fold_launches >= 2
+    return st0, st1
+
+
+@pytest.mark.parametrize("program", ONE_OPERAND)
+def test_a_read_launch_of_a_one_device_table_takes_one_host_operand(
+        warm_reads, program):
+    st0, st1 = warm_reads
+    zero = dict.fromkeys(trace.PROGRAM_FIELDS, 0)
+    names = [k for k in st1 if k.startswith(program)
+             and (program != "read_resolved_" or k.endswith("_flat"))]
+    d = {f: sum(st1[k][f] - st0.get(k, zero)[f] for k in names)
+         for f in ("launches", "host_operands")}
+    assert d["launches"] >= 1 and d["host_operands"] == d["launches"], (
+        program, names, d)
+
+
+def test_warm_walks_leave_a_served_read_nothing_to_compile(warm_reads):
+    st0, st1 = warm_reads
+    built = [s["total"]["compiles"] + s["total"]["cache_loads"]
+             for s in (st0, st1)]
+    assert built[1] == built[0], sorted(
+        k for k in st1 if k != "total" and st1[k]["compiles"]
+        + st1[k]["cache_loads"] != st0.get(k, st1[k])["compiles"]
+        + st0.get(k, st1[k])["cache_loads"])
 
 
 # ---------------------------------------------------------------------------

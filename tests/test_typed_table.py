@@ -633,3 +633,137 @@ def test_append_on_a_mesh_placed_table(cfg, case):
     for x in jax.tree.leaves((table.ops_a, table.ops_b, table.ops_vc,
                               table.ops_origin, table.head, table.head_vc)):
         assert x.sharding.is_equivalent_to(placed, x.ndim)
+
+
+# ---------------------------------------------------------------------------
+# the flat read programs of a one-device table: one staged operand a
+# launch, the answers of the routed programs (one host array an operand)
+# ---------------------------------------------------------------------------
+def _history_table(tyname, cfg):
+    """A one-device table of 12 keys over every shard and a read VC at
+    which keys 0-3 are fresh (nothing after it), 4-7 stale (their later
+    effects in the ring) and 8-11 below coverage (three rings' worth
+    after it, folded into snapshot versions newer than it)."""
+    ty = get_type(tyname)
+    table = TypedTable(ty, cfg)
+    blobs = BlobStore()
+    clock = np.zeros(cfg.max_dcs, np.int32)
+    keys = _spread(12, cfg)
+
+    def op(i, j):
+        if tyname == "counter_pn":   # int64 lanes past 2^31, both signs
+            return ("increment" if j % 2 else "decrement", BIG[j % 2] + i)
+        return ("add", f"e{(i + j) % 4}")
+
+    def commit(ids, j):
+        effs = []
+        for i in ids:
+            for a, b, _ in ty.downstream(op(i, j), None, blobs, cfg):
+                clock[0] += 1
+                effs.append((*keys[i], a, b, clock.copy()))
+        s, r, a, b, v = zip(*effs)
+        table.append(np.asarray(s), np.asarray(r), np.stack(a), np.stack(b),
+                     np.stack(v), np.zeros(len(effs), np.int32))
+
+    for j in range(2):
+        commit(range(12), j)
+    at = clock.copy()
+    for j in range(2, 4):
+        commit(range(4, 12), j)
+    for j in range(4, 4 + 3 * cfg.ops_per_key):
+        commit(range(8, 12), j)
+    return table, keys, at
+
+
+def _routed(t, ss, rr, vcs, kmax):
+    """What the routed [P, M'] programs answer for a flat batch, back in
+    batch order: (head_state, head_gather, the fold at ``kmax``, the
+    versioned read)."""
+    import jax
+
+    row_mat, pos = t._route(ss, rr)
+    p, mm = row_mat.shape
+    rows = np.minimum(row_mat, t.n_rows - 1)
+    vc_mat = np.zeros((p, mm, vcs.shape[-1]), np.int32)
+    vc_mat[pos[:, 0], pos[:, 1]] = vcs
+    n_ops = np.where(row_mat < t.n_rows,
+                     t.n_ops[np.arange(p)[:, None], rows], 0)
+    snap = (t.snap, t.snap_vc, t.snap_seq, t.ops_a, t.ops_b, t.ops_vc,
+            t.ops_origin)
+
+    def back(tree):
+        return jax.tree.map(lambda x: np.asarray(x)[pos[:, 0], pos[:, 1]],
+                            tree)
+
+    return (back(t._read_latest_fn(t.head, t.head_vc, rows, vc_mat)),
+            back(t._latest_resolved_fn(t.head, t.head_vc, rows, vc_mat)),
+            back(t._read_resolved_fn(t._fold_strategy(), kmax)(
+                t.head, t.head_vc, *snap, rows, n_ops, vc_mat)),
+            back(t._read_fn(*snap, rows, n_ops, vc_mat)))
+
+
+def _same(got, want, what):
+    import jax
+
+    got, want = jax.tree.map(np.asarray, (got, want))
+    assert jax.tree.structure(got) == jax.tree.structure(want), what
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(g, w, err_msg=what)
+
+
+@pytest.mark.parametrize("m", [1, 16, 65])   # a bucket's edge; past the last
+@pytest.mark.parametrize("tyname", ["counter_pn", "set_aw"])
+def test_staged_flat_reads_answer_as_the_routed_programs(cfg, tyname, m):
+    """head_gather, head_state, the flat fold at each ring-window bucket,
+    ``read`` and ``ckpt_gather`` of a one-device table, each launched
+    with the batch as one staged int32 operand, answer what the routed
+    programs answer — rows fresh, stale and below coverage."""
+    from antidote_tpu.store.typed_table import _cut
+
+    t, keys, at = _history_table(tyname, cfg)
+    idx = np.arange(m) % len(keys)
+    ss = np.asarray([keys[i][0] for i in idx], np.int64)
+    rr = np.asarray([keys[i][1] for i in idx], np.int64)
+    vcs = np.tile(at, (m, 1))
+    kmaxes = sorted({t._kmax_bucket(int(t.n_ops[ss, rr].max())), 0})
+    assert len(kmaxes) == 2, kmaxes
+    latest, gathered, _, versioned = _routed(t, ss, rr, vcs, 0)
+    np.testing.assert_array_equal(latest[1], idx < 4)       # fresh
+    np.testing.assert_array_equal(versioned[2], idx < 8)    # covered
+
+    _same(t.read_latest(ss, rr, vcs), latest, "head_state")
+    pss, prr, pvcs = t._pad_reads(ss, rr, vcs)
+    _same(_cut(t._latest_resolved_flat_fn(
+        t.head, t.head_vc, t._stage_reads(pss, prr, pvcs)), m), gathered,
+        "head_gather")
+    tables = (t.head, t.head_vc, t.snap, t.snap_vc, t.snap_seq, t.ops_a,
+              t.ops_b, t.ops_vc, t.ops_origin)
+    for kmax in kmaxes:
+        got = _cut(t._read_resolved_flat_fn(t._fold_strategy(), kmax)(
+            *tables, t._stage_reads(pss, prr, pvcs, t.n_ops[pss, prr])), m)
+        _same(got[:3], _routed(t, ss, rr, vcs, kmax)[2], f"fold k{kmax}")
+        # the versioned state and count ride along; a fresh row's state
+        # is its head (the same where the fold is complete)
+        _same(got[3:], versioned[:2], f"state k{kmax}")
+    state, applied, complete = t.read(ss, rr, vcs)
+    _same((state, applied), versioned[:2], "read")
+    np.testing.assert_array_equal(complete, versioned[2] | latest[1])
+    head, head_vc = _cut(t.gather_rows_dispatch(ss, rr), m)
+    _same((head, head_vc), ({f: np.asarray(x)[ss, rr]
+                             for f, x in t.head.items()},
+                            np.asarray(t.head_vc)[ss, rr]), "ckpt_gather")
+
+
+def test_stage_reads_layout_and_padding(cfg):
+    """shard, row, [n_ops,] the read VC's lanes; zeros past the batch; a
+    new buffer each call."""
+    t = TypedTable(get_type("counter_pn"), cfg)
+    vcs = np.asarray([[1, 2, 3], [4, 5, 6]], np.int32)
+    a = t._stage_reads(np.asarray([3, 1]), np.asarray([7, 9]), vcs,
+                       np.asarray([2, 5]), mb=4)
+    assert a.dtype == np.int32 and a.tolist() == [
+        [3, 7, 2, 1, 2, 3], [1, 9, 5, 4, 5, 6], [0] * 6, [0] * 6]
+    one_vc = t._stage_reads([0, 2], [5, 6], np.asarray([8, 0, 1]))
+    assert one_vc.tolist() == [[0, 5, 8, 0, 1], [2, 6, 8, 0, 1]]
+    assert t._stage_reads([1], [2]).tolist() == [[1, 2]]
+    assert t._stage_reads([1], [2]) is not t._stage_reads([1], [2])
