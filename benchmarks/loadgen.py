@@ -10,6 +10,10 @@ second fixes the window.  Then it writes its log as a pickle and exits.  Every u
 from the first one -- the comparison needs the whole history -- and every
 read that is sent inside the window is logged with its answer.
 
+Under geo-replication the spec has `ports`, one a DC: client c is homed at
+DC c mod N, sends every request there, and the log tags each of its
+updates and reads with that DC.
+
 The process never touches JAX.
 """
 
@@ -78,7 +82,9 @@ class Client(threading.Thread):
         self.updates, self.reads = [], []
         self.never = 0
         self.error = None
-        self.conn = connect(spec["client"], spec["host"], spec["port"],
+        ports = spec.get("ports") or [spec["port"]]
+        self.dc = cid % len(ports)             # the client's home DC
+        self.conn = connect(spec["client"], spec["host"], ports[self.dc],
                             spec["timeout"], spec.get("fault"),
                             spec.get("fault_every", 40))
         self.txn = None
@@ -209,8 +215,8 @@ def main() -> int:
         c.join(timeout=spec["timeout"] + 30)
     hung = sum(c.is_alive() for c in clients)
     out = {
-        "updates": [u + (c.cid,) for c in clients for u in c.updates],
-        "reads": [r for c in clients for r in c.reads],
+        "updates": [u + (c.cid, c.dc) for c in clients for u in c.updates],
+        "reads": [r + (c.dc,) for c in clients for r in c.reads],
         "never": sum(c.never for c in clients) + hung,
         "errors": [c.error for c in clients if c.error],
         "cpu_busy_window": (cpu2 - cpu1) / (times[2] - times[1]),
